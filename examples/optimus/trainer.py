@@ -3,8 +3,9 @@ trains a 125M-param transformer ... using Store-backed ICI allreduce").
 
 Where the reference's optimus fanned prime-check chunks over a worker
 pool (coordinator.go:67-99), this fans a token batch over the device
-mesh: join the cluster, build the mesh from the platform config's axes,
-and train. Three modes:
+mesh: join the cluster, build the mesh from the platform config's axes
+(every visible device on ``data`` when the config names none), and
+train. Three modes:
 
 - ``gspmd`` (default): the fully-compiled train step (train/trainer.py) —
   the throughput path; collectives inserted by sharding annotations.
@@ -27,7 +28,7 @@ from __future__ import annotations
 
 import os
 
-
+from ptype_tpu import compile_cache
 from ptype_tpu.cluster import join
 from ptype_tpu.config import config_from_env
 from ptype_tpu.models import transformer as tfm
@@ -35,6 +36,7 @@ from ptype_tpu.train.data import synthetic_batches
 
 
 def main() -> None:
+    compile_cache.configure()
     cfg = config_from_env()
 
     # Optimizer knobs ($LR/$WARMUP/$WEIGHT_DECAY/$DECAY_STEPS) and a

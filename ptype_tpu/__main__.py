@@ -147,7 +147,7 @@ def _join() -> None:
 def _serve() -> None:
     import os
 
-    from ptype_tpu import config_from_env, join
+    from ptype_tpu import compile_cache, config_from_env, join
     from ptype_tpu.models import transformer as tfm
     # Replica lifecycle has ONE home (lint PT012): the server that
     # fronts a serving replica is constructed by reconciler/replica.py
@@ -157,6 +157,7 @@ def _serve() -> None:
     from ptype_tpu.reconciler.replica import serve_actor
     from ptype_tpu.serve import BatchingGeneratorActor
 
+    compile_cache.configure()
     cfg = config_from_env()
     model_cfg = tfm.preset(os.environ.get("PRESET", "tiny"))
     # $SERVE_MODE=continuous: slot-based continuous batching (requests
@@ -210,6 +211,7 @@ def _eval() -> None:
 
     import jax
 
+    from ptype_tpu import compile_cache
     from ptype_tpu.checkpoint import Checkpointer
     from ptype_tpu.models import transformer as tfm
     from ptype_tpu.parallel.mesh import build_mesh
@@ -217,6 +219,7 @@ def _eval() -> None:
     from ptype_tpu.train.data import TokenFileDataset, synthetic_batches
     from ptype_tpu.train.trainer import Trainer, default_optimizer
 
+    compile_cache.configure()
     ckpt_dir = os.environ.get("CKPT_DIR")
     if not ckpt_dir:
         print("eval: set CKPT_DIR to the checkpoint directory",

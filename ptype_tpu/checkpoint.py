@@ -438,7 +438,11 @@ class Checkpointer:
             dtype = _resolve_dtype(entry["dtype"])
             full = np.zeros(entry["shape"], dtype=dtype)
             if full.ndim == 0:
-                full = _load_shard(sdir, entry["shards"][0], dtype)
+                # The shard file holds (1,): np.ascontiguousarray gave
+                # the scalar a dimension at save time, and int() of a
+                # (1,) jax array is an error on this installation.
+                full = _load_shard(sdir, entry["shards"][0],
+                                   dtype).reshape(())
             else:
                 _check_tiling(key, entry["shards"], entry["shape"])
                 for rec in entry["shards"]:

@@ -64,14 +64,18 @@ def get_ip() -> str:
 
 
 def _local_device_ordinals() -> tuple[int, ...]:
-    """Global ids of this process's JAX devices; () if JAX is unused."""
-    try:
-        import jax
+    """Global ids of this process's JAX devices. Only called for a
+    platform config that declares ``mesh_axes``: such a process is a
+    mesh member, and one whose backend does not come up must fail its
+    join rather than register with no devices."""
+    import jax
 
+    try:
         return tuple(d.id for d in jax.local_devices())
-    except Exception as e:  # noqa: BLE001 — control-plane-only processes
-        log.debug("no local JAX devices", kv={"err": str(e)})
-        return ()
+    except RuntimeError as e:
+        raise ClusterError(
+            "join: the platform config declares mesh_axes but the JAX "
+            f"backend did not come up: {e}") from e
 
 
 class Cluster:
@@ -105,10 +109,24 @@ class Cluster:
 
     def mesh(self, axis_names: tuple[str, ...] | None = None):
         """Device mesh from the platform config's axes — the registry-as-
-        mesh-map lowering. See ptype_tpu.parallel.mesh."""
-        from ptype_tpu.parallel.mesh import build_mesh
+        mesh-map lowering. See ptype_tpu.parallel.mesh. A config that
+        gives no axes gets every visible device on the ``data`` axis;
+        one whose axes cover fewer devices than the host shows says
+        which it left out."""
+        import jax
 
-        return build_mesh(self.cfg.platform.mesh_axes, axis_names)
+        from ptype_tpu.parallel.mesh import build_mesh
+        from ptype_tpu.parallel.topology import DATA_AXIS
+
+        axes = (self.cfg.platform.mesh_axes
+                or {DATA_AXIS: jax.device_count()})
+        mesh = build_mesh(axes, axis_names)
+        used = {d.id for d in mesh.devices.flat}
+        idle = [d.id for d in jax.devices() if d.id not in used]
+        if idle:
+            log.warning("mesh leaves devices out",
+                        kv={"axes": dict(mesh.shape), "unused": idle})
+        return mesh
 
     def close(self) -> None:
         """Leave the cluster (ref: cluster.go:95-99 — plus prompt
@@ -140,14 +158,9 @@ def _init_jax_distributed(platform) -> None:
     the launcher did it) so join stays idempotent."""
     import jax
 
-    try:
-        from jax._src import distributed as _dist
-
-        if _dist.global_state.client is not None:
-            log.debug("jax.distributed already initialized")
-            return
-    except Exception:  # noqa: BLE001 — internals moved; initialize anyway
-        pass
+    if jax.distributed.is_initialized():
+        log.debug("jax.distributed already initialized")
+        return
     addr = platform.jax_coordinator_address
     if not addr:
         host, _, port = platform.coordinator_address.rpartition(":")

@@ -64,13 +64,14 @@ TRANSFER_ENV_VAR = "PTYPE_JITWATCH_TRANSFERS"
 STORM_ENV_VAR = "PTYPE_JITWATCH_STORM"
 DEFAULT_STORM_THRESHOLD = 3
 
-#: The pxla compile log line: "Compiling <name> with global shapes and
-#: types [...]. Argument mapping: (...)." — one WARNING per backend
-#: compile (i.e. per trace-cache miss). The SIGNATURE is shapes+types
-#: AND the argument mapping: the same shapes under different
-#: shardings are legitimately distinct programs, not a recompile.
+#: The pxla compile log line: "Compiling jit(<name>) with global
+#: shapes and types [...]. Argument mapping: (...)." — one WARNING per
+#: backend compile (i.e. per trace-cache miss). The books are keyed by
+#: the bare <name>. The SIGNATURE is shapes+types AND the argument
+#: mapping: the same shapes under different shardings are legitimately
+#: distinct programs, not a recompile.
 _COMPILE_RE = re.compile(
-    r"Compiling (\S+) with global shapes and types (.*?Argument "
+    r"Compiling jit\((.+?)\) with global shapes and types (.*?Argument "
     r"mapping:.*)$", re.DOTALL)
 _COMPILE_LOGGER = "jax._src.interpreters.pxla"
 

@@ -21,104 +21,37 @@ import jax
 
 from ptype_tpu import trace as trace_mod
 
-#: Peak bf16 matmul TFLOP/s per chip, by PJRT device_kind substring.
-#: Public numbers (cloud.google.com/tpu docs); CPU entry is a nominal
-#: figure so MFU stays defined (and obviously tiny) in CPU test runs.
+#: Peak dense bf16 matmul TFLOP/s per chip, keyed by the EXACT
+#: ``device_kind`` string JAX reports. A v5e chip reports
+#: "TPU v5 lite" (chip run, PR 21); 197 is the published figure
+#: (Google Cloud documentation, "TPU v5e"). Matching is exact on
+#: purpose: a v5p reports "TPU v5", which a substring table would
+#: price at the v5e peak. A kind that is not here is an error — add it
+#: together with its source.
 PEAK_TFLOPS = {
-    "v6e": 918.0,
-    "v5p": 459.0,
-    "v5e": 197.0,  # v5 litepod
-    "v5": 197.0,
-    "v4": 275.0,
-    "v3": 123.0,
-    "v2": 45.0,
-    "cpu": 0.5,
+    "TPU v5 lite": 197.0,
 }
 
-#: Env override for the peak table: either one bare float (the peak
-#: for whatever chip this process sees — the operator knows better
-#: than the substring table) or ``kind=tflops`` pairs merged over it
-#: (``"trillium=918,v7=2000"``). A new chip generation must be one
-#: env var away from a correct MFU, not a code change.
-PEAK_TFLOPS_ENV = "PTYPE_PEAK_TFLOPS"
-
-#: Process-level override (set_peak_tflops) — wins over env and table.
-_peak_override: float | None = None
-#: device_kinds already warned about — the unknown-platform fallback
-#: logs ONCE per kind, not once per MFU computation.
-_peak_warned: set = set()
-
-
-def set_peak_tflops(value: float | None) -> None:
-    """Pin (or clear, with ``None``) the per-chip peak used by every
-    MFU computation in this process — the config-file seam; the env
-    seam is :data:`PEAK_TFLOPS_ENV`."""
-    global _peak_override
-    _peak_override = None if value is None else float(value)
-
-
-def _peak_env() -> tuple[float | None, dict]:
-    """(flat override, table additions) parsed from the env var;
-    malformed entries are ignored (a typo must not break MFU)."""
-    import os
-
-    raw = os.environ.get(PEAK_TFLOPS_ENV, "").strip()
-    if not raw:
-        return None, {}
-    extra: dict = {}
-    flat = None
-    for part in raw.split(","):
-        part = part.strip()
-        if not part:
-            continue
-        if "=" in part:
-            key, _, val = part.partition("=")
-            try:
-                extra[key.strip().lower()] = float(val)
-            except ValueError:
-                pass
-        else:
-            try:
-                flat = float(part)
-            except ValueError:
-                pass
-    return flat, extra
+#: Nominal figure for the CPU backend so MFU stays defined (and
+#: obviously tiny) in host-mesh test runs. Not a peak of anything.
+CPU_NOMINAL_TFLOPS = 0.5
 
 
 def device_peak_tflops(device=None) -> float:
-    """Best-effort peak bf16 TFLOP/s for a device (default:
-    devices()[0]). Resolution order: :func:`set_peak_tflops` override,
-    a bare-float :data:`PEAK_TFLOPS_ENV`, then the device_kind
-    substring table (env ``kind=value`` pairs take precedence within
-    it). An UNKNOWN non-CPU platform falls back to the v5e figure and
-    logs once per kind — MFU is never quietly computed against a
-    wrong peak without a trail."""
-    if _peak_override is not None:
-        return _peak_override
-    flat, extra = _peak_env()
-    if flat is not None:
-        return flat
+    """Peak bf16 TFLOP/s of ``device`` (default: ``devices()[0]``)
+    from :data:`PEAK_TFLOPS`. An accelerator whose ``device_kind`` is
+    not in the table raises: MFU is never computed against another
+    chip's peak."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "") or device.platform
-    kind = kind.lower()
-    for key, tf in extra.items():
-        if key in kind:
-            return tf
-    for key, tf in PEAK_TFLOPS.items():
-        if key in kind:
-            return tf
     if device.platform == "cpu":
-        return PEAK_TFLOPS["cpu"]
-    if kind not in _peak_warned:
-        _peak_warned.add(kind)
-        from ptype_tpu import logs
-
-        logs.get_logger("metrics").warning(
-            "unknown accelerator kind; MFU will use the v5e peak — "
-            "override with the env table",
-            kv={"device_kind": kind, "fallback_tflops":
-                PEAK_TFLOPS["v5e"], "env": PEAK_TFLOPS_ENV})
-    return PEAK_TFLOPS["v5e"]
+        return CPU_NOMINAL_TFLOPS
+    kind = device.device_kind
+    if kind not in PEAK_TFLOPS:
+        raise ValueError(
+            f"no peak TFLOP/s on record for device_kind {kind!r} "
+            f"(have {sorted(PEAK_TFLOPS)}); add it to "
+            "metrics.PEAK_TFLOPS with its source")
+    return PEAK_TFLOPS[kind]
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,
@@ -494,13 +427,14 @@ def memory_watermarks(device=None) -> dict:
     return out
 
 
-def record_memory_gauges(registry: MetricsRegistry | None = None) -> dict:
+def record_memory_gauges(registry: MetricsRegistry | None = None,
+                         device=None) -> dict:
     """Refresh the ``mem.*`` gauges from :func:`memory_watermarks` in
     ``registry`` (default: the process-global one) and return the raw
     dict — the seam serve.Info(), the telemetry endpoint, and the
     health sampler share."""
     reg = registry if registry is not None else metrics
-    wm = memory_watermarks()
+    wm = memory_watermarks(device)
     for key, value in wm.items():
         reg.gauge(f"mem.{key}").set(value)
     return wm

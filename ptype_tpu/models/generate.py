@@ -158,21 +158,26 @@ def prefill(params: dict, tokens: jax.Array, cfg: tfm.TransformerConfig,
     # TPU; explicit "flash" also forces the interpret-mode kernel on
     # CPU for tests) — dense prefill pays B·H·S² f32 scores exactly
     # where long-prompt serving hurts. Ragged (kv_mask) prompts keep
-    # the masked dense path (the kernel has no kv-mask support), and
-    # so do UNALIGNED lengths: S must be lane-aligned (128) or Mosaic
-    # rejects the block at compile time (the round-2 hardware failure
-    # class — serving buckets are pow2, so real callers qualify), and
-    # divide the clamped block size.
+    # the masked dense path: the kernel has no kv-mask, which is a
+    # different algorithm, not a shape the kernel could be handed. An
+    # UNALIGNED length does take the kernel: the block's sequence dim
+    # must be lane-aligned (128) and divide S, so q/k/v are
+    # right-padded to the next tile here — causal masking keeps every
+    # real row from seeing a pad key, so the slice back is exact.
     impl = cfg.attn_impl
     if impl == "auto":
         impl = tfm.default_attn_impl()
-    use_flash = (kv_mask is None and cfg.causal and impl == "flash"
-                 and S % 128 == 0 and S % min(1024, S) == 0)
-    if use_flash:
+    if kv_mask is None and cfg.causal and impl == "flash":
         from ptype_tpu.ops.flash_attention import flash_attention
 
+        tile = 128 if S <= 1024 else 1024
+        s_pad = -(-S // tile) * tile - S
+
         def attn(q, k, v):
-            return flash_attention(q, k, v, causal=True)
+            if s_pad:
+                pad = ((0, 0), (0, s_pad), (0, 0), (0, 0))
+                q, k, v = (jnp.pad(a, pad) for a in (q, k, v))
+            return flash_attention(q, k, v, causal=True)[:, :S]
     else:
         def attn(q, k, v):
             return tfm._attention(q, k, v, cfg, kv_mask=kv_mask)

@@ -313,7 +313,7 @@ def resolve_attn_fn(cfg: TransformerConfig, mesh=None):
     if impl == "flash":
         from ptype_tpu.ops.flash_attention import make_flash_attn_fn
 
-        return make_flash_attn_fn()
+        return make_flash_attn_fn(mesh)
     if impl in ("ring", "ulysses"):
         if mesh is None:
             raise ValueError(

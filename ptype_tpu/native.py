@@ -6,9 +6,11 @@ concatenation copy) and GIL-free exact reads. Loading is best-effort —
 ``available()`` is False and callers fall back to pure Python when the
 .so is absent and cannot be built (no compiler, read-only tree).
 
-Build explicitly with ``make native``; ``load()`` also attempts a
-one-time on-demand g++ build the first time it runs from a writable
-checkout.
+Build explicitly with ``make native``; ``load()`` also builds on
+demand, the first time it runs from a writable checkout, whenever the
+.so is missing or older than its source — the .so is git-ignored, so a
+binary left on disk by an earlier checkout must never outlive a change
+to ``native/ptype_wire.cpp``.
 """
 
 from __future__ import annotations
@@ -46,6 +48,14 @@ def _build() -> bool:
         return False
 
 
+def _stale() -> bool:
+    """True when the .so is missing or older than the source."""
+    try:
+        return os.path.getmtime(_SO) < os.path.getmtime(_SRC)
+    except OSError:
+        return not os.path.exists(_SO)
+
+
 def load() -> ctypes.CDLL | None:
     """The native library, building it on first use if possible.
 
@@ -60,7 +70,7 @@ def load() -> ctypes.CDLL | None:
         if _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _build():
+        if _stale() and not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)

@@ -37,8 +37,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ptype_tpu.parallel.topology import DATA_AXIS
 
 NEG_INF = -1e30
+
+#: ``pallas_call`` names of the three kernels — how a compiled module
+#: or a device trace identifies them (chip_smoke.py reads them back).
+KERNEL_NAMES = ("ptype_flash_fwd", "ptype_flash_dq", "ptype_flash_dkv")
 
 
 def _on_cpu() -> bool:
@@ -174,6 +181,7 @@ def _fwd(q, k, v, *, block_q: int, block_k: int, causal: bool,
             pltpu.VMEM((block_q, Dh), jnp.float32),     # acc
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[0],
     )(q, k, v)
     return (out[0], out[1]) if want_lse else (out[0], None)
 
@@ -332,6 +340,7 @@ def _flash_bwd(block_q, block_k, causal, interpret, res, do):
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, Dh), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAMES[1],
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid walks (kv head, k block) then the group's query heads
@@ -363,6 +372,7 @@ def _flash_bwd(block_q, block_k, causal, interpret, res, do):
             pltpu.VMEM((block_k, Dh), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAMES[2],
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -384,13 +394,19 @@ def flash_attention(q, k, v, causal: bool = True,
     presets use power-of-two seq. ``interpret`` defaults to True on CPU
     backends so tests validate the kernel without a TPU.
 
-    Default blocks are large (1024×1024): the grid-step count, not
-    VMEM, bounds throughput at these shapes — measured on v5e at the
-    125M train config (B=16/S=1024, dots-remat): 1024×1024 0.457 MFU,
-    512×1024 0.442, 512×512 0.422, 256×512 0.402, and 128×128 blocks
-    3.3× slower than 512+ (per-step overhead dominates the tiny
-    (128, Dh) MXU tiles). VMEM stays O(block): ~2.5 MB/program at
-    Dh=128 even at S=8192.
+    Default blocks are 1024×1024. VMEM is O(block), independent of S,
+    but it is not only the operand tiles: besides the double-buffered
+    (1024, Dh) q/k/v/o blocks (0.25 MiB each at Dh=128 bf16) every
+    kernel works on (1024, 1024) f32 score tiles — ``s`` and ``p`` in
+    the forward, ``s``/``p``/``dp``/``ds`` in the dk/dv kernel. At the
+    125M train shape (B=16, S=1024, H=K=6, Dh=128) the compiled module
+    reports 5.0 / 7.3 / 9.4 MiB of scoped VMEM for the forward / dq /
+    dkv kernel, under Mosaic's 16 MiB default, so none of the three
+    ``pallas_call``s passes compiler params. All three compile as
+    written and match the float32 reference on a "TPU v5 lite" at that
+    shape, at S=8192 GQA (H=8, K=2) and at Dh=64 (chip run, PR 21;
+    chip_smoke.py re-proves it). Block sizes have not been tuned on
+    the current installation.
     """
     if interpret is None:
         interpret = _on_cpu()
@@ -472,21 +488,43 @@ def check_tpu_lowering(B: int, H: int, S: int, Dh: int,
     return bad
 
 
-def make_flash_attn_fn(block_q: int = 1024, block_k: int = 1024):
+def make_flash_attn_fn(mesh=None, block_q: int = 1024,
+                       block_k: int = 1024):
     """attn_fn(q, k, v, cfg) for models/transformer.forward — the
-    ``attn_impl="flash"`` lowering. Shapes the kernel can't tile
-    (seq not divisible by the clamped block sizes — e.g. odd decode
-    lengths) fall back to the dense XLA path so "flash" is always safe
-    to set globally."""
+    ``attn_impl="flash"`` lowering. A sequence length the kernel cannot
+    tile raises (:func:`flash_attention` names the shape); there is no
+    dense substitute.
+
+    ``mesh``: the mesh of the jit the call sits in. A ``pallas_call``
+    is opaque to the SPMD partitioner: on a TPU backend JAX refuses to
+    lower a bare Mosaic kernel inside a multi-device jit ("Mosaic
+    kernels cannot be automatically partitioned"), which interpret
+    mode on the CPU mesh never showed. So on a multi-device mesh the
+    kernel runs under ``jax.shard_map`` on the local shard: batch over
+    the data-like axes (``data``/``fsdp``, the same ones
+    ``transformer.batch_spec`` uses), heads over ``model`` when both
+    head counts divide it. Callers already inside a fully manual
+    region (Ulysses, the pipeline stage ring, store-DP's per-worker
+    grads) pass no mesh."""
 
     def attn_fn(q, k, v, cfg):
-        S = q.shape[1]
-        bq, bk = min(block_q, S), min(block_k, S)
-        if S % bq or S % bk:
-            from ptype_tpu.models.transformer import _attention
-
-            return _attention(q, k, v, cfg)
         return flash_attention(q, k, v, causal=cfg.causal,
-                               block_q=bq, block_k=bk)
+                               block_q=block_q, block_k=block_k)
 
-    return attn_fn
+    if mesh is None or mesh.devices.size == 1:
+        return attn_fn
+
+    batch_axes = tuple(a for a in (DATA_AXIS, "fsdp")
+                       if a in mesh.axis_names) or None
+    n_model = int(mesh.shape.get("model", 1))
+
+    def sharded_attn_fn(q, k, v, cfg):
+        heads = ("model" if n_model > 1 and q.shape[2] % n_model == 0
+                 and k.shape[2] % n_model == 0 else None)
+        spec = P(batch_axes, None, heads, None)
+        return jax.shard_map(
+            functools.partial(attn_fn, cfg=cfg), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return sharded_attn_fn

@@ -37,6 +37,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: ``pallas_call`` name — how a compiled module or a device trace
+#: identifies the kernel (chip_smoke.py reads it back).
+KERNEL_NAME = "ptype_paged_attention"
 #: f32 Mosaic tile: (sublanes, lanes).
 SUBLANES = 8
 LANES = 128
@@ -151,6 +154,7 @@ def paged_attention(q, kc, vc, tables, pos,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kh, G, Dh), q.dtype),
         interpret=interpret,
+        name=KERNEL_NAME,
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), qh, kt, vt)
     return o.reshape(B, 1, H, Dh)
 

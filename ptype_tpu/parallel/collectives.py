@@ -19,10 +19,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ptype_tpu.compat import axis_size, shard_map
 from ptype_tpu.parallel.mesh import axis_n
 from ptype_tpu.parallel.topology import (INNER_AXIS, OUTER_AXIS,
                                          Topology)

@@ -25,10 +25,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ptype_tpu.compat import shard_map
 from ptype_tpu.errors import ClusterError
 
 

@@ -25,10 +25,8 @@ import math
 from functools import partial
 
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ptype_tpu.compat import shard_map
 
 NEG_INF = jnp.float32(-1e30)
 

@@ -64,11 +64,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ptype_tpu import chaos
-from ptype_tpu.compat import shard_map
 from ptype_tpu.errors import CheckpointError, ClusterError
 from ptype_tpu.parallel.mesh import axis_n
 from ptype_tpu.parallel.collectives import (Bucket, DEFAULT_BUCKET_BYTES,

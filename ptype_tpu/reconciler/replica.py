@@ -35,6 +35,7 @@ escalation) completes.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import subprocess
@@ -522,15 +523,26 @@ def _spawn_fault(name: str) -> None:
 class LocalLauncher:
     """Spawn replicas IN-PROCESS (real sockets, real registry, the
     full control surface — just no process isolation): the launcher
-    for tests, chaos drills, and simulated fleets. The reconciler
-    cannot tell it apart from :class:`ProcessLauncher`."""
+    for tests, chaos drills, simulated fleets — and for fleets on
+    accelerator chips, where one process must hold every chip of the
+    host. The reconciler cannot tell it apart from
+    :class:`ProcessLauncher`.
+
+    ``devices``: the chips to place replicas on. Each spawn calls
+    ``actor_factory(device=d)`` with the device that currently holds
+    the fewest live replicas (read back from each actor's ``device``),
+    so a four-replica fleet on a four-chip host lands one per chip
+    instead of stacking on ``jax.devices()[0]``. Without it the
+    factory is called bare."""
 
     def __init__(self, registry: Registry, actor_factory,
                  warmup=None, service: str = "llm",
                  generator_name: str = "Generator",
-                 metrics_registry=None, domain: int | None = None):
+                 metrics_registry=None, domain: int | None = None,
+                 devices=None):
         self._registry = registry
         self._actor_factory = actor_factory
+        self._devices = list(devices or ())
         self._warmup = warmup
         self._service = service
         self._generator_name = generator_name
@@ -544,9 +556,16 @@ class LocalLauncher:
     def spawn(self, name: str, warm_hold: bool = False,
               domain: int | None = None) -> LocalReplicaHandle:
         _spawn_fault(name)
+        factory = self._actor_factory
+        if self._devices:
+            with self._lock:
+                live = [getattr(h.actor, "device", None)
+                        for h in self.hosts if not h.exiting]
+            factory = functools.partial(
+                factory, device=min(self._devices, key=live.count))
         host = ReplicaHost(
             self._registry, self._service, name,
-            self._actor_factory, warmup=self._warmup,
+            factory, warmup=self._warmup,
             generator_name=self._generator_name, warm_hold=warm_hold,
             metrics_registry=self._metrics_registry,
             domain=domain if domain is not None else self._domain)
@@ -568,7 +587,16 @@ class ProcessLauncher:
     to the cluster through the coordinator address like any other
     member. The worker writes a ready file (host/port/pid) once its
     server answers; spawn blocks on it (bounded), then returns a
-    control handle. Replica kind:
+    control handle.
+
+    For CPU hosts and control-plane drills. Every worker starts with
+    this process's environment and initialises the default JAX
+    backend, and an accelerator chip belongs to ONE process: on a TPU
+    host the second worker — or any worker of a parent that has itself
+    touched JAX — fails or hangs at backend init. Fleets on chips are
+    in-process (:class:`LocalLauncher` with ``devices=``, one replica
+    per chip), so ``kind="paged"`` refuses to construct when this
+    process's default backend is a TPU. Replica kind:
 
     - ``fake``  — :class:`FakeGeneratorActor` (control-plane drills);
     - ``paged`` — the real :class:`~ptype_tpu.serve_engine.engine.
@@ -600,6 +628,16 @@ class ProcessLauncher:
                  env: dict | None = None,
                  serve_class: str = "unified",
                  domain: int | None = None):
+        if kind == "paged":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                raise ClusterError(
+                    "ProcessLauncher(kind='paged'): this process holds "
+                    "a TPU, and a chip belongs to one process — spawned "
+                    "workers would fail or hang at backend init. Run "
+                    "on-chip fleets in-process: "
+                    "LocalLauncher(..., devices=jax.devices())")
         self.coordinator_address = coordinator_address
         self.service = service
         self.kind = kind

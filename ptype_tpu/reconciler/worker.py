@@ -62,24 +62,36 @@ def _actor_factory(kind: str, preset: str):
         delay_s = float(os.environ.get("PTYPE_REPLICA_DELAY_S", "0"))
         return (lambda: FakeGeneratorActor(delay_s=delay_s)), None
     if kind == "paged":
-        def make():
+        def make(device=None):
+            # ``device``: handed out by an in-process LocalLauncher
+            # (one replica per chip); a worker process owns its
+            # backend's default device.
             from ptype_tpu.models import transformer as tfm
             from ptype_tpu.serve_engine.engine import PagedGeneratorActor
 
             serve_class = os.environ.get("PTYPE_REPLICA_SERVE_CLASS",
                                          "unified")
             return PagedGeneratorActor(tfm.preset(preset),
-                                       serve_class=serve_class)
+                                       serve_class=serve_class,
+                                       device=device)
 
         def warmup(actor):
             import jax.numpy as jnp
             import numpy as np
 
-            # One 1-token generate: the decode/prefill programs
-            # compile NOW, so activation never pays a cold compile in
-            # a scale-up's critical path.
-            out = actor.Generate(jnp.ones((1, 4), jnp.int32), 1)
-            np.asarray(out)
+            # Compile what traffic runs, NOW, so activation never pays
+            # a cold compile in a scale-up's critical path: one prompt
+            # per prefill-chunk bucket (16, 32, ... up to the chunk —
+            # distinct tokens, or prefix reuse would shrink the later
+            # ones into the first bucket), each decoding three tokens
+            # (a 1-token generate finishes inside prefill and never
+            # builds the decode step; the third token runs it on its
+            # own outputs).
+            n, fill = 16, 1
+            while n <= min(actor.prefill_chunk, actor.reach - 3):
+                out = actor.Generate(jnp.full((1, n), fill, jnp.int32), 3)
+                np.asarray(out)
+                n, fill = n * 2, fill + 1
 
         return make, warmup
     if kind == "custom":
@@ -116,10 +128,15 @@ def main() -> None:
     dom_raw = os.environ.get("PTYPE_REPLICA_DOMAIN", "")
     domain = int(dom_raw) if dom_raw else None
 
+    from ptype_tpu import compile_cache
     from ptype_tpu.coord.remote import RemoteCoord
     from ptype_tpu.reconciler.replica import ReplicaHost
     from ptype_tpu.registry import CoordRegistry
 
+    if kind != "fake":
+        # A spawned replica must not compile the model from cold when
+        # a sibling (or its own previous life) already did.
+        compile_cache.configure()
     coord = RemoteCoord([coord_addr])
     registry = CoordRegistry(coord)
     factory, warmup = _actor_factory(kind, preset)

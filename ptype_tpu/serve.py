@@ -48,12 +48,24 @@ class GeneratorActor:
     Serializes requests (one decode loop at a time per actor — the
     single-chip serving model; scale out by registering more actors
     under the same service and letting the balancer spread callers).
+
+    ``device``: the device this replica lives on. Params are committed
+    there, and every jitted program follows them, so several replicas
+    in one process (one per chip) do not stack on ``jax.devices()[0]``.
+    None leaves placement to JAX's default device.
     """
 
     def __init__(self, cfg: tfm.TransformerConfig, params=None,
-                 rng: jax.Array | None = None):
+                 rng: jax.Array | None = None, device=None):
         self.cfg = cfg
+        self.device = device
         rng = rng if rng is not None else jax.random.PRNGKey(0)
+        if device is not None:
+            # Committed inputs pin the init program (and its outputs)
+            # to the device; a given params tree is moved there.
+            rng = jax.device_put(rng, device)
+            if params is not None:
+                params = jax.device_put(params, device)
         self.params = (params if params is not None
                        else jax.jit(lambda r: tfm.init_params(r, cfg))(rng))
         self._lock = lockcheck.lock("serve.actor.decode")
@@ -173,7 +185,8 @@ class GeneratorActor:
             # Device HBM watermarks (RSS fallback) — refreshed into the
             # mem.* gauges as a side effect, so the health plane's
             # sampler/alerts see the same numbers the probe reads.
-            "memory": metrics_mod.record_memory_gauges(),
+            "memory": metrics_mod.record_memory_gauges(
+                device=self.device),
         }
 
 

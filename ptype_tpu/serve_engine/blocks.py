@@ -90,7 +90,7 @@ class BlockPool:
     """
 
     def __init__(self, cfg: tfm.TransformerConfig, n_blocks: int,
-                 block_tokens: int):
+                 block_tokens: int, device=None):
         if block_tokens % SUBLANES:
             raise ValueError(
                 f"block_tokens {block_tokens} must divide by "
@@ -102,10 +102,11 @@ class BlockPool:
         self.n_blocks = int(n_blocks)
         shape = (cfg.n_layers, n_blocks, block_tokens, cfg.kv_heads,
                  cfg.head_dim)
-        #: The banks. The engine owns these references — jitted
-        #: steps/prefills donate and replace them.
-        self.k = jnp.zeros(shape, cfg.dtype)
-        self.v = jnp.zeros(shape, cfg.dtype)
+        #: The banks, committed to ``device`` when one is given. The
+        #: engine owns these references — jitted steps/prefills donate
+        #: and replace them.
+        self.k = jnp.zeros(shape, cfg.dtype, device=device)
+        self.v = jnp.zeros(shape, cfg.dtype, device=device)
         self._lock = lockcheck.lock("serve_engine.pool")
         # Block 0 never allocated: the trash target for masked writes.
         self._free: list[int] = list(range(1, n_blocks))

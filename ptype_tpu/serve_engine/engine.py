@@ -193,7 +193,9 @@ class PagedGeneratorActor(GeneratorActor):
     target-verify, exact-distribution acceptance (greedy output stays
     bit-identical to the non-speculative engine; per-slot accept
     lengths make iterations ragged, which the retirement path already
-    tolerates).
+    tolerates); ``device`` the device the replica lives on — params
+    and both block pools are committed there (see
+    :class:`~ptype_tpu.serve.GeneratorActor`).
     """
 
     def __init__(self, cfg: tfm.TransformerConfig, params=None,
@@ -205,8 +207,8 @@ class PagedGeneratorActor(GeneratorActor):
                  attn: str = "gather",
                  spec: SpecConfig | None = None,
                  metrics_registry: metrics_mod.MetricsRegistry | None
-                 = None, serve_class: str = "unified"):
-        super().__init__(cfg, params, rng)
+                 = None, serve_class: str = "unified", device=None):
+        super().__init__(cfg, params, rng, device=device)
         #: Registry the engine's gauges/histograms land in (default:
         #: the process-global one; drills and simulated multi-replica
         #: fleets pass a per-node registry so each replica's series
@@ -227,7 +229,7 @@ class PagedGeneratorActor(GeneratorActor):
         self.nb = self.reach // bt
         n_blocks = (int(n_blocks) if n_blocks
                     else self.n_slots * self.nb + 1)
-        self.pool = BlockPool(cfg, n_blocks, bt)
+        self.pool = BlockPool(cfg, n_blocks, bt, device=device)
         self.prefill_chunk = (int(prefill_chunk) if prefill_chunk
                               else self.reach)
         self.max_queue = int(max_queue)
@@ -284,7 +286,8 @@ class PagedGeneratorActor(GeneratorActor):
                     f"target vocab {cfg.vocab_size}")
             if int(spec.k) < 1:
                 raise ValueError(f"spec.k must be >= 1, got {spec.k}")
-            self._dpool = BlockPool(spec.draft_cfg, n_blocks, bt)
+            self._dpool = BlockPool(spec.draft_cfg, n_blocks, bt,
+                                    device=device)
         #: Current proposal depth (adaptive-k backs this off; 0 =
         #: speculation disabled pending a re-probe).
         self._k_cur = int(spec.k) if spec is not None else 0
@@ -890,7 +893,11 @@ class PagedGeneratorActor(GeneratorActor):
         fail every pending row, or callers hang in done.wait()."""
         err: Exception | None = None
         try:
-            self._engine_loop()
+            # The slot state this thread uploads each iteration lands
+            # on the replica's own device, next to its params and
+            # banks, not on jax.devices()[0].
+            with jax.default_device(self.device):
+                self._engine_loop()
         except Exception as e:  # noqa: BLE001 — delivered to callers
             err = e
             log.warning("paged engine died", kv={"err": repr(e)})
@@ -1269,17 +1276,18 @@ class PagedGeneratorActor(GeneratorActor):
                 self._sdev = None
         sampled = bool((self._temps[self._active] > 0.0).any())
         if self._dev is None:
-            self._dev = {
-                "tok": jnp.asarray(self._tok),
-                "pos": jnp.asarray(self._pos),
-                "tables": jnp.asarray(self._tables),
-                "active": jnp.asarray(self._active),
-                "keys": jnp.asarray(self._keys),
-                "eidx": jnp.asarray(self._eidx),
-                "temps": jnp.asarray(self._temps),
-                "topk": jnp.asarray(self._topk),
-                "topp": jnp.asarray(self._topp),
-            }
+            # device_put, not jnp.asarray: on a placed replica the
+            # step's outputs are COMMITTED to its device, and tok/pos/
+            # eidx feed straight back in — a fresh upload must carry
+            # the same commitment or the second step of every request
+            # sees a new signature and compiles again (chip run, PR 21).
+            self._dev = jax.device_put({
+                "tok": self._tok, "pos": self._pos,
+                "tables": self._tables, "active": self._active,
+                "keys": self._keys, "eidx": self._eidx,
+                "temps": self._temps, "topk": self._topk,
+                "topp": self._topp,
+            }, self.device)
         d = self._dev
         self._steps += 1
         self._max_live = max(self._max_live, int(self._active.sum()))

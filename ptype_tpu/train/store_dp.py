@@ -158,7 +158,12 @@ class StoreDPTrainer:
         self.optimizer = optimizer or default_optimizer()
         rng = rng if rng is not None else jax.random.PRNGKey(0)
 
-        params = jax.jit(lambda r: tfm.init_params(r, cfg))(rng)
+        # Replicated over the mesh from the start — the placement every
+        # later step's params carry (the apply program's outputs), so
+        # the grads program compiles once, not once for the seed
+        # placement and again for the steady one.
+        params = jax.jit(lambda r: tfm.init_params(r, cfg),
+                         out_shardings=NamedSharding(self.mesh, P()))(rng)
         # overlap=True with the default recipe trains through
         # _bucket_states — and zero=True through the 1/N-resident
         # ZeroState — NOT this whole-tree state: leave it None so a
@@ -236,27 +241,44 @@ class StoreDPTrainer:
         #: under zero=2/3) — the bench ladder's grad column.
         self.last_grad_bytes: int | None = None
 
-        # Per-worker grad fn, vmapped over the stacked worker batch dim —
-        # one compiled program computing every worker's local grads, laid
-        # out sharded over the data axis (SPMD over the mesh).
-        def local_grads(params, batch):
-            loss, grads = jax.value_and_grad(tfm.loss_fn)(
-                params, batch, cfg
-            )
-            return loss, grads
-
         # Under zero=3 the gathered param leaves are TRANSIENT: they
         # live only for the forward (locals of _step) and die when it
         # returns — the resident footprint stays the sharded flats,
         # and the apply program's donation (parallel/zero.py
         # _shard_apply3_fn, pinned by progaudit) keeps the update
         # in-place on those flats.
-        self._grads_fn = jax.jit(jax.vmap(local_grads, in_axes=(None, 0)))
+        #: The jitted per-worker ``(params, stacked batch) -> (losses,
+        #: grads)`` program — public for inspection, like
+        #: ``Trainer.train_step``.
+        self.grads_step = self._make_grads_fn()
         self._apply_fn = make_apply_fn(self.optimizer)
         #: (params avals, stacked-batch avals) stashed on the first
         #: step — what compiled_cost() lowers the cost programs
         #: against without holding batch data.
         self._cost_avals: tuple | None = None
+
+    def _make_grads_fn(self):
+        """Per-worker grad fn: one compiled program in which every
+        device along the store axis computes ITS worker's loss and
+        grads on its own shard of the stacked batch, results stacked
+        back over the axis. A ``shard_map``, not a ``vmap`` left to
+        the SPMD partitioner: the partitioner cannot split the flash
+        kernel's custom call, and on a TPU backend JAX refuses to lower
+        one in a multi-device jit (see
+        ``ops/flash_attention.make_flash_attn_fn``)."""
+        cfg = self.cfg
+        stack = lambda x: x[None]  # noqa: E731
+
+        def local_grads(params, batch):
+            # The local block keeps the stacked worker dim at size 1.
+            loss, grads = jax.value_and_grad(tfm.loss_fn)(
+                params, jax.tree.map(lambda x: x[0], batch), cfg)
+            return stack(loss), jax.tree.map(stack, grads)
+
+        per_worker = P(self.axis)
+        return jax.jit(jax.shard_map(
+            local_grads, mesh=self.mesh, in_specs=(P(), per_worker),
+            out_specs=(per_worker, per_worker), check_vma=False))
 
     def params(self) -> dict:
         """The current parameter tree. Served from the locally-kept
@@ -345,7 +367,7 @@ class StoreDPTrainer:
             # whole dispatch chain (grads → reduce → apply): the batch
             # already staged through the sanctioned seam, so anything
             # else crossing the host boundary here is a leak.
-            losses, grads = self._grads_fn(params, stacked)
+            losses, grads = self.grads_step(params, stacked)
 
             if self.zero_stage == 1:
                 self._reduce_apply_zero1(grads)
@@ -562,6 +584,7 @@ class StoreDPTrainer:
             self.mesh = mesh
             self.axis = axis
             self.n_workers = new_n
+            self.grads_step = self._make_grads_fn()
             if self.zero_stage == 3:
                 for bi, flat in enumerate(self._zero.pflat):
                     self.store.commit_sharded(

@@ -155,3 +155,48 @@ def test_dead_member_does_not_block_join():
     finally:
         c1.close()
         c3.close()
+
+
+def test_mesh_member_whose_backend_is_down_fails_its_join(monkeypatch):
+    """A platform config with mesh_axes names a mesh member: if its JAX
+    backend does not come up, join raises — it used to register the
+    member with no devices and carry on."""
+    import jax
+
+    def down():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "local_devices", down)
+    with pytest.raises(ClusterError, match="backend did not come up"):
+        join(local_cfg("trainer", "t0", mesh_axes={"data": 1}))
+    # A control-plane process (no axes) never asks the backend.
+    join(local_cfg("gateway", "g0")).close()
+
+
+def test_mesh_defaults_to_every_device_and_names_what_it_leaves_out(
+        caplog):
+    """No mesh_axes: every visible device on ``data`` (the documented
+    trainer command used to take one chip of four without a word). An
+    explicit smaller layout logs the devices it leaves idle."""
+    import logging
+
+    import jax
+
+    c = join(local_cfg("trainer", "t0"))
+    try:
+        mesh = c.mesh()
+        assert dict(mesh.shape) == {"data": jax.device_count()}
+    finally:
+        c.close()
+    c = join(local_cfg("trainer", "t1", mesh_axes={"data": 2}))
+    lg = logging.getLogger("ptype_tpu.cluster")
+    lg.addHandler(caplog.handler)  # the package logger does not propagate
+    try:
+        with caplog.at_level(logging.WARNING, logger="ptype_tpu.cluster"):
+            mesh = c.mesh()
+        assert mesh.devices.size == 2
+        assert any("leaves devices out" in r.getMessage()
+                   for r in caplog.records)
+    finally:
+        lg.removeHandler(caplog.handler)
+        c.close()

@@ -9,6 +9,7 @@ just the spec table) is exercised in the fast tier."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ptype_tpu.ops.flash_attention import (LANES, _fwd,
                                            check_tpu_lowering,
@@ -77,3 +78,46 @@ def test_interpret_mode_forward_emits_lse_and_grads_flow():
 
     grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     assert all(np.isfinite(np.asarray(g)).all() for g in grads)
+
+
+def test_multi_device_trainer_runs_the_kernel_on_the_local_shard(
+        monkeypatch):
+    """A ``pallas_call`` is opaque to the SPMD partitioner: JAX refuses
+    to lower a bare Mosaic kernel inside a multi-device jit. The
+    Trainer's flash path runs under ``shard_map``: lowered for TPU
+    (cross-lowering needs no chip), the step holds the three named
+    kernels, each at batch B/n — and heads H/m on a model axis."""
+    import importlib
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from chip_smoke import lowered_kernels
+
+    from ptype_tpu.models import transformer as tfm
+    from ptype_tpu.parallel.mesh import build_mesh
+    from ptype_tpu.train.data import synthetic_batches
+    from ptype_tpu.train.trainer import Trainer
+
+    fa = importlib.import_module("ptype_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_on_cpu", lambda: False)  # no interpreter
+    cfg = tfm.preset("tiny", attn_impl="flash")  # H = K = 4, Dh = 16
+    B, S = 8, 32
+
+    def kernels(axes, attn_fn=None):
+        tr = Trainer(cfg, build_mesh(axes), attn_fn=attn_fn)
+        batch = tr.shard_batch(
+            next(synthetic_batches(cfg.vocab_size, B, S)))
+        text = tr.train_step.trace(tr.state, batch).lower(
+            lowering_platforms=("tpu",)).as_text()
+        return lowered_kernels(text)
+
+    seen = kernels({"data": 4})
+    assert {n for n, _ in seen} == set(fa.KERNEL_NAMES)
+    assert all(d == (B // 4, 4, S, 16) for _, d in seen), seen
+    seen = kernels({"data": 2, "model": 2})
+    assert seen and all(d == (B // 2, 2, S, 16) for _, d in seen), seen
+    # What the shard_map replaced: the bare kernel does not lower.
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        kernels({"data": 4}, attn_fn=fa.make_flash_attn_fn())

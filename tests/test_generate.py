@@ -279,9 +279,6 @@ def test_prefill_flash_matches_dense():
     base = tfm.preset("tiny", dtype=jnp.float32)
     flash = tfm.preset("tiny", dtype=jnp.float32, attn_impl="flash")
     params = tfm.init_params(jax.random.PRNGKey(0), base)
-    # S=128: the gate requires lane alignment (unaligned lengths would
-    # be Mosaic compile failures on hardware — they stay dense), so
-    # anything smaller would silently test dense-vs-dense.
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 128), 0,
                               base.vocab_size, jnp.int32)
     ld, cd = gen.prefill(params, toks, base,
@@ -293,9 +290,8 @@ def test_prefill_flash_matches_dense():
     np.testing.assert_allclose(np.asarray(cf.k), np.asarray(cd.k),
                                rtol=2e-5, atol=2e-5)
     # Ragged prompts keep the masked dense path (kernel has no
-    # kv-mask): same call must still work with lens given. Unaligned
-    # S likewise stays dense rather than feeding the kernel an
-    # unpadded block.
+    # kv-mask): same call must still work with lens given. An
+    # unaligned S takes the kernel too, right-padded to the lane tile.
     lens = jnp.asarray([100, 128], jnp.int32)
     lr, _ = gen.prefill(params, toks, flash,
                         gen.init_cache(flash, 2, max_seq=128),
@@ -308,4 +304,4 @@ def test_prefill_flash_matches_dense():
         np.asarray(gen.prefill(params, toks[:, :100], base,
                                gen.init_cache(base, 2,
                                               max_seq=128))[0]),
-        rtol=2e-5, atol=2e-5)
+        rtol=2e-4, atol=2e-4)

@@ -93,3 +93,22 @@ def test_rpc_over_native_wire(lib):
         conn.close()
     finally:
         srv.close()
+
+
+def test_a_binary_older_than_its_source_is_stale(tmp_path, monkeypatch):
+    """The .so is git-ignored: one left on disk by an earlier checkout
+    must be rebuilt once native/ptype_wire.cpp is newer (and built when
+    it is missing), never loaded as-is."""
+    import os
+
+    src, so = tmp_path / "wire.cpp", tmp_path / "wire.so"
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_SO", str(so))
+    src.write_text("// source")
+    assert native._stale()  # missing
+    so.write_bytes(b"\x7fELF")
+    os.utime(so, (1_000, 1_000))
+    os.utime(src, (2_000, 2_000))
+    assert native._stale()  # older than the source
+    os.utime(so, (3_000, 3_000))
+    assert not native._stale()

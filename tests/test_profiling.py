@@ -322,59 +322,24 @@ def test_measure_compiled_cost_gap_within_10pct_on_125m():
     assert abs(out["mfu_gap_pct"]) <= 10.0, out
 
 
-# ------------------------------------------------- peak-TFLOPS override
+# ---------------------------------------------------- peak-TFLOPS table
 
 
-def test_device_peak_tflops_override_env_and_fallback(monkeypatch):
-    # Flat env override wins for whatever chip this process sees.
-    monkeypatch.setenv(metrics_mod.PEAK_TFLOPS_ENV, "123.5")
-    assert metrics_mod.device_peak_tflops() == 123.5
-    # kind=value pairs extend the substring table.
-    monkeypatch.setenv(metrics_mod.PEAK_TFLOPS_ENV, "cpu=7.5")
-    assert metrics_mod.device_peak_tflops() == 7.5
-    # Malformed entries are ignored, not fatal.
-    monkeypatch.setenv(metrics_mod.PEAK_TFLOPS_ENV, "garbage=x,,")
-    assert metrics_mod.device_peak_tflops() == \
-        metrics_mod.PEAK_TFLOPS["cpu"]
-    monkeypatch.delenv(metrics_mod.PEAK_TFLOPS_ENV)
-    # Process-level pin wins over everything.
-    metrics_mod.set_peak_tflops(42.0)
-    try:
-        assert metrics_mod.device_peak_tflops() == 42.0
-    finally:
-        metrics_mod.set_peak_tflops(None)
-
-
-def test_unknown_accelerator_falls_back_and_logs_once():
-    import logging
-
-    class _FakeDev:
-        device_kind = "tpu v99 weirdchip"
+def test_device_peak_tflops_is_keyed_by_exact_device_kind():
+    class _Dev:
         platform = "tpu"
 
-    class _Sink(logging.Handler):
-        def __init__(self):
-            super().__init__()
-            self.records = []
+        def __init__(self, kind):
+            self.device_kind = kind
 
-        def emit(self, record):
-            self.records.append(record)
-
-    metrics_mod._peak_warned.discard("tpu v99 weirdchip")
-    sink = _Sink()
-    # The package root logger has propagate=False (logs.py), so hook
-    # the metrics logger directly.
-    lg = logging.getLogger("ptype_tpu.metrics")
-    lg.addHandler(sink)
-    try:
-        a = metrics_mod.device_peak_tflops(_FakeDev())
-        b = metrics_mod.device_peak_tflops(_FakeDev())
-    finally:
-        lg.removeHandler(sink)
-    assert a == b == metrics_mod.PEAK_TFLOPS["v5e"]
-    hits = [r for r in sink.records
-            if "unknown accelerator" in r.getMessage()]
-    assert len(hits) == 1  # once per kind, not once per MFU
+    assert metrics_mod.device_peak_tflops(_Dev("TPU v5 lite")) == 197.0
+    # A v5p reports "TPU v5": a substring table priced it as a v5e.
+    for kind in ("TPU v5", "tpu v5 lite", "TPU v99 weirdchip"):
+        with pytest.raises(ValueError, match="device_kind"):
+            metrics_mod.device_peak_tflops(_Dev(kind))
+    # The CPU backend keeps a nominal figure (host-mesh test runs).
+    assert metrics_mod.device_peak_tflops() == \
+        metrics_mod.CPU_NOMINAL_TFLOPS
 
 
 # ------------------------------------------- end-to-end chaos drill

@@ -50,12 +50,10 @@ def test_pure_callback_is_flagged():
 
 
 def test_f64_drift_is_flagged_and_allow_f64_waives():
-    from jax.experimental import enable_x64
-
     def drift(x):
         return x.astype(jnp.float64).sum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         rep = progaudit.audit(
             drift, (jax.ShapeDtypeStruct((4,), jnp.float32),),
             name="drift")
@@ -71,7 +69,7 @@ def test_unfused_collective_count_breaks_the_contract():
     regression the launch-count invariant exists to catch."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ptype_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(jax.devices(), ("data",))
 
@@ -194,7 +192,7 @@ def test_split_bucket_two_reduce_scatters_breaks_the_pin():
     what a total-count-only check would if it summed to the same."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ptype_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(jax.devices(), ("data",))
     n = jax.device_count()
@@ -223,7 +221,7 @@ def test_sneaky_grad_allgather_breaks_the_zero2_pin():
     {all_gather: 0} pin even though reduce_scatter still counts 1."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ptype_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(jax.devices(), ("data",))
     n = jax.device_count()
@@ -248,7 +246,7 @@ def test_per_leaf_param_gathers_break_the_zero3_pin():
     per leaf instead of one per flat bucket."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from ptype_tpu.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(jax.devices(), ("data",))
     n = jax.device_count()

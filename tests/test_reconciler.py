@@ -699,6 +699,46 @@ def test_paged_engine_drain_seam():
 # -------------------------------------------------- obs renders (CLI)
 
 
+def test_local_launcher_hands_out_the_least_loaded_device(coord):
+    """``devices=``: each spawn goes to the device holding the fewest
+    live replicas, so an on-chip fleet lands one replica per chip; a
+    killed replica's device is the next one handed out."""
+    placed = []
+
+    def factory(device=None):
+        placed.append(device)
+        actor = FakeGeneratorActor()
+        actor.device = device  # what a placed actor reports
+        return actor
+
+    launcher = LocalLauncher(CoordRegistry(coord, lease_ttl=2.0), factory,
+                             devices=["chip0", "chip1", "chip2"])
+    try:
+        handles = [launcher.spawn(f"r{i}") for i in range(4)]
+        assert placed == ["chip0", "chip1", "chip2", "chip0"]
+        handles[1].kill()
+        launcher.spawn("r4")
+        assert placed[-1] == "chip1"
+    finally:
+        launcher.close()
+
+
+def test_process_launcher_refuses_paged_workers_on_a_tpu_host(
+        monkeypatch):
+    """A chip belongs to one process: spawned paged workers would fail
+    or hang at backend init when the parent already holds the TPU."""
+    import jax
+
+    from ptype_tpu.errors import ClusterError
+    from ptype_tpu.reconciler import ProcessLauncher
+
+    ProcessLauncher("127.0.0.1:1", kind="paged")  # CPU host: fine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ClusterError, match="one process"):
+        ProcessLauncher("127.0.0.1:1", kind="paged")
+    ProcessLauncher("127.0.0.1:1", kind="fake")  # control plane: fine
+
+
 def test_lifecycle_names_pinned_in_sync():
     from ptype_tpu.health import top as top_mod
     from ptype_tpu.serve import LIFECYCLES

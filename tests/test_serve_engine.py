@@ -777,3 +777,33 @@ def test_steady_state_decode_compiles_nothing_armed(jitwatch_watchdog):
         assert jw.recompiles() == {} and jw.storms() == []
     finally:
         actor.close()
+
+
+def test_placed_replica_lives_on_its_device_and_compiles_its_step_once(
+        jitwatch_watchdog):
+    """``device=``: params and banks are committed there, output
+    matches a default-placed replica token for token, and the decode
+    step compiles ONCE — its own committed outputs feed the next step,
+    so the first upload must carry the same commitment (on the chip an
+    uncommitted first upload cost every replica a second compile in the
+    middle of its first request)."""
+    jw = jitwatch_watchdog
+    dev = jax.devices()[3]
+    p = _prompt(5)
+    plain = PagedGeneratorActor(CFG, n_slots=2)
+    try:
+        want = np.asarray(plain.Generate(p, 6))
+    finally:
+        plain.close()
+    before = jw.compiles().get("engine_step", 0)
+    actor = PagedGeneratorActor(CFG, n_slots=2, device=dev)
+    try:
+        got = np.asarray(actor.Generate(p, 6))
+        homes = actor.pool.k.devices() | actor.pool.v.devices()
+        for leaf in jax.tree.leaves(actor.params):
+            homes |= leaf.devices()
+        assert homes == {dev}
+        np.testing.assert_array_equal(got, want)
+        assert jw.compiles()["engine_step"] - before == 1, jw.compiles()
+    finally:
+        actor.close()

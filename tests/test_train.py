@@ -260,34 +260,6 @@ def test_grad_accum_transparent_with_uneven_mask(tiny):
     np.testing.assert_allclose(losses[1][1], losses[4][1], rtol=1e-4)
 
 
-def test_trainer_attn_impl_flash_calls_pallas(tiny, monkeypatch):
-    """attn_impl='flash' resolves to the Pallas kernel and the Trainer
-    actually runs it (VERDICT r1 weak #2: the field must be read)."""
-    from dataclasses import replace
-
-    import importlib
-
-    # The ops package re-exports the flash_attention FUNCTION, which
-    # shadows the submodule attribute — resolve the module itself.
-    fa = importlib.import_module("ptype_tpu.ops.flash_attention")
-
-    calls = {"n": 0}
-    real = fa.flash_attention
-
-    def spy(*a, **kw):
-        calls["n"] += 1
-        return real(*a, **kw)
-
-    monkeypatch.setattr(fa, "flash_attention", spy)
-    cfg = replace(tiny, attn_impl="flash")
-    mesh = build_mesh({"data": 2})
-    tr = Trainer(cfg, mesh)
-    it = _batches(cfg)
-    out = tr.step(next(it))
-    assert np.isfinite(float(out["loss"]))
-    assert calls["n"] > 0
-
-
 def test_resolve_attn_fn_auto(monkeypatch):
     """'auto' → flash on TPU backends, dense XLA elsewhere."""
     cfg = tfm.preset("tiny")  # attn_impl defaults to "auto"
