@@ -1,0 +1,209 @@
+"""A tiny copy of the benchmark's data for CPU tests: the shipped
+manifest and metric files, with configurations and traffic cut to sizes a
+test run can hold. The code under test is the shipped ``benchmark``
+package; only the data it is pointed at is small."""
+
+import json
+import os
+import shutil
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+#: The long-context mix at ISSUE 24's own sizes (what the chip runs of
+#: PR 24 used, with the pool cut to 3,072 blocks): the next benchmark PR
+#: ships it as ``benchmark/traffic/longctx.json``.
+LONGCTX_TRAFFIC = {
+    "kind": "closed",
+    "clients": 16,
+    "max_rps": 4.0,
+    "sizes_seed": 20240925,
+    "documents": {
+        "count": 6,
+        "asks": [
+            3,
+            5
+        ],
+        "length": {
+            "dist": "uniform",
+            "min": 6144,
+            "max": 12288,
+            "quantum": 512
+        }
+    },
+    "question": {
+        "dist": "uniform",
+        "min": 32,
+        "max": 128,
+        "quantum": 16
+    },
+    "output": {
+        "dist": "uniform",
+        "min": 64,
+        "max": 128,
+        "quantum": 8
+    },
+    "sampling": "greedy",
+    "engine": {
+        "n_slots": 8,
+        "max_len": 16384,
+        "block_tokens": 16,
+        "n_blocks": 3072,
+        "prefill_chunk": 512,
+        "max_queue": 4096,
+        "admit_timeout_s": 0
+    },
+    "gateway": {
+        "per_replica_inflight": 4096,
+        "max_queue_depth": 4096,
+        "default_deadline_s": 600.0,
+        "probe_interval_s": 1.0
+    },
+    "deadline_s": 600.0,
+    "drain_s": 120.0,
+    "check_sample": 3,
+    "check_bucket": 1024
+}
+
+TINY_MODEL = {
+    "hidden_size": 64, "num_hidden_layers": 2, "num_attention_heads": 4,
+    "head_dim": 16, "intermediate_size": 128, "vocab_size": 256,
+    "rope_theta": 10000.0, "rms_norm_eps": 1e-06,
+    "initializer_range": 0.02}
+ENGINE = {"n_slots": 4, "max_len": 128, "block_tokens": 16, "n_blocks": 64,
+          "prefill_chunk": 32, "max_queue": 4096, "admit_timeout_s": 0}
+GATEWAY = {"per_replica_inflight": 4096, "max_queue_depth": 4096,
+           "default_deadline_s": 600.0, "probe_interval_s": 1.0}
+
+
+STORE_CELL = "optimus-125m.train-store-4chip"
+LONG_CELL = "mistral-7b.serve-longctx"
+ENGINE_STEP = "^jit_engine_step\\("
+#: ISSUE 24's cell 4 (PERF.md §7: runs correctly on the chip, too unsteady
+#: to judge): name → (layer, unit, better, source, metric file).
+LONG_METRICS = {
+    "req_e2e_p50_ms.longctx": ("gateway rpc", "ms", "lower", "host_clock", {
+        "reader": "counter", "params": {"key": "req_e2e_p50_ms"}}),
+    "prefix_hit_pct.longctx": ("block pool", "%", "higher", "program_counter", {
+        "reader": "counter", "params": {"key": "prefix_hit_pct"}}),
+    "compiles_in_window.longctx": ("entry points", "count", "lower",
+                                   "program_counter", {
+        "reader": "counter", "params": {"key": "compiles_in_window"}}),
+    "engine_host_pct.longctx": ("engine loop", "%", "lower", "device_trace", {
+        "reader": "span_no_device_pct", "params": {"span": "serve.step"}}),
+    "step_mfu.longctx": ("paged step", "%", "higher", "device_trace", {
+        "reader": "step_mfu", "params": {"flops_key": "model_flops_traced",
+                                         "basis": "window"}}),
+    "decode_hbm_roofline.longctx": ("paged step", "%", "higher",
+                                    "device_trace", {
+        "reader": "decode_hbm_roofline", "params": {"pattern": ENGINE_STEP}}),
+    "device_idle_pct.longctx": ("device", "%", "lower", "device_trace", {
+        "reader": "device_idle_pct", "params": {}}),
+}
+STEP = "^jit_(step|local_grads)\\("
+
+
+def manifest() -> dict:
+    """The shipped manifest plus ISSUE 24's two cells that are not in it
+    yet (PERF.md §7): the four-chip Store cell and the long-context
+    closed loop, so that the paths they need — ``StoreDPTrainer``, the
+    exchange fault, the collective readers, the closed-loop driver —
+    stay rehearsed until a later PR adds the cells as data."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        m = json.load(f)
+    train1 = next(w for w in m["workloads"]
+                  if w["name"] == "optimus-125m.train-s1024")
+    m["workloads"].append({**train1, "name": STORE_CELL, "chips": 4,
+                           "traffic": "train-store-s1024"})
+    for x in m["end_to_end"] + m["per_layer"]:
+        if train1["name"] in x.get("workloads", ()):
+            x["workloads"].append(STORE_CELL)
+    for name in ("collective_ms.train4", "collective_exposed_ms.train4"):
+        m["per_layer"].append({
+            "name": name, "unit": "ms", "better": "lower",
+            "source": "device_trace", "layer": "collectives",
+            "moves": "train_tok_s_chip", "workloads": [STORE_CELL]})
+    chat = next(w for w in m["workloads"]
+                if w["name"] == "mistral-7b.serve-chat")
+    m["workloads"].append({**chat, "name": LONG_CELL, "traffic": "longctx"})
+    m["end_to_end"].append({
+        "name": "serve_tok_s", "unit": "tokens/s", "better": "higher",
+        "bound": 0.1, "source": "host_clock", "workloads": [LONG_CELL]})
+    for x in m["end_to_end"]:
+        if x["name"] == "setup_s" and "workloads" in x:
+            x["workloads"].append(LONG_CELL)
+    for name, (layer, unit, better, source, _) in LONG_METRICS.items():
+        m["per_layer"].append({
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": layer, "moves": "serve_tok_s",
+            "workloads": [LONG_CELL]})
+    return m
+
+
+def make_root(tmp: str) -> str:
+    m = manifest()
+    bench = os.path.join(tmp, "benchmark")
+    for d in ("configs", "traffic", "limits"):
+        os.makedirs(os.path.join(bench, d), exist_ok=True)
+    os.makedirs(os.path.join(tmp, "tests", "benchmark"), exist_ok=True)
+    shutil.copytree(os.path.join(ROOT, "benchmark", "metrics"),
+                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "optimus-125m.json")) as f:
+        training = json.load(f)["training"]
+
+    def put(rel, obj):
+        with open(os.path.join(bench, rel), "w") as f:
+            json.dump(obj, f)
+
+    for name, spec in LONG_METRICS.items():
+        put(f"metrics/{name}.json", spec[-1])
+    put("metrics/collective_ms.train4.json",
+        {"reader": "collective_ms", "params": {"step_pattern": STEP}})
+    put("metrics/collective_exposed_ms.train4.json",
+        {"reader": "collective_ms",
+         "params": {"step_pattern": STEP, "exposed": True}})
+
+    put("configs/optimus-125m.json", {
+        **TINY_MODEL, "num_key_value_heads": 4,
+        "tie_word_embeddings": True, "param_dtype": "float32",
+        "training": training})
+    put("configs/mistral-7b.json", {
+        **TINY_MODEL, "vocab_size": 4096, "num_key_value_heads": 2,
+        "tie_word_embeddings": False, "param_dtype": "bfloat16"})
+    train = {"kind": "train", "seq": 64, "per_chip_batch": 2,
+             "check_steps": 3, "reference_micro_rows": 2}
+    put("traffic/train-s1024.json", {**train, "trainer": "gspmd"})
+    put("traffic/train-store-s1024.json", {**train, "trainer": "store"})
+    put("traffic/chat.json", {
+        "kind": "open", "rate_rps": 12.0, "sizes_seed": 7,
+        "shared_prefixes": {"count": 2, "tokens": 32},
+        "suffix": {"dist": "lognormal", "median": 24, "sigma": 0.5,
+                   "min": 16, "max": 48, "quantum": 16},
+        "output": {"dist": "lognormal", "median": 12, "sigma": 0.5,
+                   "min": 4, "max": 24, "quantum": 2},
+        "engine": ENGINE, "gateway": GATEWAY, "check_sample": 6,
+        "check_bucket": 64})
+    put("traffic/longctx.json", {
+        "kind": "closed", "clients": 3, "max_rps": 40.0, "sizes_seed": 8,
+        "documents": {"count": 2, "asks": [2, 3],
+                      "length": {"dist": "uniform", "min": 48, "max": 80,
+                                 "quantum": 16}},
+        "question": {"dist": "uniform", "min": 16, "max": 32,
+                     "quantum": 16},
+        "output": {"dist": "uniform", "min": 4, "max": 8, "quantum": 2},
+        "engine": ENGINE, "gateway": GATEWAY, "check_sample": 2,
+        "check_bucket": 64})
+    # Set as the shipped ones are, from readings at these sizes on the
+    # CPU (four seeds): train, program <= 3.2e-5 / 8.1e-4 / 4.5e-4,
+    # float8 control >= 3.2e-3 / 1.7e-3 on the two norms, half a batch
+    # >= 2.1e-4 on the loss; serve, program <= 3.9e-4, control >= 1.7e-2.
+    train = {"loss_step3_rel": 1e-4, "grad_norm_gap_worst_leaf": 1.6e-3,
+             "change_norm_gap_worst_leaf": 9e-4, "final_loss_finite": 0.0}
+    serve = {"served_logit_gap_max": 3e-3, "requests_failed": 0.0}
+    for w in m["workloads"]:
+        put(f"limits/{w['name']}.json",
+            train if w["name"].startswith("optimus") else serve)
+    return tmp
