@@ -411,7 +411,7 @@ def phase_serve(cluster, preset: str, *, max_new: int = 8,
                     jitwatch.disable()
             if errs:
                 raise errs[0]
-            check(not {"engine_step", "run"} & set(in_window),
+            check(not {"engine_step", "prefill_chunk"} & set(in_window),
                   "the engine compiled inside the serving window, after "
                   f"warm-up: {in_window}")
             check(all(o is not None and o.shape == (max_new,)

@@ -27,10 +27,19 @@ def configure() -> str:
     """Point JAX at the persistent compile cache; returns the directory
     in use. Must run before the process's first compilation — JAX
     opens the cache once."""
+    import jax
+
+    # An executable carries its HLO metadata (op_name: the model's
+    # named scopes; source lines), and JAX leaves metadata out of the
+    # cache key by default: a hit would then bring back the names of
+    # whichever commit compiled the program first, and a device trace
+    # would show those (chip run, PR 25: the decode step came back from
+    # PR 24's cache without a single scope). Keyed on metadata too, a
+    # program is compiled again when its names or lines move.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      True)
     env_dir = os.environ.get(ENV_VAR)
     if env_dir:
         return env_dir
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     return DEFAULT_DIR

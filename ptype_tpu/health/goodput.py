@@ -5,7 +5,7 @@ breakdown only as spans a human loads into Perfetto. This module makes
 both a live, always-on account:
 
 - :class:`GoodputLedger` listens on the ``metrics.annotate`` seam
-  (:func:`ptype_tpu.metrics.set_annotate_observer`) — the one hook
+  (:func:`ptype_tpu.trace.set_region_observer`) — the one hook
   train/store_dp.py, train/trainer.py, and parallel/tensorstore.py
   already run their regions through — and folds every finished region
   into a per-step record: ``data`` (``train.data``), ``collective``
@@ -40,6 +40,7 @@ import threading
 import time
 
 from ptype_tpu import metrics as metrics_mod
+from ptype_tpu import trace
 
 #: Steps of history a ledger keeps.
 LEDGER_WINDOW = 512
@@ -171,11 +172,11 @@ class GoodputLedger:
     def install(self) -> "GoodputLedger":
         """Become the process's annotate observer: every
         ``metrics.annotate`` region now feeds this ledger."""
-        metrics_mod.set_annotate_observer(self.observe)
+        trace.set_region_observer(self.observe)
         return self
 
     def uninstall(self) -> None:
-        metrics_mod.set_annotate_observer(None)
+        trace.set_region_observer(None)
 
     # ------------------------------------------------------------ ledger
 

@@ -57,6 +57,8 @@ in ``bench.py --serve``'s tail, the <1%-per-engine-iteration bar).
 from __future__ import annotations
 
 import collections
+import contextlib
+import itertools
 import time
 
 from ptype_tpu import lockcheck
@@ -84,14 +86,17 @@ class RequestRecord:
     synthesized spans on the cluster's shared timeline.
     """
 
-    __slots__ = ("tp", "prompt_tokens", "max_new", "reused_blocks",
+    __slots__ = ("rid", "tp", "prompt_tokens", "max_new", "reused_blocks",
                  "t_enqueue", "w_enqueue", "t_head", "t_admit",
                  "chunks", "t_first", "w_first", "tok_t",
                  "t_done", "reason", "closed", "t_mig0", "w_mig0",
                  "t_mig1", "migrate_blocks", "migrate_bytes")
 
     def __init__(self, prompt_tokens: int, max_new: int,
-                 tp: str | None):
+                 tp: str | None, rid: int = 0):
+        #: The request's id on this ledger: in every region and span
+        #: the request leaves, with tracing on or off.
+        self.rid = rid
         self.tp = tp
         self.prompt_tokens = int(prompt_tokens)
         self.max_new = int(max_new)
@@ -140,6 +145,27 @@ class RequestRecord:
             return None
         return max(0.0, self.t_first - self.t_enqueue)
 
+    def first_token_split(self) -> dict:
+        """The ``serve.first_token`` record: where this request's
+        first-token time went, in ms. ``queue_ms + reserve_ms +
+        admitted_ms`` is its ``ttft_s``. ``prefill_host_ms`` (the sum
+        of its chunk meters) is NOT its prefill time: under async
+        dispatch a non-final chunk's meter closes at dispatch and the
+        final chunk's host sync pays for all of them."""
+        queue_s, reserve_s = self.queue_wait_s(), self.reserve_wait_s()
+        return {
+            "rid": self.rid,
+            "prompt_tokens": self.prompt_tokens,
+            "reused_blocks": self.reused_blocks,
+            "chunks": len(self.chunks),
+            "queue_ms": round(queue_s * 1e3, 3),
+            "reserve_ms": round(reserve_s * 1e3, 3),
+            "admitted_ms": round(
+                (self.ttft_s() - queue_s - reserve_s) * 1e3, 3),
+            "prefill_host_ms": round(
+                sum(c[1] for c in self.chunks) * 1e3, 3),
+        }
+
     def tpot_s(self) -> float | None:
         """Mean inter-token time after the first token."""
         if self.t_first is None or self.t_done is None:
@@ -165,6 +191,7 @@ class RequestRecord:
         ttft = self.ttft_s()
         tpot = self.tpot_s()
         d = {
+            "rid": self.rid,
             "t": round(self.w_enqueue, 3),
             "prompt_tokens": self.prompt_tokens,
             "max_new": self.max_new,
@@ -194,11 +221,22 @@ class RequestRecord:
         return d
 
 
+def _region(rec: RequestRecord, name: str, **attrs):
+    """One of a request's own regions (its chunks, its
+    ``serve.first_token`` and ``serve.retire`` records), through the
+    one seam. A live profiler capture always takes it; the flight
+    recorder takes it under the request's own trace, so a request that
+    carried no traceparent leaves no orphan spans there."""
+    if trace.capturing() or (rec.tp is not None and trace.enabled()):
+        return trace.span_from(rec.tp, name, **attrs)
+    return contextlib.nullcontext()
+
+
 class _ChunkMeter:
     """Times one prefill chunk into its record + the ledger's
     per-iteration prefill accumulator."""
 
-    __slots__ = ("_led", "_rec", "tokens", "dur_s", "_t0", "_w0")
+    __slots__ = ("_led", "_rec", "tokens", "dur_s", "_t0", "_w0", "_sp")
 
     def __init__(self, led: "ServingLedger", rec: RequestRecord,
                  tokens: int):
@@ -208,6 +246,9 @@ class _ChunkMeter:
         self.dur_s = 0.0
 
     def __enter__(self) -> "_ChunkMeter":
+        self._sp = _region(self._rec, "serve.prefill/chunk",
+                           rid=self._rec.rid, tokens=self.tokens)
+        self._sp.__enter__()
         self._w0 = time.time()
         self._t0 = time.perf_counter()
         return self
@@ -219,6 +260,7 @@ class _ChunkMeter:
         with led._lock:
             led._iter_prefill_s += self.dur_s
             led._iter_prefill_tokens += self.tokens
+        self._sp.__exit__(*exc)
         return False
 
 
@@ -302,6 +344,7 @@ class ServingLedger:
         self.g_active = reg.gauge("serve.active_slots")
         self.g_stall = reg.gauge("serve.stall_ms")
         self._lock = lockcheck.lock("health.serving.ledger")
+        self._rids = itertools.count(1)
         self._records: collections.deque = collections.deque(
             maxlen=int(window))
         self._iters: collections.deque = collections.deque(
@@ -335,7 +378,8 @@ class ServingLedger:
         traceparent (captured inside the actor handler span) the
         synthesized span tree will parent under."""
         self.registry.counter("serve.requests").add(1)
-        return RequestRecord(prompt_tokens, max_new, tp)
+        return RequestRecord(prompt_tokens, max_new, tp,
+                             rid=next(self._rids))
 
     def head_refused(self, rec: RequestRecord) -> float:
         """The head-of-line reservation was refused; returns seconds
@@ -354,9 +398,14 @@ class ServingLedger:
         return _ChunkMeter(self, rec, tokens)
 
     def first_token(self, rec: RequestRecord) -> None:
+        """The TTFT stamp; and, into whatever is listening (a live
+        profiler capture, the flight recorder), one ``serve.first_token``
+        region carrying the record's split of its first-token time."""
         rec.w_first = time.time()
         rec.t_first = time.perf_counter()
         rec.tok_t.append(rec.t_first)
+        with _region(rec, "serve.first_token", **rec.first_token_split()):
+            pass
 
     def migrate_begin(self, rec: RequestRecord) -> None:
         """Decode-side migration plan accepted (blocks reserved,
@@ -435,6 +484,9 @@ class ServingLedger:
             self._reasons[rec.reason] = \
                 self._reasons.get(rec.reason, 0) + 1
             self._records.append(rec.to_dict())
+        with _region(rec, "serve.retire", rid=rec.rid, reason=rec.reason,
+                     tokens_out=len(rec.tok_t)):
+            pass
         self._emit_spans(rec)
 
     # ------------------------------------------------- iteration seams
@@ -598,6 +650,7 @@ class ServingLedger:
         admit.dur_s = max(0.0, (anchor or rec.t_enqueue)
                           - rec.t_enqueue)
         admit.attrs = {
+            "rid": rec.rid,
             "queue_wait_ms": round(rec.queue_wait_s() * 1e3, 3),
             "reserve_wait_ms": round(rec.reserve_wait_s() * 1e3, 3),
             "prompt_tokens": rec.prompt_tokens,
@@ -618,7 +671,7 @@ class ServingLedger:
             sp = trace.Span("serve.migrate", trace_id, parent_id)
             sp.start_s = rec.w_mig0
             sp.dur_s = mig
-            sp.attrs = {"blocks": rec.migrate_blocks,
+            sp.attrs = {"rid": rec.rid, "blocks": rec.migrate_blocks,
                         "bytes": rec.migrate_bytes,
                         "dedup_blocks": rec.reused_blocks,
                         "stage": "migrate"}
@@ -628,13 +681,14 @@ class ServingLedger:
                             parent_id)
             sp.start_s = w0
             sp.dur_s = dur
-            sp.attrs = {"tokens": tokens, "stage": "prefill"}
+            sp.attrs = {"rid": rec.rid, "tokens": tokens,
+                        "stage": "prefill"}
             recd.record(sp)
         if rec.t_first is not None:
             dec = trace.Span("serve.decode", trace_id, parent_id)
             dec.start_s = rec.w_first
             dec.dur_s = max(0.0, rec.t_done - rec.t_first)
-            dec.attrs = {"tokens": len(rec.tok_t),
+            dec.attrs = {"rid": rec.rid, "tokens": len(rec.tok_t),
                          "reason": rec.reason,
                          "stage": "decode",
                          "ttft_ms": round(rec.ttft_s() * 1e3, 3)}

@@ -500,95 +500,16 @@ class MetricsWriter:
 # The reference had zap logging only (SURVEY.md §5 "Tracing/profiling:
 # Absent"); the TPU build owes JAX profiler traces (XPlane/TensorBoard)
 # with annotated steps so Store collective time is attributable.
+# Captures are taken by ``health/profiling.start``/``stop``.
 
-
-class trace:
-    """Context manager: capture a JAX profiler trace (XPlane) to
-    ``logdir`` — view with TensorBoard's profile plugin or xprof.
-
-    >>> with metrics.trace("/tmp/trace"):
-    ...     trainer.step(batch)
-    """
-
-    def __init__(self, logdir: str):
-        self.logdir = logdir
-
-    def __enter__(self):
-        jax.profiler.start_trace(self.logdir)
-        return self
-
-    def __exit__(self, *exc):
-        jax.profiler.stop_trace()
-        return False
-
-
-#: Observer for finished annotate() regions — ``fn(name, dur_s)``.
-#: The health plane's goodput ledger installs itself here, so every
-#: train.step / store.push_tree / checkpoint region feeds the per-step
-#: breakdown through the one existing seam.
-_annotate_observer = None
-
-
-def set_annotate_observer(fn) -> None:
-    """Install (or clear, with ``None``) the region observer. One
-    observer per process — the goodput ledger; tests that need several
-    ledgers drive them directly via ``GoodputLedger.region``."""
-    global _annotate_observer
-    _annotate_observer = fn
-
-
-class _AnnotatedSpan:
-    """TraceAnnotation + distributed-trace span + region observer
-    entered as one scope — profiler timelines, the flight recorder,
-    and the goodput ledger see the same region."""
-
-    __slots__ = ("_ann", "_sp", "_name", "_obs", "_t0")
-
-    def __init__(self, ann, sp, name, obs):
-        self._ann = ann
-        self._sp = sp
-        self._name = name
-        self._obs = obs
-
-    def __enter__(self):
-        self._ann.__enter__()
-        self._sp.__enter__()
-        if self._obs is not None:
-            self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        if self._obs is not None:
-            dt = time.perf_counter() - self._t0
-            try:
-                self._obs(self._name, dt)
-            except Exception:  # noqa: BLE001 — telemetry must never
-                pass           # kill the training step it observes,
-                #                nor leak the span/annotation scopes.
-        self._sp.__exit__(*exc)
-        return self._ann.__exit__(*exc)
-
-
-def annotate(name: str, **kwargs):
-    """Named region in profiler traces (host + device timeline). Use
-    around Store pushes so allreduce time is attributable:
-
-    >>> with metrics.annotate("store.push/grads"):
-    ...     store.push_tree("grads", grads)
-
-    When distributed tracing is armed (:mod:`ptype_tpu.trace`), the
-    region ALSO opens a span of the same name — store pushes and train
-    steps nest inside both the jax profiler trace and the request's
-    distributed trace through this one seam. When a region observer is
-    installed (:func:`set_annotate_observer` — the goodput ledger),
-    the region's wall time is reported to it on exit. With neither
-    armed the cost stays one ``enabled()`` check + one global load.
-    """
-    ann = jax.profiler.TraceAnnotation(name, **kwargs)
-    obs = _annotate_observer
-    if obs is None and not trace_mod.enabled():
-        return ann
-    return _AnnotatedSpan(ann, trace_mod.span(name), name, obs)
+#: A named region in profiler traces (host timeline, on the device
+#: trace's clock), in the flight recorder and for the goodput ledger:
+#: :func:`ptype_tpu.trace.span`, the one function that opens a region,
+#: under the name the train/store side has always imported.
+#:
+#: >>> with metrics.annotate("store.push/grads"):
+#: ...     store.push_tree("grads", grads)
+annotate = trace_mod.span
 
 
 def step_annotation(step: int):

@@ -66,6 +66,7 @@ def init_cache(cfg: tfm.TransformerConfig, batch: int,
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
 
 
+@jax.named_scope("attn")
 def _cached_attention(q, k_cache, v_cache, pos_limit, cfg,
                       valid_from=None):
     """q: (B, 1, H, Dh); caches: (B, Smax, Kh, Dh); attend to
@@ -132,7 +133,8 @@ def prefill(params: dict, tokens: jax.Array, cfg: tfm.TransformerConfig,
     final real token sits at column L_i - 1, and decode writes grow
     from L_i, overwriting the never-attended pad garbage)."""
     B, S = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
     if prompt_lens is None:
         sin, cos = tfm.rope_tables(cfg, S)
         kv_mask = None
@@ -185,19 +187,23 @@ def prefill(params: dict, tokens: jax.Array, cfg: tfm.TransformerConfig,
     def body(x, inputs):
         layer, kc, vc = inputs
         q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        o = attn(q, k, v)
+        with jax.named_scope("attn"):
+            o = attn(q, k, v)
         x = tfm.attn_residual(x, o, layer, cfg)
         x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=cap)
-        kc = lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
+        with jax.named_scope("kv_write"):
+            kc = lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
         return x, (kc, vc)
 
     x, (kcs, vcs) = lax.scan(body, x,
                              (params["blocks"], cache.k, cache.v))
-    x = tfm.rms_norm(x, params["final_norm"])
-    x_last = (x[:, -1] if last_index is None
-              else x[jnp.arange(B), last_index])
-    return _head_logits(params, x_last, cfg), KVCache(kcs, vcs)
+    with jax.named_scope("head"):
+        x = tfm.rms_norm(x, params["final_norm"])
+        x_last = (x[:, -1] if last_index is None
+                  else x[jnp.arange(B), last_index])
+        logits = _head_logits(params, x_last, cfg)
+    return logits, KVCache(kcs, vcs)
 
 
 def decode_step(params: dict, token: jax.Array, pos: jax.Array,
@@ -215,7 +221,8 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
     its first real cache slot — slot and position coincide only in the
     uniform-length case."""
     B = token.shape[0]
-    x = params["embed"][token][:, None, :].astype(cfg.dtype)  # (B, 1, D)
+    with jax.named_scope("embed"):
+        x = params["embed"][token][:, None, :].astype(cfg.dtype)  # (B, 1, D)
     if rope_pos is None:
         sin, cos = tfm.rope_tables(cfg, positions=jnp.asarray(pos)[None])
     else:
@@ -224,8 +231,9 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
     def body(x, inputs):
         layer, kc, vc = inputs  # kc/vc: (B, Smax, Kh, Dh)
         q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        kc = lax.dynamic_update_slice(kc, k, (0, pos, 0, 0))
-        vc = lax.dynamic_update_slice(vc, v, (0, pos, 0, 0))
+        with jax.named_scope("kv_write"):
+            kc = lax.dynamic_update_slice(kc, k, (0, pos, 0, 0))
+            vc = lax.dynamic_update_slice(vc, v, (0, pos, 0, 0))
         o = _cached_attention(q, kc, vc, pos + 1, cfg,
                               valid_from=valid_from)
         x = tfm.attn_residual(x, o, layer, cfg)
@@ -234,8 +242,10 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
 
     x, (kcs, vcs) = lax.scan(body, x,
                              (params["blocks"], cache.k, cache.v))
-    x = tfm.rms_norm(x, params["final_norm"])
-    return _head_logits(params, x[:, 0], cfg), KVCache(kcs, vcs)
+    with jax.named_scope("head"):
+        x = tfm.rms_norm(x, params["final_norm"])
+        logits = _head_logits(params, x[:, 0], cfg)
+    return logits, KVCache(kcs, vcs)
 
 
 def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
@@ -255,24 +265,26 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
     nb = tables.shape[1]
     bt = kc.shape[1]
     Kh = kc.shape[2]
-    ks = kc[tables].reshape(B, nb * bt, Kh, Dh)
-    vs = vc[tables].reshape(B, nb * bt, Kh, Dh)
-    G = H // Kh
-    qg = q.reshape(B, Q, Kh, G, Dh)
-    scores = jnp.einsum("bqkgd,bskd->bkgqs", qg,
-                        ks).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(Dh))
-    cols = jnp.arange(nb * bt)
-    pos_limit = jnp.asarray(pos_limit)
-    if pos_limit.ndim == 1:
-        mask = cols[None, None, :] < pos_limit[:, None, None]
-    else:  # (B, Q) per-query
-        mask = cols[None, None, :] < pos_limit[:, :, None]
-    scores = jnp.where(mask[:, None, None, :, :], scores,
-                       jnp.float32(-1e30))
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", probs, vs)
-    return o.reshape(B, Q, H, Dh)
+    with jax.named_scope("kv_gather"):
+        ks = kc[tables].reshape(B, nb * bt, Kh, Dh)
+        vs = vc[tables].reshape(B, nb * bt, Kh, Dh)
+    with jax.named_scope("attn"):
+        G = H // Kh
+        qg = q.reshape(B, Q, Kh, G, Dh)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qg,
+                            ks).astype(jnp.float32)
+        scores = scores / jnp.sqrt(jnp.float32(Dh))
+        cols = jnp.arange(nb * bt)
+        pos_limit = jnp.asarray(pos_limit)
+        if pos_limit.ndim == 1:
+            mask = cols[None, None, :] < pos_limit[:, None, None]
+        else:  # (B, Q) per-query
+            mask = cols[None, None, :] < pos_limit[:, :, None]
+        scores = jnp.where(mask[:, None, None, :, :], scores,
+                           jnp.float32(-1e30))
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        o = jnp.einsum("bkgqs,bskd->bqkgd", probs, vs)
+        return o.reshape(B, Q, H, Dh)
 
 
 def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
@@ -298,19 +310,22 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     the default is the XLA gather path. Returns
     ``(logits (B, V), kb, vb)``."""
     B = token.shape[0]
-    x = params["embed"][token][:, None, :].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][token][:, None, :].astype(cfg.dtype)
     sin, cos = tfm.rope_tables(cfg, positions=pos[:, None])
 
     def body(x, inputs):
         layer, kc, vc = inputs  # (n_blocks, block_tokens, Kh, Dh)
         q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        kc = kc.at[wr_blocks, wr_off].set(k[:, 0])
-        vc = vc.at[wr_blocks, wr_off].set(v[:, 0])
+        with jax.named_scope("kv_write"):
+            kc = kc.at[wr_blocks, wr_off].set(k[:, 0])
+            vc = vc.at[wr_blocks, wr_off].set(v[:, 0])
         if attn_impl == "kernel":
             from ptype_tpu.ops.paged_attention import paged_attention
 
-            o = paged_attention(q, kc, vc, tables, pos,
-                                interpret=interpret)
+            with jax.named_scope("attn"):
+                o = paged_attention(q, kc, vc, tables, pos,
+                                    interpret=interpret)
         else:
             o = _paged_attention_gather(q, kc, vc, tables, pos + 1,
                                         cfg)
@@ -319,8 +334,10 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
         return x, (kc, vc)
 
     x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
-    x = tfm.rms_norm(x, params["final_norm"])
-    return _head_logits(params, x[:, 0], cfg), kb, vb
+    with jax.named_scope("head"):
+        x = tfm.rms_norm(x, params["final_norm"])
+        logits = _head_logits(params, x[:, 0], cfg)
+    return logits, kb, vb
 
 
 def prefill_paged_chunk(params: dict, tokens: jax.Array,
@@ -342,7 +359,8 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
     B, C = tokens.shape
     bt = kb.shape[2]
     nb = table.shape[0]
-    x = params["embed"][tokens].astype(cfg.dtype)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
     pos_vec = start + jnp.arange(C)  # (C,) positions of chunk columns
     sin, cos = tfm.rope_tables(cfg, positions=pos_vec[None])
     valid = jnp.arange(C) < length
@@ -359,8 +377,9 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
     def body(x, inputs):
         layer, kc, vc = inputs
         q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        kc = kc.at[wr_b, wr_o].set(k[0])
-        vc = vc.at[wr_b, wr_o].set(v[0])
+        with jax.named_scope("kv_write"):
+            kc = kc.at[wr_b, wr_o].set(k[0])
+            vc = vc.at[wr_b, wr_o].set(v[0])
         o = _paged_attention_gather(q, kc, vc, table[None],
                                     limits[None], cfg)
         x = tfm.attn_residual(x, o, layer, cfg)
@@ -368,9 +387,11 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
         return x, (kc, vc)
 
     x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
-    x = tfm.rms_norm(x, params["final_norm"])
-    x_last = x[jnp.arange(B), jnp.asarray(length)[None] - 1]
-    return _head_logits(params, x_last, cfg), kb, vb
+    with jax.named_scope("head"):
+        x = tfm.rms_norm(x, params["final_norm"])
+        x_last = x[jnp.arange(B), jnp.asarray(length)[None] - 1]
+        logits = _head_logits(params, x_last, cfg)
+    return logits, kb, vb
 
 
 # ------------------------------------------------- speculative decoding
@@ -430,7 +451,8 @@ def verify_step_paged(params: dict, tokens: jax.Array,
     position-limit mask hides them until a later token overwrites them
     (rollback is a position rewind, never a reallocation)."""
     B, W = tokens.shape
-    x = params["embed"][tokens].astype(cfg.dtype)  # (B, W, D)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)  # (B, W, D)
     pos = pos0[:, None] + jnp.arange(W)[None, :]   # (B, W)
     sin, cos = tfm.rope_tables(cfg, positions=pos)
     limits = pos + 1  # (B, W): per-query causal limits
@@ -441,16 +463,19 @@ def verify_step_paged(params: dict, tokens: jax.Array,
     def body(x, inputs):
         layer, kc, vc = inputs
         q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        kc = kc.at[wr_b, wr_o].set(k)
-        vc = vc.at[wr_b, wr_o].set(v)
+        with jax.named_scope("kv_write"):
+            kc = kc.at[wr_b, wr_o].set(k)
+            vc = vc.at[wr_b, wr_o].set(v)
         o = _paged_attention_gather(q, kc, vc, tables, limits, cfg)
         x = tfm.attn_residual(x, o, layer, cfg)
         x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=cap)
         return x, (kc, vc)
 
     x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
-    x = tfm.rms_norm(x, params["final_norm"])
-    return _head_logits(params, x, cfg), kb, vb
+    with jax.named_scope("head"):
+        x = tfm.rms_norm(x, params["final_norm"])
+        logits = _head_logits(params, x, cfg)
+    return logits, kb, vb
 
 
 def draft_propose_paged(params: dict, tok: jax.Array,
@@ -489,14 +514,16 @@ def draft_propose_paged(params: dict, tok: jax.Array,
         tok, kb, vb = carry
         j, wb, wo = inputs
         pos = pos0 + j  # (B,)
-        x = params["embed"][tok][:, None, :].astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"][tok][:, None, :].astype(cfg.dtype)
         sin, cos = tfm.rope_tables(cfg, positions=pos[:, None])
 
         def body(x, inp):
             layer, kc, vc = inp
             q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-            kc = kc.at[wb, wo].set(k[:, 0])
-            vc = vc.at[wb, wo].set(v[:, 0])
+            with jax.named_scope("kv_write"):
+                kc = kc.at[wb, wo].set(k[:, 0])
+                vc = vc.at[wb, wo].set(v[:, 0])
             o = _paged_attention_gather(q, kc, vc, tables, pos + 1,
                                         cfg)
             x = tfm.attn_residual(x, o, layer, cfg)
@@ -504,13 +531,15 @@ def draft_propose_paged(params: dict, tok: jax.Array,
             return x, (kc, vc)
 
         x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
-        x = tfm.rms_norm(x, params["final_norm"])
-        lg = _head_logits(params, x[:, 0], cfg)  # (B, V) f32
-        if sampled:
-            nxt = sample_token_rows(lg, dkeys, steps0 + j, temps,
-                                    top_ks, top_ps)
-        else:
-            nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            x = tfm.rms_norm(x, params["final_norm"])
+            lg = _head_logits(params, x[:, 0], cfg)  # (B, V) f32
+        with jax.named_scope("sample"):
+            if sampled:
+                nxt = sample_token_rows(lg, dkeys, steps0 + j, temps,
+                                        top_ks, top_ps)
+            else:
+                nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
         return (nxt, kb, vb), (nxt, lg)
 
     (_, kb, vb), (toks, lgs) = lax.scan(

@@ -20,6 +20,15 @@ anything:
   GSPMD inserts the collectives (ICI-mapped; scaling-book recipe).
 - **Remat.** ``cfg.remat`` wraps the block body in ``jax.checkpoint`` to
   trade FLOPs for HBM.
+- **Named scopes.** Every part of a step is a ``jax.named_scope`` with a
+  fixed name, the same in training, prefill and decode because the code
+  is shared: ``embed``, ``qkv`` (norm, projections, RoPE), ``kv_write``
+  and ``kv_gather`` (models/generate.py), ``attn``, ``attn_out``,
+  ``mlp``, ``head`` (final norm + LM head), ``loss``, ``optimizer``
+  (train/trainer.py), ``sample`` (serve_engine/engine.py). A scope is
+  HLO metadata only: it names the operation in a device trace (the
+  profiler's ``tf_op`` stat) and changes no compiled program.
+  ``benchmark/readers/scope_time_pct.py`` buckets device time by them.
 """
 
 from __future__ import annotations
@@ -401,6 +410,7 @@ def _moe_mlp(h, layer, cfg: TransformerConfig, capacity: int | None = None):
     return y.reshape(B, S, D), aux
 
 
+@jax.named_scope("qkv")
 def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos):
     """Pre-norm + Q/K/V projections + RoPE. x: (B, S, D) → three
     (B, S, H|K, Dh). Shared by training forward and the KV-cache
@@ -414,12 +424,14 @@ def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos):
     return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
+@jax.named_scope("attn_out")
 def attn_residual(x, o, layer, cfg: TransformerConfig):
     """Output projection + residual add. o: (B, S, H, Dh)."""
     return x + jnp.einsum("bshk,hkd->bsd", o,
                           layer["wo"].astype(cfg.dtype))
 
 
+@jax.named_scope("mlp")
 def mlp_residual(x, layer, cfg: TransformerConfig,
                  moe_capacity: int | None = None):
     """Pre-norm MLP (dense SwiGLU or MoE) + residual. → (x, aux)."""
@@ -440,7 +452,8 @@ def _block(x, layer, sin, cos, cfg: TransformerConfig, attn_fn):
     """One transformer block; x: (B, S, D) in compute dtype.
     Returns (x, moe_aux) — aux is 0.0 for dense MLPs."""
     q, k, v = qkv_proj(x, layer, cfg, sin, cos)
-    o = attn_fn(q, k, v, cfg)
+    with jax.named_scope("attn"):
+        o = attn_fn(q, k, v, cfg)
     x = attn_residual(x, o, layer, cfg)
     return mlp_residual(x, layer, cfg)
 
@@ -454,7 +467,8 @@ def hidden_with_aux(params: dict, tokens: jax.Array,
     attn_fn = attn_fn or resolve_attn_fn(cfg)
     B, S = tokens.shape
     dt = cfg.dtype
-    x = params["embed"][tokens].astype(dt)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(dt)
     sin, cos = rope_tables(cfg, S)
 
     def body(x, layer):
@@ -466,7 +480,9 @@ def hidden_with_aux(params: dict, tokens: jax.Array,
                   if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(body, policy=policy)
     x, auxs = lax.scan(body, x, params["blocks"], unroll=cfg.scan_unroll)
-    return rms_norm(x, params["final_norm"]), jnp.sum(auxs)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"])
+    return x, jnp.sum(auxs)
 
 
 def _head_weight(params: dict, cfg: TransformerConfig) -> jax.Array:
@@ -474,6 +490,7 @@ def _head_weight(params: dict, cfg: TransformerConfig) -> jax.Array:
             else params["lm_head"])
 
 
+@jax.named_scope("head")
 def head_logits(x: jax.Array, head: jax.Array,
                 cfg: TransformerConfig) -> jax.Array:
     """LM head matmul: bf16 operands, f32 MXU accumulation.
@@ -534,6 +551,7 @@ def nll_from_logits(logits: jax.Array, batch: dict) -> jax.Array:
 LOSS_CHUNK_ROWS = 8192
 
 
+@jax.named_scope("loss")
 def _chunked_nll(x, head, targets, mask, cfg: TransformerConfig):
     """(nll_sum, denom) with the head matmul fused into the loss.
 

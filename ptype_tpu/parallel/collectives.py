@@ -610,7 +610,7 @@ def _bucket_all_reduce_fn(mesh: Mesh, axis: str, op: str, shapes: tuple,
             P(axis, *(None,) * len(s)) for s in shapes)
     offs = _slot_offsets(shapes)
 
-    def f(*locals_):
+    def store_push(*locals_):
         flat = _pack_flat(locals_[:len(shapes)], pad)
         if wire == "int8":
             res = _pack_flat(locals_[len(shapes):], pad) if ef else None
@@ -642,7 +642,7 @@ def _bucket_all_reduce_fn(mesh: Mesh, axis: str, op: str, shapes: tuple,
         assert new_res is not None, "ef requires the int8 wire"
         return out + tuple(r[None] for r in _unpack(new_res, offs))
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.jit(shard_map(store_push, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))
 
 
@@ -669,7 +669,7 @@ def _bucket_reduce_scatter_fn(mesh: Mesh, axis: str, op: str,
             P(axis, *(None,) * len(s)) for s in shapes)
     offs = _slot_offsets(shapes)
 
-    def f(*locals_):
+    def store_push_scatter(*locals_):
         flat = _pack_flat(locals_[:len(shapes)], pad)
         if wire == "int8":
             if ef:
@@ -694,8 +694,9 @@ def _bucket_reduce_scatter_fn(mesh: Mesh, axis: str, op: str,
         new_res = err.reshape(flat.shape).astype(jnp.dtype(dtype))
         return (shard,) + tuple(r[None] for r in _unpack(new_res, offs))
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False))
+    return jax.jit(shard_map(store_push_scatter, mesh=mesh,
+                             in_specs=in_specs, out_specs=out_specs,
+                             check_vma=False))
 
 
 # --------------------------------------- hierarchical (2-D) programs
@@ -868,7 +869,7 @@ def _hier_bucket_all_reduce_fn(mesh: Mesh, op: str, shapes: tuple,
     offs = _slot_offsets(shapes)
     k = len(shapes)
 
-    def f(*locals_):
+    def store_push_hier(*locals_):
         flat = _pack_flat(locals_[:k], pad)
         pos = k
         res_in = None
@@ -895,7 +896,7 @@ def _hier_bucket_all_reduce_fn(mesh: Mesh, op: str, shapes: tuple,
             outs = outs + (nro.astype(jnp.float32),)
         return outs
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.jit(shard_map(store_push_hier, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False))
 
 
@@ -927,7 +928,7 @@ def _hier_bucket_reduce_scatter_fn(mesh: Mesh, op: str, shapes: tuple,
     offs = _slot_offsets(shapes)
     k = len(shapes)
 
-    def f(*locals_):
+    def store_push_scatter_hier(*locals_):
         flat = _pack_flat(locals_[:k], pad)
         pos = k
         res_in = None
@@ -951,7 +952,8 @@ def _hier_bucket_reduce_scatter_fn(mesh: Mesh, op: str, shapes: tuple,
             outs = outs + (nro.astype(jnp.float32),)
         return outs if len(outs) > 1 else outs[0]
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.jit(shard_map(store_push_scatter_hier, mesh=mesh,
+                             in_specs=in_specs,
                              out_specs=(out_specs if ef_in or ef_out
                                         else out_specs[0]),
                              check_vma=False))

@@ -353,15 +353,17 @@ class PagedGeneratorActor(GeneratorActor):
             logits, kb, vb = gen.decode_step_paged(
                 params, tok, pos, self.cfg, kb, vb, tables, wr_b,
                 wr_o, attn_impl=self.attn)
-            if sampled:
-                nxt = gen.sample_token_rows(logits, keys, eidx, temps,
-                                            topk, topp)
-            else:
-                # All-greedy step: skip the per-row sort/gumbel
-                # machinery entirely (the serving hot path; two cached
-                # programs, picked per step by live-slot inspection).
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            nxt = jnp.where(active, nxt, 0)
+            with jax.named_scope("sample"):
+                if sampled:
+                    nxt = gen.sample_token_rows(logits, keys, eidx,
+                                                temps, topk, topp)
+                else:
+                    # All-greedy step: skip the per-row sort/gumbel
+                    # machinery entirely (the serving hot path; two
+                    # cached programs, picked per step by live-slot
+                    # inspection).
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                nxt = jnp.where(active, nxt, 0)
             return (kb, vb, nxt, jnp.where(active, pos + 1, pos),
                     jnp.where(active, eidx + 1, eidx))
 
@@ -928,9 +930,11 @@ class PagedGeneratorActor(GeneratorActor):
             # Cancelled rows (their caller already got a sibling's
             # error) retire before admission: their blocks are exactly
             # the headroom the queue head is waiting on.
-            for slot in list(self._slot_state):
-                if self._active[slot] and self._slot_state[slot].cancelled:
-                    self._retire(slot, "cancelled")
+            with metrics_mod.annotate("serve.admit"):
+                for slot in list(self._slot_state):
+                    if (self._active[slot]
+                            and self._slot_state[slot].cancelled):
+                        self._retire(slot, "cancelled")
             # Admission round, bounded by the TOKEN budget: several
             # short prompts (or one chunk of a long one) may prefill,
             # but never more than prefill_chunk prompt tokens — that
@@ -972,7 +976,7 @@ class PagedGeneratorActor(GeneratorActor):
         budget = self.prefill_chunk
         spent = 0.0
         while budget > 0:
-            with self._cond:
+            with metrics_mod.annotate("serve.admit"), self._cond:
                 self._maybe_start_admission_locked()
                 row = self._admitting
                 if row is not None and row.cancelled:
@@ -1045,12 +1049,13 @@ class PagedGeneratorActor(GeneratorActor):
     def _chunk_prog(self, C: int):
         prog = self._chunk_progs.get(C)
         if prog is None:
-            def run(params, kb, vb, tokens, start, length, table):
+            def prefill_chunk(params, kb, vb, tokens, start, length,
+                              table):
                 return gen.prefill_paged_chunk(
                     params, tokens, start, length, self.cfg, kb, vb,
                     table)
 
-            prog = jax.jit(run, donate_argnums=(1, 2))
+            prog = jax.jit(prefill_chunk, donate_argnums=(1, 2))
             self._chunk_progs[C] = prog
         return prog
 
@@ -1063,6 +1068,83 @@ class PagedGeneratorActor(GeneratorActor):
         seconds — the stall charge)."""
         if row.migrated:
             return self._activate_migrated(row)
+        with metrics_mod.annotate("serve.prefill/host"):
+            start, n, padded, table_arr = self._prefill_host(row, budget)
+        toks = row.prompt
+        L = len(toks)
+        bt = self.block_tokens
+        # The meter stays open through the FINAL chunk's first-token
+        # sampling: under async dispatch the program call returns
+        # before the device runs, and the np.asarray/sample host sync
+        # below is where that chunk's wall is actually paid — closing
+        # the meter early would under-report the stall charge (and the
+        # chunk span) by the final chunk's compute.
+        cm = self.ledger.chunk(row.rec, n)
+        with cm:
+            # The dispatch lock orders this bank-donating call against
+            # ExportBlocks' pack reads on RPC threads (ISSUE 16): a
+            # pack that dispatched first still reads the pre-donation
+            # buffers; one that dispatches after sees the NEW bank
+            # refs — never a half-donated alias.
+            with self._lock:
+                logits, self.pool.k, self.pool.v = self._chunk_prog(
+                    padded.shape[1])(
+                    self.params, self.pool.k, self.pool.v,
+                    jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
+                    jnp.asarray(table_arr))
+            row.prefill_pos += n
+            done = row.prefill_pos >= L
+            if done:
+                # Prompt fully resident: seal the freshly-computed
+                # full blocks (reused ones are already in the index)
+                # and emit the first token.
+                for i in range(row.reused, len(row.hashes)):
+                    self.pool.seal(row.table[i], row.hashes[i],
+                                   toks[i * bt:(i + 1) * bt])
+                with metrics_mod.annotate("serve.prefill/fetch"):
+                    # The host waits here for every chunk of the prompt.
+                    if row.temperature == 0.0:
+                        first = int(np.asarray(logits)[0].argmax())
+                    else:
+                        first = int(self._sample_first(
+                            logits, jnp.asarray(row.key),
+                            jnp.float32(row.temperature),
+                            jnp.int32(row.top_k),
+                            jnp.float32(row.top_p)))
+                if (self._dpool is not None and row.max_new > 1
+                        and not (row.stop_token >= 0
+                                 and first == row.stop_token)):
+                    # The row will take a slot: give the draft model
+                    # its prompt KV (inside this chunk's meter, so the
+                    # activation cost is a charged stall, not free).
+                    self._draft_prefill(row, toks, L)
+        self._prefill_chunks += 1
+        self._prefill_tokens += n
+        if not done:
+            return n, cm.dur_s
+        # The TTFT stamp: the first token exists on the host here.
+        self.ledger.first_token(row.rec)
+        row.emitted.append(first)
+        with self._cond:
+            self._admitting = None
+        self._export_gauges()
+        if row.export_id is not None:
+            self._stash_export(row)
+            return n, cm.dur_s
+        if (row.max_new == 1
+                or (row.stop_token >= 0 and first == row.stop_token)):
+            self._finish_row(row,
+                             "stop" if (row.stop_token >= 0
+                                        and first == row.stop_token)
+                             else "complete")
+            return n, cm.dur_s
+        self._take_slot(row, first, L)
+        return n, cm.dur_s
+
+    def _prefill_host(self, row, budget: int | None):
+        """What a chunk needs before its program runs: the reuse walk
+        (first chunk only), the chunk's blocks, its tokens padded to
+        their bucket and its table. → (start, tokens, padded, table)."""
         toks = row.prompt
         L = len(toks)
         bt = self.block_tokens
@@ -1094,75 +1176,11 @@ class PagedGeneratorActor(GeneratorActor):
         while len(row.table) * bt < start + n:
             row.table.append(self.pool.alloc())
             row.reserve_left -= 1
-        C = max(16, _pow2(n))
-        padded = np.zeros((1, C), np.int32)
+        padded = np.zeros((1, max(16, _pow2(n))), np.int32)
         padded[0, :n] = toks[start:start + n]
         table_arr = np.zeros(self.nb, np.int32)
         table_arr[:len(row.table)] = row.table
-        # The meter stays open through the FINAL chunk's first-token
-        # sampling: under async dispatch the program call returns
-        # before the device runs, and the np.asarray/sample host sync
-        # below is where that chunk's wall is actually paid — closing
-        # the meter early would under-report the stall charge (and the
-        # chunk span) by the final chunk's compute.
-        cm = self.ledger.chunk(row.rec, n)
-        with cm:
-            # The dispatch lock orders this bank-donating call against
-            # ExportBlocks' pack reads on RPC threads (ISSUE 16): a
-            # pack that dispatched first still reads the pre-donation
-            # buffers; one that dispatches after sees the NEW bank
-            # refs — never a half-donated alias.
-            with self._lock:
-                logits, self.pool.k, self.pool.v = self._chunk_prog(C)(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
-                    jnp.asarray(table_arr))
-            row.prefill_pos += n
-            done = row.prefill_pos >= L
-            if done:
-                # Prompt fully resident: seal the freshly-computed
-                # full blocks (reused ones are already in the index)
-                # and emit the first token.
-                for i in range(row.reused, len(row.hashes)):
-                    self.pool.seal(row.table[i], row.hashes[i],
-                                   toks[i * bt:(i + 1) * bt])
-                if row.temperature == 0.0:
-                    first = int(np.asarray(logits)[0].argmax())
-                else:
-                    first = int(self._sample_first(
-                        logits, jnp.asarray(row.key),
-                        jnp.float32(row.temperature),
-                        jnp.int32(row.top_k),
-                        jnp.float32(row.top_p)))
-                if (self._dpool is not None and row.max_new > 1
-                        and not (row.stop_token >= 0
-                                 and first == row.stop_token)):
-                    # The row will take a slot: give the draft model
-                    # its prompt KV (inside this chunk's meter, so the
-                    # activation cost is a charged stall, not free).
-                    self._draft_prefill(row, toks, L)
-        self._prefill_chunks += 1
-        self._prefill_tokens += n
-        if not done:
-            return n, cm.dur_s
-        # The TTFT stamp: the first token exists on the host here.
-        self.ledger.first_token(row.rec)
-        row.emitted.append(first)
-        with self._cond:
-            self._admitting = None
-        self._export_gauges()
-        if row.export_id is not None:
-            self._stash_export(row)
-            return n, cm.dur_s
-        if (row.max_new == 1
-                or (row.stop_token >= 0 and first == row.stop_token)):
-            self._finish_row(row,
-                             "stop" if (row.stop_token >= 0
-                                        and first == row.stop_token)
-                             else "complete")
-            return n, cm.dur_s
-        self._take_slot(row, first, L)
-        return n, cm.dur_s
+        return start, n, padded, table_arr
 
     def _take_slot(self, row: _PagedRow, first: int, L: int) -> None:
         """Land a prompt-complete row in a free slot (the caller
@@ -1261,37 +1279,45 @@ class PagedGeneratorActor(GeneratorActor):
         self._plain_step()
 
     def _plain_step(self) -> None:
-        # Boundary crossings first: a slot whose next write lands past
-        # its allocated blocks materializes one from its reservation
-        # (guaranteed — admission reserved the worst case).
-        for slot in np.flatnonzero(self._active):
-            if self._pos[slot] == self._nalloc[slot] * self.block_tokens:
-                row = self._slot_state[slot]
-                bid = self.pool.alloc()
-                row.reserve_left -= 1
-                row.table.append(bid)
-                self._tables[slot, self._nalloc[slot]] = bid
-                self._nalloc[slot] += 1
-                self._dev = None  # tables changed: re-upload
-                self._sdev = None
-        sampled = bool((self._temps[self._active] > 0.0).any())
+        # The iteration's phases are regions of their own inside
+        # serve.step, so a device profile says what the host was doing
+        # in each of the device's gaps (PERF.md §3).
+        annotate = metrics_mod.annotate
+        with annotate("serve.step/blocks"):
+            # Boundary crossings first: a slot whose next write lands
+            # past its allocated blocks materializes one from its
+            # reservation (guaranteed — admission reserved the worst
+            # case).
+            for slot in np.flatnonzero(self._active):
+                if (self._pos[slot]
+                        == self._nalloc[slot] * self.block_tokens):
+                    row = self._slot_state[slot]
+                    bid = self.pool.alloc()
+                    row.reserve_left -= 1
+                    row.table.append(bid)
+                    self._tables[slot, self._nalloc[slot]] = bid
+                    self._nalloc[slot] += 1
+                    self._dev = None  # tables changed: re-upload
+                    self._sdev = None
+            sampled = bool((self._temps[self._active] > 0.0).any())
         if self._dev is None:
             # device_put, not jnp.asarray: on a placed replica the
             # step's outputs are COMMITTED to its device, and tok/pos/
             # eidx feed straight back in — a fresh upload must carry
             # the same commitment or the second step of every request
             # sees a new signature and compiles again (chip run, PR 21).
-            self._dev = jax.device_put({
-                "tok": self._tok, "pos": self._pos,
-                "tables": self._tables, "active": self._active,
-                "keys": self._keys, "eidx": self._eidx,
-                "temps": self._temps, "topk": self._topk,
-                "topp": self._topp,
-            }, self.device)
+            with annotate("serve.step/upload"):
+                self._dev = jax.device_put({
+                    "tok": self._tok, "pos": self._pos,
+                    "tables": self._tables, "active": self._active,
+                    "keys": self._keys, "eidx": self._eidx,
+                    "temps": self._temps, "topk": self._topk,
+                    "topp": self._topp,
+                }, self.device)
         d = self._dev
         self._steps += 1
         self._max_live = max(self._max_live, int(self._active.sum()))
-        with self._lock:
+        with annotate("serve.step/dispatch"), self._lock:
             # Armed (PTYPE_JITWATCH=1), the hot region makes any
             # unsanctioned implicit transfer into the decode step
             # raise at the call — the steady-state step re-uploads
@@ -1304,27 +1330,31 @@ class PagedGeneratorActor(GeneratorActor):
                     d["keys"], d["eidx"], d["temps"], d["topk"],
                     d["topp"])
         d["tok"] = nxt
-        nxt_host = np.array(nxt)  # host mirror for retire bookkeeping
-        self._pos[self._active] += 1
-        self._eidx[self._active] += 1
-        self._tok = nxt_host
-        live = [(slot, self._slot_state[slot])
-                for slot in list(self._slot_state)
-                if self._active[slot]]
-        # One shared stamp for every row that just emitted — the
-        # per-token decode-delta trail behind the TPOT histogram.
-        self.ledger.tokens_emitted([row.rec for _, row in live])
-        for slot, row in live:
-            t = int(nxt_host[slot])
-            row.emitted.append(t)
-            if row.stop_token >= 0 and t == row.stop_token:
-                self._retire(slot, "stop")
-            elif len(row.emitted) >= row.max_new:
-                self._retire(slot, "complete")
-        if self._steps % 32 == 0:
-            self._export_gauges()  # sampler cadence is ~50 ms+; the
-            #                        retire/admission exports keep the
-            #                        block gauges fresh between these.
+        with annotate("serve.step/fetch"):
+            # The host waits for the device here.
+            nxt_host = np.array(nxt)  # host mirror for retire bookkeeping
+        with annotate("serve.step/emit"):
+            self._pos[self._active] += 1
+            self._eidx[self._active] += 1
+            self._tok = nxt_host
+            live = [(slot, self._slot_state[slot])
+                    for slot in list(self._slot_state)
+                    if self._active[slot]]
+            # One shared stamp for every row that just emitted — the
+            # per-token decode-delta trail behind the TPOT histogram.
+            self.ledger.tokens_emitted([row.rec for _, row in live])
+            for slot, row in live:
+                t = int(nxt_host[slot])
+                row.emitted.append(t)
+                if row.stop_token >= 0 and t == row.stop_token:
+                    self._retire(slot, "stop")
+                elif len(row.emitted) >= row.max_new:
+                    self._retire(slot, "complete")
+            if self._steps % 32 == 0:
+                self._export_gauges()  # sampler cadence is ~50 ms+;
+                #                        the retire/admission exports
+                #                        keep the block gauges fresh
+                #                        between these.
 
     # ------------------------------------------------------ speculation
 
@@ -1355,12 +1385,13 @@ class PagedGeneratorActor(GeneratorActor):
         if prog is None:
             dcfg = self._spec.draft_cfg
 
-            def run(params, kb, vb, tokens, start, length, table):
+            def draft_prefill_chunk(params, kb, vb, tokens, start,
+                                    length, table):
                 return gen.prefill_paged_chunk(
                     params, tokens, start, length, dcfg, kb, vb,
                     table)
 
-            prog = jax.jit(run, donate_argnums=(1, 2))
+            prog = jax.jit(draft_prefill_chunk, donate_argnums=(1, 2))
             self._draft_chunk_progs[C] = prog
         return prog
 
@@ -1450,9 +1481,9 @@ class PagedGeneratorActor(GeneratorActor):
             bt = self.block_tokens
             nb = self.nb
 
-            def run(tparams, dparams, tok, pos, kb, vb, dkb, dvb,
-                    tables, dtables, nalloc, dnalloc, active, keys,
-                    sctr, temps, topk, topp):
+            def spec_window(tparams, dparams, tok, pos, kb, vb, dkb,
+                            dvb, tables, dtables, nalloc, dnalloc,
+                            active, keys, sctr, temps, topk, topp):
                 ap = pos[:, None] + jnp.arange(W)[None, :]  # (B, W)
                 blk = jnp.minimum(ap // bt, nb - 1)
                 wr_o = ap % bt
@@ -1480,7 +1511,7 @@ class PagedGeneratorActor(GeneratorActor):
                     temps, topk, topp, sampled=sampled)
                 return out, n_acc, kb, vb, dkb, dvb
 
-            prog = jax.jit(run, donate_argnums=(4, 5, 6, 7))
+            prog = jax.jit(spec_window, donate_argnums=(4, 5, 6, 7))
             self._window_progs[key] = prog
         return prog
 
@@ -1549,7 +1580,7 @@ class PagedGeneratorActor(GeneratorActor):
         tok_dev = jnp.asarray(self._tok)
         pos_dev = jnp.asarray(self._pos)
         sctr_dev = jnp.asarray(self._sctr)
-        with self._lock:
+        with metrics_mod.annotate("serve.step/dispatch"), self._lock:
             with jitwatch.hot_region("serve.spec_window"):
                 (out_toks, n_acc, self.pool.k, self.pool.v,
                  self._dpool.k, self._dpool.v) = \
@@ -1561,8 +1592,9 @@ class PagedGeneratorActor(GeneratorActor):
                         sd["nalloc"], sd["dnalloc"], sd["active"],
                         sd["keys"], sctr_dev, sd["temps"],
                         sd["topk"], sd["topp"])
-        out_host = np.asarray(out_toks)   # the window's ONE host sync
-        acc_host = np.asarray(n_acc)
+        with metrics_mod.annotate("serve.step/fetch"):
+            out_host = np.asarray(out_toks)  # the window's ONE host sync
+            acc_host = np.asarray(n_acc)
         emit_recs, emit_counts = [], []
         retires: list[tuple[int, str]] = []
         total_acc = total_emit = 0

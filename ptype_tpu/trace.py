@@ -24,16 +24,29 @@ processes' logs. This module is the missing trace plane:
   (:mod:`ptype_tpu.chaos`) land as events on the span they hit, so a
   soak failure shows *which request* a fault landed in.
 
-Zero-cost contract (same shape as chaos.py): with no recorder armed,
+- **One seam**: :func:`span` is the one function that opens a region,
+  for every layer (``metrics.annotate`` is this function under the name
+  the train/store side imports). A region has three sinks, each taken
+  only when it is armed: the flight recorder (:func:`enable`), a live
+  ``jax.profiler`` capture (the region becomes a ``TraceAnnotation`` of
+  the same name, its attributes the annotation's metadata — so a device
+  profile holds the gateway's, the rpc's and the engine's spans on the
+  device trace's clock), and the region observer
+  (:func:`set_region_observer` — the goodput ledger).
+
+Zero-cost contract (same shape as chaos.py): with no sink armed,
 :func:`span` / :func:`span_from` / :func:`attach` return a module
-singleton no-op context manager — one global load + ``None`` check,
-no allocation; :func:`traceparent` returns ``None`` before touching
-the contextvar. Tracing is enabled per process with :func:`enable`
-(tests, the obs demo, bench probes) or the ``PTYPE_TRACE`` env var.
+singleton no-op context manager — two global loads and the profiler's
+one atomic "is a capture live" check, no allocation;
+:func:`traceparent` returns ``None`` before touching the contextvar.
+Tracing is enabled per process with :func:`enable` (tests, the obs
+demo, bench probes) or the ``PTYPE_TRACE`` env var.
 
 This module imports only the stdlib plus :mod:`ptype_tpu.chaos`
-(itself stdlib-only) — it sits under logs/metrics/rpc and must never
-create an import cycle.
+(itself stdlib-only) at module level — it sits under logs/metrics/rpc
+and must never create an import cycle. ``jax.profiler`` is looked up
+once, and only in a process that has already imported jax: a process
+without jax cannot have a capture running.
 """
 
 from __future__ import annotations
@@ -43,6 +56,7 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 
@@ -50,8 +64,9 @@ from ptype_tpu import chaos
 
 __all__ = [
     "Span", "FlightRecorder",
-    "enable", "disable", "enabled", "recorder",
-    "span", "span_from", "attach", "current", "traceparent",
+    "enable", "disable", "enabled", "capturing", "recorder",
+    "set_region_observer", "span", "span_from", "attach", "current",
+    "traceparent",
     "parse_traceparent", "add_event", "maybe_dump", "telemetry",
 ]
 
@@ -212,11 +227,16 @@ def enable(service: str = "", capacity: int = 4096,
     """Arm tracing process-wide; returns the fresh flight recorder.
     Also registers the chaos observer so fault firings / recovery
     beacons land as events on the span they hit."""
-    global _recorder, _dump_dir
+    global _recorder, _dump_dir, _dump_last
     rec = FlightRecorder(service, capacity)
     _recorder = rec
     if dump_dir is not None:
         _dump_dir = dump_dir
+    # A fresh recorder owes nothing to the last one's dumps: the rate
+    # limit would otherwise silence this session's first post-mortem
+    # for up to DUMP_MIN_INTERVAL_S after another session's.
+    with _dump_lock:
+        _dump_last = 0.0
     chaos.set_observer(_chaos_observer)
     return rec
 
@@ -241,6 +261,39 @@ def _restore(rec: FlightRecorder | None, dump_dir: str | None) -> None:
 
 def enabled() -> bool:
     return _recorder is not None
+
+
+#: Observer of finished regions — ``fn(name, dur_s)``. The health
+#: plane's goodput ledger installs itself here, so every train.step /
+#: store.push_tree / checkpoint region feeds the per-step breakdown
+#: through the one seam.
+_observer = None
+
+
+def set_region_observer(fn) -> None:
+    """Install (or clear, with ``None``) the region observer. One per
+    process — the goodput ledger; tests that need several ledgers drive
+    them directly via ``GoodputLedger.region``."""
+    global _observer
+    _observer = fn
+
+
+#: ``jax.profiler.TraceAnnotation``, once this process has imported jax.
+_trace_me = None
+
+
+def capturing() -> bool:
+    """Is a jax profiler capture running? The profiler's own atomic
+    check; False in a process that never imported jax."""
+    global _trace_me
+    tm = _trace_me
+    if tm is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation as tm
+
+        _trace_me = tm
+    return tm.is_enabled()
 
 
 def recorder() -> FlightRecorder | None:
@@ -326,22 +379,34 @@ _NOOP = _Noop()
 
 
 class _SpanCtx:
-    """Context manager that opens a span as a child of the current (or
-    an explicit remote) context, makes it current for the scope, and
-    freezes it into the recorder on exit."""
+    """One region, into whichever sinks are armed. Ring: a span opened
+    as a child of the current (or an explicit remote) context, current
+    for the scope, frozen into the recorder on exit. Capture: a
+    profiler ``TraceAnnotation`` around the same scope. Observer: the
+    region's name and wall seconds on exit."""
 
-    __slots__ = ("_rec", "_name", "_attrs", "_parent", "_span", "_token")
+    __slots__ = ("_rec", "_name", "_attrs", "_parent", "_span", "_token",
+                 "_ann", "_obs", "_t0")
 
-    def __init__(self, rec: FlightRecorder, name: str,
-                 parent: tuple[str, str] | None, attrs: dict):
+    def __init__(self, rec: FlightRecorder | None, name: str,
+                 parent: tuple[str, str] | None, attrs: dict,
+                 live: bool, obs):
         self._rec = rec
         self._name = name
         self._attrs = attrs
         self._parent = parent  # (trace_id, span_id) | None
         self._span: Span | None = None
         self._token = None
+        self._ann = _trace_me(name, **attrs) if live else None
+        self._obs = obs
 
-    def __enter__(self) -> Span:
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        # Monotonic duration clock alongside the wall-clock start.
+        self._t0 = time.perf_counter()
+        if self._rec is None:
+            return _NOOP
         if self._parent is not None:
             trace_id, parent_id = self._parent
         else:
@@ -355,42 +420,55 @@ class _SpanCtx:
             sp.attrs.update(self._attrs)
         self._span = sp
         self._token = _current.set(sp)
-        # Monotonic duration clock alongside the wall-clock start.
-        sp.attrs["_t0"] = time.perf_counter()
         return sp
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        dt = time.perf_counter() - self._t0
         sp = self._span
-        sp.dur_s = time.perf_counter() - sp.attrs.pop("_t0")
-        if exc is not None:
-            # ShedError is a typed refusal, not a failure — checked by
-            # name so this module stays import-light.
-            sp.status = ("shed" if type(exc).__name__ == "ShedError"
-                         else "error")
-            sp.add_event("exception", type=type(exc).__name__,
-                         message=str(exc)[:200])
-        _current.reset(self._token)
-        self._rec.record(sp)
+        if sp is not None:
+            sp.dur_s = dt
+            if exc is not None:
+                # ShedError is a typed refusal, not a failure — checked
+                # by name so this module stays import-light.
+                sp.status = ("shed" if type(exc).__name__ == "ShedError"
+                             else "error")
+                sp.add_event("exception", type=type(exc).__name__,
+                             message=str(exc)[:200])
+            _current.reset(self._token)
+            self._rec.record(sp)
+        if self._obs is not None:
+            try:
+                self._obs(self._name, dt)
+            except Exception:  # noqa: BLE001 — telemetry must never
+                pass           # kill the step it observes, nor leak
+                #                the annotation's scope.
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
 def span(name: str, **attrs):
-    """Open a span (child of the current one) for a ``with`` scope.
-    The no-op singleton when tracing is disabled — no allocation."""
-    rec = _recorder
-    if rec is None:
-        return _NOOP
-    return _SpanCtx(rec, name, None, attrs)
+    """Open a region for a ``with`` scope: a span (child of the current
+    one) in the flight recorder when tracing is armed, a profiler
+    annotation of the same name — ``attrs`` its metadata — when a
+    capture is live, a report to the region observer when one is
+    installed. The no-op singleton otherwise — no allocation.
+
+    >>> with trace.span("store.push/grads"):
+    ...     store.push_tree("grads", grads)
+    """
+    return span_from(None, name, **attrs)
 
 
 def span_from(tp, name: str, **attrs):
-    """Open a span whose parent is a wire ``traceparent`` (the server
-    side of a propagated call). Falls back to :func:`span` semantics
-    when ``tp`` is absent/malformed; no-op when disabled."""
-    rec = _recorder
-    if rec is None:
+    """:func:`span` whose ring parent is a wire ``traceparent`` (the
+    server side of a propagated call; absent or malformed, the current
+    span as usual)."""
+    rec, obs, live = _recorder, _observer, capturing()
+    if rec is None and obs is None and not live:
         return _NOOP
-    return _SpanCtx(rec, name, parse_traceparent(tp), attrs)
+    parent = parse_traceparent(tp) if rec is not None else None
+    return _SpanCtx(rec, name, parent, attrs, live, obs)
 
 
 class _AttachCtx:

@@ -779,14 +779,15 @@ class StoreDPTrainer:
             inner = make_inner({str(i): mask_leaves[i] for i in idxs})
             self._bucket_states.append(inner.init(subp))
 
-            def apply(p, g, s, scale, _inner=inner):
+            @jax.named_scope("optimizer")
+            def store_apply(p, g, s, scale, _inner=inner):
                 g = jax.tree_util.tree_map(
                     lambda t: (t.astype(jnp.float32) * scale).astype(
                         t.dtype), g)
                 updates, s = _inner.update(g, s, p)
                 return optax.apply_updates(p, updates), s
 
-            self._apply_fns.append(jax.jit(apply))
+            self._apply_fns.append(jax.jit(store_apply))
             self._sqnorm_fns.append(jax.jit(
                 lambda g: sum(jnp.sum(jnp.square(x.astype(jnp.float32)))
                               for x in jax.tree_util.tree_leaves(g))))

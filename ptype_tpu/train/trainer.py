@@ -132,11 +132,12 @@ def make_apply_fn(optimizer):
     the one optimizer-step helper every eager trainer shares (store_dp,
     param_server, actor_pipeline)."""
 
-    def apply(params, grads, opt_state):
+    @jax.named_scope("optimizer")
+    def optimizer_apply(params, grads, opt_state):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         return optax.apply_updates(params, updates), opt_state
 
-    return jax.jit(apply)
+    return jax.jit(optimizer_apply)
 
 
 def _path_key(path) -> tuple[str, ...]:
@@ -322,11 +323,12 @@ def make_train_step(cfg: tfm.TransformerConfig, mesh: Mesh,
 
     def step(state: TrainState, batch: dict):
         loss, grads = grads_of(state.params, batch)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+            gnorm = optax.global_norm(grads)
         new = TrainState(params, opt_state, state.step + 1)
         return new, {"loss": loss, "grad_norm": gnorm, "step": new.step}
 
@@ -493,7 +495,8 @@ class Trainer:
 
         batch = self.shard_batch(batch)
         train_step = self._step_for(batch)
-        if self._stats is None:
+        first = self._stats is None
+        if first:
             self._stats = StepStats(
                 flops_per_token=tfm.flops_per_token(
                     self.cfg, batch["tokens"].shape[1]),
@@ -503,7 +506,6 @@ class Trainer:
             self._host_step = int(self.state.step)
             self._pending_tokens = 0
             self._pending_steps = 0
-            self._stats.start()
         # train.step is the health-plane seam too (goodput ledger /
         # trace span). NOTE: this trainer dispatches asynchronously —
         # the region measures dispatch between drains and the whole
@@ -513,8 +515,14 @@ class Trainer:
             self.state, out = train_step(self.state, batch)
         self._host_step += 1
         metrics.counter("train.steps").add(1)
-        self._pending_tokens += batch["tokens"].size
-        self._pending_steps += 1
+        if first:
+            # The first step compiles. Drain it and start the clock
+            # behind it: the rates (and "mfu") are of steady steps.
+            jax.block_until_ready(out["loss"])
+            self._stats.start()
+        else:
+            self._pending_tokens += batch["tokens"].size
+            self._pending_steps += 1
         if self.sync_every and self._host_step % self.sync_every == 0:
             jax.block_until_ready(out["loss"])
             # loss is materialized at the drain anyway — stamp the

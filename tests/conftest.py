@@ -73,9 +73,9 @@ def _disarm_health():
     yield
     import sys as _sys
 
-    metrics_mod = _sys.modules.get("ptype_tpu.metrics")
-    if metrics_mod is not None:
-        metrics_mod.set_annotate_observer(None)
+    trace_mod = _sys.modules.get("ptype_tpu.trace")
+    if trace_mod is not None:
+        trace_mod.set_region_observer(None)
     series_mod = _sys.modules.get("ptype_tpu.health.series")
     if series_mod is not None:
         series_mod.stop()

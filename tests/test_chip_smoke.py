@@ -95,8 +95,9 @@ def test_script_refuses_to_run_without_a_tpu():
 
 def test_compile_cache_lives_in_one_fixed_place(monkeypatch):
     """$JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the code
-    sets nothing. Unset: <checkout>/.jax_cache, derived from the package
-    location — never a temp dir, which would never hit."""
+    sets no directory. Unset: <checkout>/.jax_cache, derived from the
+    package location — never a temp dir, which would never hit. Either
+    way the key takes the HLO metadata in (the op names a trace shows)."""
     from ptype_tpu import compile_cache
 
     # Recorded, not applied: once JAX has opened a persistent cache it
@@ -105,9 +106,10 @@ def test_compile_cache_lives_in_one_fixed_place(monkeypatch):
     monkeypatch.setattr(jax.config, "update",
                         lambda key, value: updates.append((key, value)))
     monkeypatch.setenv(compile_cache.ENV_VAR, "/somewhere/else")
+    keyed = ("jax_compilation_cache_include_metadata_in_key", True)
     assert compile_cache.configure() == "/somewhere/else"
-    assert updates == []
+    assert updates == [keyed]
     monkeypatch.delenv(compile_cache.ENV_VAR)
     want = os.path.join(REPO, ".jax_cache")
     assert compile_cache.configure() == want
-    assert updates == [("jax_compilation_cache_dir", want)]
+    assert updates == [keyed, keyed, ("jax_compilation_cache_dir", want)]

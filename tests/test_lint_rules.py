@@ -485,14 +485,15 @@ def test_pt008_flags_from_import_forms(tmp_path):
     assert sum("PT008" in f for f in findings) == 2, findings
 
 
-def test_pt008_exempts_the_managed_seams(tmp_path):
-    # metrics.py (the legacy local wrapper) and health/profiling.py
-    # (the managed capture plane) ARE the sanctioned call sites.
-    findings = _check(tmp_path, "ptype_tpu/metrics.py", PT008_RAW_TRACE)
-    assert not any("PT008" in f for f in findings), findings
+def test_pt008_exempts_the_managed_seam_only(tmp_path):
+    # health/profiling.py (the managed capture plane) IS the sanctioned
+    # call site; metrics.py lost its wrapper (``metrics.trace``) and
+    # its exemption with it.
     findings = _check(tmp_path, "ptype_tpu/health/profiling.py",
                       PT008_RAW_TRACE)
     assert not any("PT008" in f for f in findings), findings
+    findings = _check(tmp_path, "ptype_tpu/metrics.py", PT008_RAW_TRACE)
+    assert sum("PT008" in f for f in findings) == 2, findings
 
 
 def test_pt008_silent_outside_package(tmp_path):

@@ -314,8 +314,7 @@ class _RawProfilerTraceCheck(ast.NodeVisitor):
 
 
 @rule("PT008", "raw jax.profiler start/stop outside the managed seam",
-      applies=lambda ctx: ctx.in_pkg and ctx.basename not in (
-          "metrics.py", "profiling.py"))
+      applies=lambda ctx: ctx.in_pkg and ctx.basename != "profiling.py")
 def check_pt008(ctx: FileContext) -> list[Finding]:
     findings: list[Finding] = []
     _RawProfilerTraceCheck(ctx, findings).visit(ctx.tree)
