@@ -287,6 +287,56 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
         return o.reshape(B, Q, H, Dh)
 
 
+def _paged_layers(params, tokens, positions, cfg, kb, vb, tables, wr_b,
+                  wr_o, limits, moe_capacity, attend=None):
+    """Embedding and the ONE layer loop of the paged programs (decode
+    step, prefill chunk, speculative verify and draft). The banks ride
+    the scan's CARRY, whole, and a layer reaches its own rows through
+    the indices of its scatter and gather: in the free flat view
+    ``(L * n_blocks, block_tokens, Kh, Dh)`` layer ``l``'s block ``b``
+    is row ``l * n_blocks + b``. (Scanned, every layer sliced its bank
+    out of the stack and wrote all of it back, and the program copied
+    both banks again: a scanned output cannot alias a donated input.)
+
+    ``tokens``/``positions``/``wr_b``/``wr_o`` (B, Q): each position's
+    K/V row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name the
+    trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q)) as
+    :func:`_paged_attention_gather` takes them. ``attend(q, kc, vc)``,
+    if given, replaces the gather path on the layer's own bank, sliced
+    out of the carry (the Pallas kernel wants one layer, head-major).
+    Returns ``(x (B, Q, D) before the final norm, kb, vb)``."""
+    L, n_blocks = kb.shape[:2]
+    flat = (L * n_blocks,) + kb.shape[2:]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cfg.dtype)
+    sin, cos = tfm.rope_tables(cfg, positions=positions)
+
+    def body(carry, inputs):
+        x, kf, vf = carry
+        layer, base = inputs  # base: the layer's first row of the view
+        q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
+        with jax.named_scope("kv_write"):
+            kf = kf.at[base + wr_b, wr_o].set(k)
+            vf = vf.at[base + wr_b, wr_o].set(v)
+        if attend is None:
+            o = _paged_attention_gather(q, kf, vf, base + tables,
+                                        limits, cfg)
+        else:
+            with jax.named_scope("attn"):
+                o = attend(
+                    q, lax.dynamic_slice_in_dim(kf, base, n_blocks),
+                    lax.dynamic_slice_in_dim(vf, base, n_blocks))
+        x = tfm.attn_residual(x, o, layer, cfg)
+        x, _aux = tfm.mlp_residual(x, layer, cfg,
+                                   moe_capacity=moe_capacity)
+        return (x, kf, vf), None
+
+    (x, kf, vf), _ = lax.scan(
+        body, (x, kb.reshape(flat), vb.reshape(flat)),
+        (params["blocks"], jnp.arange(L, dtype=jnp.int32) * n_blocks))
+    return x, kf.reshape(kb.shape), vf.reshape(vb.shape)
+
+
 def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
                       cfg: tfm.TransformerConfig, kb: jax.Array,
                       vb: jax.Array, tables: jax.Array,
@@ -309,31 +359,18 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
     (ops/paged_attention, gated behind its ``check_tpu_lowering``);
     the default is the XLA gather path. Returns
     ``(logits (B, V), kb, vb)``."""
-    B = token.shape[0]
-    with jax.named_scope("embed"):
-        x = params["embed"][token][:, None, :].astype(cfg.dtype)
-    sin, cos = tfm.rope_tables(cfg, positions=pos[:, None])
+    attend = None
+    if attn_impl == "kernel":
+        from ptype_tpu.ops.paged_attention import paged_attention
 
-    def body(x, inputs):
-        layer, kc, vc = inputs  # (n_blocks, block_tokens, Kh, Dh)
-        q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        with jax.named_scope("kv_write"):
-            kc = kc.at[wr_blocks, wr_off].set(k[:, 0])
-            vc = vc.at[wr_blocks, wr_off].set(v[:, 0])
-        if attn_impl == "kernel":
-            from ptype_tpu.ops.paged_attention import paged_attention
+        def attend(q, kc, vc):
+            return paged_attention(q, kc, vc, tables, pos,
+                                   interpret=interpret)
 
-            with jax.named_scope("attn"):
-                o = paged_attention(q, kc, vc, tables, pos,
-                                    interpret=interpret)
-        else:
-            o = _paged_attention_gather(q, kc, vc, tables, pos + 1,
-                                        cfg)
-        x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=B)
-        return x, (kc, vc)
-
-    x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
+    x, kb, vb = _paged_layers(
+        params, token[:, None], pos[:, None], cfg, kb, vb, tables,
+        wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
+        attend)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"])
         logits = _head_logits(params, x[:, 0], cfg)
@@ -359,10 +396,7 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
     B, C = tokens.shape
     bt = kb.shape[2]
     nb = table.shape[0]
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)
     pos_vec = start + jnp.arange(C)  # (C,) positions of chunk columns
-    sin, cos = tfm.rope_tables(cfg, positions=pos_vec[None])
     valid = jnp.arange(C) < length
     wr_b = jnp.where(valid, table[jnp.clip(pos_vec // bt, 0, nb - 1)],
                      0)
@@ -374,19 +408,9 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
     # prefill's B*S bound — dropping is a training regularizer).
     cap = C if cfg.n_experts else None
 
-    def body(x, inputs):
-        layer, kc, vc = inputs
-        q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        with jax.named_scope("kv_write"):
-            kc = kc.at[wr_b, wr_o].set(k[0])
-            vc = vc.at[wr_b, wr_o].set(v[0])
-        o = _paged_attention_gather(q, kc, vc, table[None],
-                                    limits[None], cfg)
-        x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=cap)
-        return x, (kc, vc)
-
-    x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
+    x, kb, vb = _paged_layers(params, tokens, pos_vec[None], cfg, kb, vb,
+                              table[None], wr_b[None], wr_o[None],
+                              limits[None], cap)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"])
         x_last = x[jnp.arange(B), jnp.asarray(length)[None] - 1]
@@ -451,27 +475,14 @@ def verify_step_paged(params: dict, tokens: jax.Array,
     position-limit mask hides them until a later token overwrites them
     (rollback is a position rewind, never a reallocation)."""
     B, W = tokens.shape
-    with jax.named_scope("embed"):
-        x = params["embed"][tokens].astype(cfg.dtype)  # (B, W, D)
     pos = pos0[:, None] + jnp.arange(W)[None, :]   # (B, W)
-    sin, cos = tfm.rope_tables(cfg, positions=pos)
-    limits = pos + 1  # (B, W): per-query causal limits
     # MoE: zero-drop capacity over the whole window (same reasoning
     # as decode_step's B bound — dropping is a training regularizer).
     cap = B * W if cfg.n_experts else None
 
-    def body(x, inputs):
-        layer, kc, vc = inputs
-        q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        with jax.named_scope("kv_write"):
-            kc = kc.at[wr_b, wr_o].set(k)
-            vc = vc.at[wr_b, wr_o].set(v)
-        o = _paged_attention_gather(q, kc, vc, tables, limits, cfg)
-        x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=cap)
-        return x, (kc, vc)
-
-    x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
+    # Per-query causal limits: query j attends through pos0 + j.
+    x, kb, vb = _paged_layers(params, tokens, pos, cfg, kb, vb, tables,
+                              wr_b, wr_o, pos + 1, cap)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"])
         logits = _head_logits(params, x, cfg)
@@ -514,23 +525,9 @@ def draft_propose_paged(params: dict, tok: jax.Array,
         tok, kb, vb = carry
         j, wb, wo = inputs
         pos = pos0 + j  # (B,)
-        with jax.named_scope("embed"):
-            x = params["embed"][tok][:, None, :].astype(cfg.dtype)
-        sin, cos = tfm.rope_tables(cfg, positions=pos[:, None])
-
-        def body(x, inp):
-            layer, kc, vc = inp
-            q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-            with jax.named_scope("kv_write"):
-                kc = kc.at[wb, wo].set(k[:, 0])
-                vc = vc.at[wb, wo].set(v[:, 0])
-            o = _paged_attention_gather(q, kc, vc, tables, pos + 1,
-                                        cfg)
-            x = tfm.attn_residual(x, o, layer, cfg)
-            x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=B)
-            return x, (kc, vc)
-
-        x, (kb, vb) = lax.scan(body, x, (params["blocks"], kb, vb))
+        x, kb, vb = _paged_layers(
+            params, tok[:, None], pos[:, None], cfg, kb, vb, tables,
+            wb[:, None], wo[:, None], pos + 1, B)
         with jax.named_scope("head"):
             x = tfm.rms_norm(x, params["final_norm"])
             lg = _head_logits(params, x[:, 0], cfg)  # (B, V) f32

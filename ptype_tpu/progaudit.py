@@ -20,6 +20,13 @@ program itself, the ones a green test suite cannot see breaking:
   donation is what keeps the KV pool from being copied per step — a
   silently-dropped donation is a 2x HBM regression with no failing
   test;
+- **compiled temporaries under a ceiling** — the alias marker is in
+  the lowering whether or not the compiled program copies anyway (a
+  layer scan that took the banks as scanned inputs and outputs kept
+  the marker and copied both banks every step). With
+  ``max_temp_bytes`` the program is COMPILED (still not run) and its
+  ``memory_analysis().temp_size_in_bytes`` must stay under the
+  ceiling; the paged programs pin it below one bank;
 - **collective-op count** — the bucketed collectives exist to make
   one bucket cost ONE launch; a refactor that un-fuses them (N psums
   for N leaves) keeps every parity test green and gives back the PR 1
@@ -29,9 +36,9 @@ program itself, the ones a green test suite cannot see breaking:
 :func:`register` + :func:`audit_registered` keep a process-wide
 registry of hot-program builders; :func:`register_default_programs`
 installs the standing set (train-step grads, ZeRO shard-apply,
-bucketed allreduce/reduce-scatter, the paged decode step, the fused
-spec window) that ``tests/test_progaudit.py`` audits in the fast
-tier.
+bucketed allreduce/reduce-scatter, the paged decode step and prefill
+chunk, the fused spec window) that ``tests/test_progaudit.py`` audits
+in the fast tier.
 
 Stdlib + jax only at the bottom; model/mesh imports live inside the
 default builders (lazy — auditing a custom program must not drag the
@@ -41,6 +48,7 @@ transformer stack in).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Callable
 
 import jax
@@ -84,6 +92,10 @@ class AuditReport:
     eqns: int
     donated_expected: int = 0
     donated_consumed: int = 0
+    #: The compiled program's temporaries and their ceiling; None
+    #: where the audit set no ceiling (nothing was compiled).
+    temp_bytes: int | None = None
+    max_temp_bytes: int | None = None
 
     @property
     def ok(self) -> bool:
@@ -106,6 +118,8 @@ class AuditReport:
             "eqns": self.eqns,
             "donated_expected": self.donated_expected,
             "donated_consumed": self.donated_consumed,
+            "temp_bytes": self.temp_bytes,
+            "max_temp_bytes": self.max_temp_bytes,
         }
 
 
@@ -156,13 +170,16 @@ def _is_f64(aval) -> bool:
 def audit(fn, args, *, name: str = "", donate_argnums=(),
           expect_collectives: int | dict | None = None,
           allow_f64: bool = False, static_argnums=(),
-          check_donation: bool | None = None) -> AuditReport:
+          check_donation: bool | None = None,
+          max_temp_bytes: int | None = None) -> AuditReport:
     """Trace ``fn(*args)`` (args may be ShapeDtypeStructs — nothing
     executes) and audit the program. ``expect_collectives``: an int
     pins the TOTAL collective-primitive count, a dict pins per-prim
     counts (prims absent from the dict are unconstrained). With
     ``donate_argnums`` the program is additionally LOWERED (still no
     execution) and the donation must survive into the lowering text.
+    With ``max_temp_bytes`` it is also COMPILED for this backend (not
+    run) and its temporaries must come to less than that many bytes.
     Returns the report; call :meth:`AuditReport.raise_if_failed` to
     turn problems into a typed :class:`AuditError`."""
     problems: list[str] = []
@@ -218,14 +235,17 @@ def audit(fn, args, *, name: str = "", donate_argnums=(),
     donated_expected = donated_consumed = 0
     if check_donation is None:
         check_donation = bool(donate_argnums)
-    if check_donation and donate_argnums:
+    check_donation = check_donation and bool(donate_argnums)
+    lowered = None
+    if check_donation or max_temp_bytes is not None:
+        lowered = jax.jit(
+            fn, donate_argnums=donate_argnums,
+            static_argnums=static_argnums).lower(*args)
+    if check_donation:
         flat_args = []
         for i in donate_argnums:
             flat_args.extend(jax.tree_util.tree_leaves(args[i]))
         donated_expected = len(flat_args)
-        lowered = jax.jit(
-            fn, donate_argnums=donate_argnums,
-            static_argnums=static_argnums).lower(*args)
         text = lowered.as_text()
         donated_consumed = sum(text.count(m) for m in
                                _DONATION_MARKERS)
@@ -236,12 +256,23 @@ def audit(fn, args, *, name: str = "", donate_argnums=(),
                 f"buffers marked ({'/'.join(_DONATION_MARKERS)}) — "
                 f"the banks are being COPIED per step")
 
+    temp_bytes = None
+    if max_temp_bytes is not None:
+        temp_bytes = int(
+            lowered.compile().memory_analysis().temp_size_in_bytes)
+        if temp_bytes >= max_temp_bytes:
+            problems.append(
+                f"compiled temporaries {temp_bytes} B >= ceiling "
+                f"{max_temp_bytes} B — the program holds a copy of "
+                f"what it was given to update in place")
+
     return AuditReport(
         name=name or getattr(fn, "__name__", "<fn>"),
         problems=problems, collectives=collectives,
         callbacks=sorted(set(callbacks)), f64_sites=f64_sites,
         eqns=n_eqns, donated_expected=donated_expected,
-        donated_consumed=donated_consumed)
+        donated_consumed=donated_consumed, temp_bytes=temp_bytes,
+        max_temp_bytes=max_temp_bytes)
 
 
 # -------------------------------------------------------------- registry
@@ -256,7 +287,8 @@ DEFAULT_PROGRAMS = (
     "collectives.bucket_reduce_scatter",
     "collectives.hier_allreduce",
     "collectives.hier_reduce_scatter", "serve.decode_step",
-    "serve.spec_window", "serve.kv_pack", "serve.kv_unpack",
+    "serve.prefill_chunk", "serve.spec_window", "serve.kv_pack",
+    "serve.kv_unpack",
 )
 
 
@@ -540,40 +572,71 @@ def _build_hier_collective(kind: str):
     return builder
 
 
-def _build_decode_step(preset: str, n_slots: int, n_blocks: int,
-                       block_tokens: int):
+def _bank_aval(cfg, n_blocks: int, block_tokens: int):
+    """One float32 K (or V) bank of a paged pool, as a shape. float32
+    because the CPU backend widens a bfloat16 scatter's whole operand,
+    which would read as a copy of the bank that the chip never makes."""
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct(
+        (cfg.n_layers, n_blocks, block_tokens, cfg.kv_heads,
+         cfg.head_dim), jnp.float32)
+
+
+def _nbytes(aval) -> int:
+    return math.prod(aval.shape) * aval.dtype.itemsize
+
+
+def _build_paged_program(name: str, preset: str, n_slots: int,
+                         n_blocks: int, block_tokens: int,
+                         chunk: int):
+    """``serve.decode_step`` / ``serve.prefill_chunk``: the engine's
+    two paged programs as it jits them, banks donated. The pool is far
+    wider than a row's table (``cfg.max_seq`` tokens), so a bank
+    dominates every other temporary and the ceiling "less than one
+    bank" tells an in-place update from a copy."""
     def builder() -> AuditReport:
         import jax.numpy as jnp
 
         from ptype_tpu.models import generate as gen
 
         cfg, params_avals = _tiny_setup(preset)
-        B, nb, bt = n_slots, n_blocks, block_tokens
-        kvh = cfg.n_kv_heads or cfg.n_heads
-        hd = cfg.d_model // cfg.n_heads
-        bank = jax.ShapeDtypeStruct(
-            (cfg.n_layers, nb, bt, kvh, hd), jnp.float32)
+        B, nb = n_slots, cfg.max_seq // block_tokens
+        bank = _bank_aval(cfg, n_blocks, block_tokens)
         i32 = jnp.int32
 
-        def step(params, kb, vb, tok, pos, tables, wr_b, wr_o):
+        def decode_step(params, kb, vb, tok, pos, tables, wr_b, wr_o):
             return gen.decode_step_paged(params, tok, pos, cfg, kb,
                                          vb, tables, wr_b, wr_o)
 
-        args = (params_avals, bank, bank,
-                jax.ShapeDtypeStruct((B,), i32),
-                jax.ShapeDtypeStruct((B,), i32),
-                jax.ShapeDtypeStruct((B, nb), i32),
-                jax.ShapeDtypeStruct((B,), i32),
-                jax.ShapeDtypeStruct((B,), i32))
-        # Banks donated (the engine's donate_argnums=(2, 3) shape):
-        # a dropped donation copies the whole KV pool every step.
-        return audit(step, args, name="serve.decode_step",
-                     donate_argnums=(1, 2), expect_collectives=0)
+        def prefill_chunk(params, kb, vb, tokens, start, length,
+                          table):
+            return gen.prefill_paged_chunk(
+                params, tokens, start, length, cfg, kb, vb, table)
+
+        row = jax.ShapeDtypeStruct((B,), i32)
+        scalar = jax.ShapeDtypeStruct((), i32)
+        fn, rest = {
+            "serve.decode_step": (
+                decode_step,
+                (row, row, jax.ShapeDtypeStruct((B, nb), i32), row,
+                 row)),
+            "serve.prefill_chunk": (
+                prefill_chunk,
+                (jax.ShapeDtypeStruct((1, chunk), i32), scalar, scalar,
+                 jax.ShapeDtypeStruct((nb,), i32))),
+        }[name]
+        # Banks donated (the engine's donate_argnums shape): a dropped
+        # donation, or a loop that cannot alias them, copies the whole
+        # KV pool every step.
+        return audit(fn, (params_avals, bank, bank) + rest, name=name,
+                     donate_argnums=(1, 2), expect_collectives=0,
+                     max_temp_bytes=_nbytes(bank))
 
     return builder
 
 
-def _build_spec_window(preset: str, k: int):
+def _build_spec_window(preset: str, k: int, n_blocks: int):
     def builder() -> AuditReport:
         import jax.numpy as jnp
 
@@ -588,20 +651,16 @@ def _build_spec_window(preset: str, k: int):
         dp, dcfg = gen.truncated_draft_params(params, cfg, n_layers=1)
         eng = PagedGeneratorActor(
             cfg, params=params, n_slots=2, block_tokens=16,
+            n_blocks=n_blocks,
             spec=SpecConfig(draft_params=dp, draft_cfg=dcfg, k=k,
                             adaptive=False))
         try:
             W = k + 1
             B, nb = eng.n_slots, eng.nb
             i32, f32 = jnp.int32, jnp.float32
-            kvh = cfg.n_kv_heads or cfg.n_heads
-            hd = cfg.d_model // cfg.n_heads
-            bank = jax.ShapeDtypeStruct(
-                (cfg.n_layers, eng.pool.n_blocks, eng.block_tokens,
-                 kvh, hd), f32)
-            dbank = jax.ShapeDtypeStruct(
-                (dcfg.n_layers, eng.pool.n_blocks, eng.block_tokens,
-                 kvh, hd), f32)
+            bank = _bank_aval(cfg, eng.pool.n_blocks, eng.block_tokens)
+            dbank = _bank_aval(dcfg, eng.pool.n_blocks,
+                               eng.block_tokens)
             run = eng._window_prog(W, sampled=False)
             args = (
                 params, dp,
@@ -621,10 +680,13 @@ def _build_spec_window(preset: str, k: int):
             )
             # The REAL engine window program: fused draft scan +
             # batched verify + accept, both pools' banks donated,
-            # ONE dispatch per window, no collectives, no f64.
+            # ONE dispatch per window, no collectives, no f64, and no
+            # copy of a pool: all temporaries under one DRAFT bank,
+            # the smaller of the two kinds.
             return audit(run, args, name="serve.spec_window",
                          donate_argnums=(4, 5, 6, 7),
-                         expect_collectives=0)
+                         expect_collectives=0,
+                         max_temp_bytes=_nbytes(dbank))
         finally:
             eng.close()
 
@@ -708,10 +770,12 @@ def register_default_programs(preset: str = "tiny", batch: int = 4,
              _build_hier_collective("allreduce"))
     register("collectives.hier_reduce_scatter",
              _build_hier_collective("reduce_scatter"))
-    register("serve.decode_step",
-             _build_decode_step(preset, n_slots=2, n_blocks=12,
-                                block_tokens=16))
-    register("serve.spec_window", _build_spec_window(preset, spec_k))
+    for name in ("serve.decode_step", "serve.prefill_chunk"):
+        register(name, _build_paged_program(
+            name, preset, n_slots=2, n_blocks=256, block_tokens=16,
+            chunk=32))
+    register("serve.spec_window",
+             _build_spec_window(preset, spec_k, n_blocks=256))
     register("serve.kv_pack",
              _build_kv_pack(preset, n_blocks=12, block_tokens=16))
     register("serve.kv_unpack",

@@ -1,9 +1,10 @@
 """Progaudit contract tier (ISSUE 15): the jaxpr-level auditor's
 detectors (callbacks, f64 drift, collective-count fusion, donation
-consumption) on synthetic programs, and THE acceptance — the real
-hot-program registry (train grads, ZeRO shard-apply, bucketed
-allreduce/reduce-scatter, the paged decode step, the fused spec
-window) audits clean on the current tree."""
+consumption, compiled temporaries under a ceiling) on synthetic
+programs, and THE acceptance — the real hot-program registry (train
+grads, ZeRO shard-apply, bucketed allreduce/reduce-scatter, the paged
+decode step and prefill chunk, the fused spec window) audits clean on
+the current tree."""
 
 import jax
 import jax.numpy as jnp
@@ -110,6 +111,63 @@ def test_consumed_donation_passes():
     assert rep.ok and rep.donated_consumed >= 1, rep.to_dict()
 
 
+def test_temporaries_ceiling_compiles_and_compares():
+    """A 256 KiB product under a 1 KiB ceiling is flagged, under a
+    1 MiB ceiling it passes; with no ceiling nothing is compiled."""
+    def outer_sum(x):
+        return (jnp.outer(x, x) @ jnp.outer(x, x)).sum()
+
+    args = (jax.ShapeDtypeStruct((256,), jnp.float32),)
+    over = progaudit.audit(outer_sum, args, name="outer",
+                           max_temp_bytes=1 << 10)
+    assert not over.ok and over.temp_bytes >= 256 * 256 * 4
+    assert any("temporaries" in p for p in over.problems)
+    with pytest.raises(progaudit.AuditError, match="temporaries"):
+        over.raise_if_failed()
+    under = progaudit.audit(outer_sum, args, max_temp_bytes=1 << 20)
+    assert under.ok and under.temp_bytes == over.temp_bytes
+    assert under.to_dict()["max_temp_bytes"] == 1 << 20
+    assert progaudit.audit(outer_sum, args).temp_bytes is None
+
+
+@pytest.mark.parametrize("banks,ok", [("scanned", False),
+                                      ("carried", True)])
+def test_scanned_banks_keep_the_marker_and_break_the_ceiling(banks, ok):
+    """The shape of the paged layer loop before ISSUE 26 and after: a
+    donated stack of per-layer banks that a scan over layers updates a
+    few rows of. As a scanned input and output the donation marker is
+    in the lowering all the same, and the compiled program holds a
+    whole copy; as the carry, indexed by layer, it holds none."""
+    L, n, d = 4, 4096, 64
+    bank = jax.ShapeDtypeStruct((L, n, d), jnp.float32)
+    rows = jax.ShapeDtypeStruct((L, 8, d), jnp.float32)
+    idx = jax.ShapeDtypeStruct((8,), jnp.int32)
+
+    def scanned(bank, rows, idx):
+        def body(x, inp):
+            b, r = inp
+            b = b.at[idx].set(r)
+            return x + b[idx].sum(), b
+
+        return jax.lax.scan(body, 0.0, (bank, rows))
+
+    def carried(bank, rows, idx):
+        def body(carry, inp):
+            x, b = carry
+            l, r = inp
+            b = b.at[l, idx].set(r)
+            return (x + b[l, idx].sum(), b), None
+
+        return jax.lax.scan(body, (0.0, bank), (jnp.arange(L), rows))[0]
+
+    rep = progaudit.audit(
+        {"scanned": scanned, "carried": carried}[banks],
+        (bank, rows, idx), name=banks, donate_argnums=(0,),
+        max_temp_bytes=L * n * d * 4)
+    assert rep.donated_consumed == rep.donated_expected == 1
+    assert rep.ok is ok, rep.to_dict()
+
+
 # ------------------------------------------------------------- registry
 
 
@@ -143,6 +201,12 @@ def test_real_hot_programs_audit_clean():
     win = reports["serve.spec_window"]
     assert win.donated_consumed == win.donated_expected == 4
     assert win.collectives == {} and dec.collectives == {}
+    pre = reports["serve.prefill_chunk"]
+    assert pre.donated_consumed == pre.donated_expected == 2
+    # The paged programs update their donated banks in place: all the
+    # compiled temporaries together come to less than one bank.
+    for rep in (dec, pre, win):
+        assert 0 < rep.temp_bytes < rep.max_temp_bytes, rep.to_dict()
 
 
 def test_hier_programs_pin_per_leg_launches():
