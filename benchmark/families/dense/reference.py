@@ -1,8 +1,9 @@
-"""The plain reference: a decoder-only transformer (RMSNorm, rotary
-positions in the half-split convention, grouped-query causal attention,
-SwiGLU), its loss, gradients and AdamW, in straightforward ``jax.numpy``.
-No kernels, no cache, no batching tricks, nothing imported from the
-program. ``mode`` picks the arithmetic of every matmul:
+"""The dense family's plain reference: a decoder-only transformer
+(RMSNorm, rotary positions in the half-split convention, grouped-query
+causal attention, SwiGLU), its loss, gradients and AdamW, in
+straightforward ``jax.numpy``. No kernels, no cache, no batching tricks,
+nothing imported from the program. ``mode`` picks the arithmetic of
+every matmul:
 
 - ``f32``  float32 operands at ``highest`` precision — the reference;
 - ``bf16`` operands rounded to bfloat16 — what the configurations state;
@@ -18,7 +19,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmark import weights, work
+from benchmark.families.dense import weights, work
 
 QBLOCK = 512
 

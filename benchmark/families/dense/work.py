@@ -1,9 +1,13 @@
-"""Operations and bytes the algorithms need, counted from shapes alone.
+"""Operations and bytes the dense block's algorithms need, counted from
+shapes alone.
 
 These are the yardstick's numerators: a roofline share divides what the
 work *needs* by what the device took, so nothing here looks at how the
 program moves data. ``cfg`` is a configuration file's dict (the public
-``config.json`` key names)."""
+``config.json`` key names). The serving counts take what the cell saw,
+one context length per row or per token, and not a digest of it
+(``benchmark/family.py``); for this block every key costs the same, so
+they sum the lengths."""
 
 from __future__ import annotations
 
@@ -48,13 +52,13 @@ def train_flops_per_token(cfg: dict, seq: int) -> float:
     return 6.0 * matmul_params(cfg) + 12.0 * L * H * Dh * seq
 
 
-def forward_flops(cfg: dict, n_tokens: int, ctx_sum: int) -> float:
-    """Forward FLOPs of ``n_tokens`` tokens whose attention contexts
-    (keys each one attends to, itself included) sum to ``ctx_sum``:
-    2 per matmul parameter per token, and QK^T + PV = 4·H·Dh per key
-    per layer."""
+def forward_flops(cfg: dict, n_tokens: int, contexts) -> float:
+    """Forward FLOPs of ``n_tokens`` tokens, ``contexts`` holding for
+    each the keys it attends to (itself included): 2 per matmul
+    parameter per token, and QK^T + PV = 4·H·Dh per key per layer."""
     L, _, H, _, Dh, _, _ = dims(cfg)
-    return 2.0 * matmul_params(cfg) * n_tokens + 4.0 * L * H * Dh * ctx_sum
+    return (2.0 * matmul_params(cfg) * n_tokens
+            + 4.0 * L * H * Dh * sum(contexts))
 
 
 def flash_train_flops(cfg: dict, batch: int, seq: int) -> dict:
@@ -93,17 +97,19 @@ def flash_train_floor_s(cfg: dict, batch: int, seq: int, peaks: dict
             "bound": "flops" if t_fl >= t_by else "hbm"}
 
 
-def kv_bytes_per_token(cfg: dict, itemsize: int = 2) -> int:
+def cache_bytes_per_token(cfg: dict, itemsize: int = 2) -> int:
     """Bytes of K and V one token holds across all layers."""
     L, _, _, K, Dh, _, _ = dims(cfg)
     return 2 * K * Dh * itemsize * L
 
 
-def decode_needed_bytes(cfg: dict, unique_ctx_tokens: int,
+def decode_needed_bytes(cfg: dict, row_contexts, shared_tokens: int = 0,
                         itemsize: int = 2) -> float:
     """HBM bytes one decode iteration must read: every matmul weight
     once (the embedding rows of a handful of tokens are nothing beside
-    them) and the K, V of the tokens its rows hold — a block shared by
-    several rows once."""
+    them) and the K, V of the tokens its rows hold (``row_contexts``,
+    one length a live row) — a block shared by several rows once:
+    ``shared_tokens`` are the tokens the lengths count again."""
     return (float(matmul_params(cfg)) * itemsize
-            + float(unique_ctx_tokens) * kv_bytes_per_token(cfg, itemsize))
+            + float(sum(row_contexts) - shared_tokens)
+            * cache_bytes_per_token(cfg, itemsize))

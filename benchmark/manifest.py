@@ -7,6 +7,8 @@ import json
 import os
 import re
 
+from benchmark import family
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -93,6 +95,10 @@ def check(m: dict, root: str = ROOT) -> list[str]:
         if not under_paths(f) or not os.path.isfile(os.path.join(root, f)):
             errs.append(f"config {c['name']}: file {f!r} is missing or "
                         f"outside paths")
+        else:
+            with open(os.path.join(root, f)) as fh:
+                errs.extend(f"config {c['name']}: {e}"
+                            for e in family.faults(json.load(fh)))
         if f in files:
             errs.append(f"config file {f!r} serves two configurations")
         files.add(f)

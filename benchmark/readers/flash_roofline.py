@@ -1,4 +1,4 @@
-from benchmark import work, xplane
+from benchmark import family, xplane
 
 
 def read(ctx, pattern: str, step_pattern: str):
@@ -16,8 +16,8 @@ def read(ctx, pattern: str, step_pattern: str):
                               device=0)["calls"]
     if not steps:
         return None
-    floor = work.flash_train_floor_s(ctx["cfg"], c["per_chip_batch"],
-                                     c["seq"], ctx["peaks"])
+    floor = family.of(ctx["cfg"]).flash_train_floor_s(
+        ctx["cfg"], c["per_chip_batch"], c["seq"], ctx["peaks"])
     ctx["notes"]["flash_bound"] = floor["bound"]
     return (100.0 * floor["floor_s"] * steps * got["devices"]
             / got["seconds"])

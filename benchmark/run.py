@@ -9,8 +9,10 @@ cluster the way the examples do, makes weights on the device from
 ``--seconds``, checks what the timed path produced against the plain
 reference, and prints one JSON line last. Which cell does what is data:
 ``BENCHMARK.json`` names a configuration file and a traffic file; the
-traffic file's ``kind`` picks the driver; each per-layer metric is a file
-under ``metrics/`` naming a reader under ``readers/``."""
+configuration's ``family`` names the module under ``families/`` that
+knows its architecture (``benchmark/family.py``); the traffic file's
+``kind`` picks the driver; each per-layer metric is a file under
+``metrics/`` naming a reader under ``readers/``."""
 
 from __future__ import annotations
 

@@ -18,7 +18,7 @@ from unittest import mock
 
 import numpy as np
 
-from benchmark import adapter, harness, reference, traffic, weights, work
+from benchmark import family, harness, traffic
 
 
 def prompt_key(prompt) -> tuple:
@@ -131,11 +131,10 @@ class Server:
         mix, cfg = ctx["mix"], ctx["cfg"]
         eng = dict(mix["engine"])
         device = ctx["devices"][0]
-        tcfg = adapter.transformer_config(cfg, eng["max_len"],
-                                          cfg["param_dtype"])
+        fam = family.of(cfg)
+        tcfg = fam.program_config(cfg, eng["max_len"], cfg["param_dtype"])
         sharding = jax.sharding.SingleDeviceSharding(device)
-        params = weights.tree(cfg, ctx["seed"], cfg["param_dtype"],
-                              sharding)
+        params = fam.tree(cfg, ctx["seed"], cfg["param_dtype"], sharding)
         self.taps = None
 
         def make(device=None):
@@ -310,25 +309,28 @@ def reduce(ctx, server: Server, drove: dict) -> dict:
         counters["prefix_hit_pct"] = 100.0 * sum(
             t.rec.reused_blocks * bt for t in with_rec) / ptoks
 
-    # What the window's iterations needed, from the benchmark's own count.
+    # What the window's iterations needed, from the family's own count
+    # of what each saw: one context length a live row, and a chunk's
+    # tokens each with the keys it attends to.
+    fam = family.of(cfg)
     emitted: dict[int, int] = {}
     need_bytes, flops, decode_steps = 0.0, 0.0, 0
     prev_t = None
     iter_ms = []
     for t_step, live in server.taps.steps:
-        ctxs, groups = 0, {}
+        ctxs, groups = [], {}
         for tap in live:
             k = id(tap)
             n = emitted.get(k, 1)  # the first token came from prefill
-            ctxs += len(tap.req.prompt) + n
+            ctxs.append(len(tap.req.prompt) + n)
             emitted[k] = n + 1
             if tap.req.group >= 0:
                 groups[tap.req.group] = groups.get(tap.req.group, 0) + 1
         if t_open <= t_step <= t_close and live:
             shared = sum((c - 1) * (live[0].req.shared_tokens // bt) * bt
                          for c in groups.values() if c > 1)
-            need_bytes += work.decode_needed_bytes(cfg, ctxs - shared)
-            flops += work.forward_flops(cfg, len(live), ctxs)
+            need_bytes += fam.decode_needed_bytes(cfg, ctxs, shared)
+            flops += fam.forward_flops(cfg, len(live), ctxs)
             decode_steps += 1
             if prev_t is not None:
                 iter_ms.append((t_step - prev_t) * 1e3)
@@ -339,8 +341,8 @@ def reduce(ctx, server: Server, drove: dict) -> dict:
         pos = tap.rec.reused_blocks * bt
         for t_chunk, n in tap.chunks:
             if t_open <= t_chunk <= t_close:
-                flops += work.forward_flops(
-                    cfg, n, n * pos + n * (n + 1) // 2)
+                flops += fam.forward_flops(
+                    cfg, n, range(pos + 1, pos + n + 1))
             pos += n
     counters["decode_needed_bytes"] = need_bytes
     counters["model_flops_traced"] = flops
@@ -390,7 +392,7 @@ def served_gaps(cfg: dict, seed: int, sample: list, modes=("f32",),
         idx[r, m:] = idx[r, m - 1]
         want[r, :m] = o
         mask[r, :m] = True
-    logits = reference.served_logits(
+    logits = family.of(cfg).served_logits(
         cfg, seed, cfg["param_dtype"], jnp.asarray(toks), jnp.asarray(idx),
         modes=tuple(modes))
     ref = np.asarray(logits["f32"])

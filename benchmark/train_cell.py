@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import time
 
-from benchmark import adapter, harness, reference, traffic, weights, work
+from benchmark import family, harness, traffic
 
 FAULTS = ("state_unchanged", "half_batch", "no_exchange")
 
@@ -29,14 +29,15 @@ def build(ctx):
     if n_dev != ctx["chips"]:
         raise SystemExit(f"benchmark: mesh covers {n_dev} devices, the "
                          f"cell asks for {ctx['chips']}")
-    tcfg = adapter.transformer_config(cfg, mix["seq"], cfg["param_dtype"])
+    fam = family.of(cfg)
+    tcfg = fam.program_config(cfg, mix["seq"], cfg["param_dtype"])
     if mix.get("remat_policy"):
         from dataclasses import replace
 
         tcfg = replace(tcfg, remat=True, remat_policy=mix["remat_policy"])
     batch = int(mix["per_chip_batch"]) * n_dev
     repl = NamedSharding(mesh, P())
-    params = weights.tree(cfg, ctx["seed"], cfg["param_dtype"], repl)
+    params = fam.tree(cfg, ctx["seed"], cfg["param_dtype"], repl)
     if mix["trainer"] == "gspmd":
         from ptype_tpu.train.trainer import Trainer, TrainState
 
@@ -202,6 +203,8 @@ def run(ctx) -> dict:
         window_s = time.perf_counter() - t_open
     compiles = counter.n - compiles0
     tokens = steps * batch * int(mix["seq"])
+    flops_per_token = family.of(cfg).train_flops_per_token(
+        cfg, int(mix["seq"]))
     peak = harness.memory_peak_bytes(ctx["devices"])
     final_loss = float(out["loss"])
 
@@ -229,10 +232,8 @@ def run(ctx) -> dict:
                      "seq": int(mix["seq"]),
                      "compiles_in_window": compiles,
                      "not_compared": rest,
-                     "model_flops_traced": tokens
-                     * work.train_flops_per_token(cfg, int(mix["seq"])),
-                     "flops_per_token": work.train_flops_per_token(
-                         cfg, int(mix["seq"]))},
+                     "model_flops_traced": tokens * flops_per_token,
+                     "flops_per_token": flops_per_token},
         "memory_peak_bytes": peak, "readings": extra,
     }
 
@@ -245,11 +246,12 @@ def follow(ctx, batch: int, n_check: int, mode: str = "f32",
     import numpy as np
 
     mix, cfg = ctx["mix"], ctx["cfg"]
-    params = weights.tree(cfg, ctx["seed"], "float32")
+    fam = family.of(cfg)
+    params = fam.tree(cfg, ctx["seed"], "float32")
     make = traffic.train_batch_fn(int(cfg["vocab_size"]), batch,
                                   int(mix["seq"]), ctx["seed"])
     batches = [make(np.int32(i)) for i in range(n_check)]
-    losses, g1, after = reference.train_steps(
+    losses, g1, after = fam.train_steps(
         cfg, cfg["training"], params, batches, mode,
         int(mix.get("reference_micro_rows", 4)), rows, frozen_state)
     import jax

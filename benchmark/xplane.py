@@ -250,11 +250,17 @@ def idle_gaps_by_host_span(tr: dict, n: int = 10,
                    if name not in ignore and d > 0 and s < hi
                    and s + d > lo), key=lambda e: e[0])
     acc: dict[str, int] = {}
+    # Gaps come in order of time: a span that ended before one gap has
+    # ended before every later one, so only the spans still open (in
+    # their order of start) are looked at, not all of them for each gap.
+    nxt, open_spans = 0, []
     for a, b in gaps(busy[0], lo, hi):
+        while nxt < len(host) and host[nxt][0] < b:
+            open_spans.append(host[nxt])
+            nxt += 1
+        open_spans = [h for h in open_spans if h[1] > a]
         best, best_key = "no host span", (0, 0)
-        for s, e, name in host:
-            if s >= b:
-                break
+        for s, e, name in open_spans:
             ov = min(e, b) - max(s, a)
             if ov > 0 and (ov, -(e - s)) > best_key:
                 best, best_key = name, (ov, -(e - s))

@@ -6,9 +6,20 @@ package; only the data it is pointed at is small."""
 import json
 import os
 import shutil
+import sys
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import benchmark.families  # noqa: E402
+
+# A family is a file found by name under ``benchmark/families/``. The
+# tests' second family (families/twin.py) enters the same way, from this
+# directory, with no shipped file touched.
+if os.path.join(HERE, "families") not in benchmark.families.__path__:
+    benchmark.families.__path__.append(os.path.join(HERE, "families"))
 
 #: The long-context mix at ISSUE 24's own sizes (what the chip runs of
 #: PR 24 used, with the pool cut to 3,072 blocks): the next benchmark PR
@@ -78,6 +89,7 @@ GATEWAY = {"per_replica_inflight": 4096, "max_queue_depth": 4096,
 
 STORE_CELL = "optimus-125m.train-store-4chip"
 LONG_CELL = "mistral-7b.serve-longctx"
+TWIN_CELL = "dense-twin.serve-chat"
 ENGINE_STEP = "^jit_engine_step\\("
 #: ISSUE 24's cell 4 (PERF.md §7: runs correctly on the chip, too unsteady
 #: to judge): name → (layer, unit, better, source, metric file).
@@ -108,7 +120,9 @@ def manifest() -> dict:
     yet (PERF.md §7): the four-chip Store cell and the long-context
     closed loop, so that the paths they need — ``StoreDPTrainer``, the
     exchange fault, the collective readers, the closed-loop driver —
-    stay rehearsed until a later PR adds the cells as data."""
+    stay rehearsed until a later PR adds the cells as data. And a
+    configuration of the tests' second family under the chat mix, added
+    the way a ``model_config`` PR adds one: entries here, files beside."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         m = json.load(f)
     train1 = next(w for w in m["workloads"]
@@ -126,6 +140,15 @@ def manifest() -> dict:
     chat = next(w for w in m["workloads"]
                 if w["name"] == "mistral-7b.serve-chat")
     m["workloads"].append({**chat, "name": LONG_CELL, "traffic": "longctx"})
+    m["configs"].append({
+        "name": "dense-twin", "source": "tests/benchmark/families/twin.py",
+        "file": "benchmark/configs/dense-twin.json", "reduced": [],
+        "why": "the dense block under a second family's name"})
+    m["workloads"].append({**chat, "name": TWIN_CELL,
+                           "config": "dense-twin"})
+    for x in m["end_to_end"] + m["per_layer"]:
+        if chat["name"] in x.get("workloads", ()):
+            x["workloads"].append(TWIN_CELL)
     m["end_to_end"].append({
         "name": "serve_tok_s", "unit": "tokens/s", "better": "higher",
         "bound": 0.1, "source": "host_clock", "workloads": [LONG_CELL]})
@@ -138,6 +161,17 @@ def manifest() -> dict:
             "layer": layer, "moves": "serve_tok_s",
             "workloads": [LONG_CELL]})
     return m
+
+
+def copy_shipped(dst) -> None:
+    """``BENCHMARK.json`` and the directories under its ``paths``, and
+    nothing else of the repo, into ``dst``."""
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), dst)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        paths = json.load(f)["paths"]
+    for p in paths:
+        shutil.copytree(os.path.join(ROOT, p), os.path.join(dst, p),
+                        ignore=shutil.ignore_patterns("__pycache__"))
 
 
 def make_root(tmp: str) -> str:
@@ -167,12 +201,13 @@ def make_root(tmp: str) -> str:
          "params": {"step_pattern": STEP, "exposed": True}})
 
     put("configs/optimus-125m.json", {
-        **TINY_MODEL, "num_key_value_heads": 4,
+        **TINY_MODEL, "family": "dense", "num_key_value_heads": 4,
         "tie_word_embeddings": True, "param_dtype": "float32",
         "training": training})
-    put("configs/mistral-7b.json", {
-        **TINY_MODEL, "vocab_size": 4096, "num_key_value_heads": 2,
-        "tie_word_embeddings": False, "param_dtype": "bfloat16"})
+    served = {**TINY_MODEL, "vocab_size": 4096, "num_key_value_heads": 2,
+              "tie_word_embeddings": False, "param_dtype": "bfloat16"}
+    put("configs/mistral-7b.json", {**served, "family": "dense"})
+    put("configs/dense-twin.json", {**served, "family": "twin"})
     train = {"kind": "train", "seq": 64, "per_chip_batch": 2,
              "check_steps": 3, "reference_micro_rows": 2}
     put("traffic/train-s1024.json", {**train, "trainer": "gspmd"})

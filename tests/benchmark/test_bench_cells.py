@@ -5,7 +5,6 @@ device metric from a CPU."""
 
 import json
 import os
-import shutil
 import subprocess
 import sys
 
@@ -82,10 +81,7 @@ def test_no_chip_no_number():
 
 
 def test_alone_in_a_directory_it_prints_no_result(tmp_path):
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
-    for p in M["paths"]:
-        shutil.copytree(os.path.join(ROOT, p), tmp_path / p,
-                        ignore=shutil.ignore_patterns("__pycache__"))
+    perfbench_tiny.copy_shipped(tmp_path)
     got = run_cli(str(tmp_path), "--workload", CELLS[0][0], "--seed", "1",
                   "--seconds", "1", "--trace", "0")
     assert got.returncode != 0 and got.stdout.strip() == ""

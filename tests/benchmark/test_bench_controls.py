@@ -61,7 +61,7 @@ def test_rounding_modes_round():
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference
+    from benchmark.families.dense import reference
 
     x = jnp.asarray([1.0 + 2.0 ** -9, 1.0 + 2.0 ** -5, 3.3], jnp.float32)
     assert np.array_equal(reference._round(x, "f32"), x)

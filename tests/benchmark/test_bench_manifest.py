@@ -13,7 +13,9 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import perfbench_tiny  # noqa: E402
 from benchmark import manifest  # noqa: E402
 
 
@@ -150,6 +152,77 @@ def test_broken_copy_is_refused(breaker, says):
     assert any(says in e for e in errs), errs
 
 
+# A configuration names its family; the family is a module found by
+# that name which holds every function of benchmark/family.py.
+
+
+def _family_struck(cfg, monkeypatch):
+    del cfg["family"]
+
+
+def _family_unknown(cfg, monkeypatch):
+    cfg["family"] = "no_such_family"
+
+
+def _family_is_a_path(cfg, monkeypatch):
+    cfg["family"] = "dense.work"
+
+
+def _family_lacks_a_function(cfg, monkeypatch):
+    from benchmark.families import twin
+
+    cfg["family"] = "twin"
+    monkeypatch.delattr(twin, "forward_flops")
+
+
+BROKEN_FAMILY = [
+    (_family_struck, "states no family"),
+    (_family_unknown, "no family 'no_such_family' under"),
+    (_family_is_a_path, "states no family"),
+    (_family_lacks_a_function, "family 'twin' lacks forward_flops"),
+]
+
+
+@pytest.mark.parametrize("breaker,says", BROKEN_FAMILY,
+                         ids=[b.__name__.lstrip("_")
+                              for b, _ in BROKEN_FAMILY])
+def test_configuration_without_a_whole_family_is_refused(
+        breaker, says, tmp_path, monkeypatch):
+    root = perfbench_tiny.make_root(str(tmp_path))
+    m = perfbench_tiny.manifest()
+    assert manifest.check(m, root) == []    # the twin's cell included
+    f = os.path.join(root, "benchmark", "configs", "mistral-7b.json")
+    with open(f) as fh:
+        cfg = json.load(fh)
+    breaker(cfg, monkeypatch)
+    with open(f, "w") as fh:
+        json.dump(cfg, fh)
+    errs = manifest.check(m, root)
+    assert [e for e in errs if "config mistral-7b" in e and says in e], errs
+
+
+@pytest.mark.parametrize("family,says", [
+    (None, "states no family"), ("glm5", "no family 'glm5' under")],
+    ids=["struck", "no-module"])
+def test_check_command_names_a_family_fault(family, says, tmp_path):
+    """The command itself, over a copy of the shipped benchmark."""
+    perfbench_tiny.copy_shipped(tmp_path)
+    f = tmp_path / "benchmark" / "configs" / "optimus-125m.json"
+    cfg = json.loads(f.read_text())
+    if family is None:
+        del cfg["family"]
+    else:
+        cfg["family"] = family
+    f.write_text(json.dumps(cfg))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    got = subprocess.run([sys.executable, "benchmark/run.py", "--check"],
+                         cwd=tmp_path, env=env, capture_output=True,
+                         text=True, timeout=120)
+    assert got.returncode == 1
+    assert "config optimus-125m" in got.stderr and says in got.stderr
+    assert got.stdout.strip().endswith("1 fault(s)")
+
+
 def test_run_refuses_a_broken_manifest(tmp_path, monkeypatch):
     """The harness itself refuses before it looks for a chip."""
     from benchmark import run
@@ -167,6 +240,8 @@ def test_every_named_file_is_found_by_name():
         with open(os.path.join(ROOT, c["file"])) as f:
             cfg = json.load(f)
         assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+        base = os.path.join(ROOT, "benchmark", "families", cfg["family"])
+        assert os.path.isdir(base) or os.path.isfile(base + ".py")
     for w in m["workloads"]:
         assert manifest.traffic_file(w["traffic"], m["paths"], ROOT)
     for x in m["per_layer"]:

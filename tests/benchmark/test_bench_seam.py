@@ -82,6 +82,12 @@ def capture(tmp_path_factory):
                 with jax.profiler.TraceAnnotation(xplane.WINDOW_SPAN):
                     for p in prompts:
                         assert gw.generate(p, 4).shape[-1] == 4
+                # The last answer leaves from inside the iteration that
+                # retires it: a capture stopped now can hold that
+                # ``serve.step``'s phases and not the step. With its
+                # work done the loop thread ends at once, and every
+                # region it opened has closed.
+                engine.close()
             finally:
                 jax.profiler.stop_trace()
         finally:

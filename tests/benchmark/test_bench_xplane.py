@@ -18,7 +18,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import perfbench_tiny  # noqa: E402
-from benchmark import peaks, work, xplane  # noqa: E402
+from benchmark import family, peaks, xplane  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 MS = 1_000_000
@@ -119,6 +119,52 @@ def test_idle_gaps_go_to_what_the_host_was_doing():
     assert got["rpc.recv"] == pytest.approx(0.006)
 
 
+def gaps_every_span_for_every_gap(tr, ignore=("bench.window",)):
+    """The attribution as first written: every host span looked at for
+    every gap (minutes on a 40-s capture of the chat cell). Kept as the
+    reference for the pass over the spans still open."""
+    lo, hi = xplane.window(tr)
+    busy = xplane.busy_by_device(tr, lo, hi)
+    host = sorted(([s, s + d, name] for name, s, d in xplane.host_events(tr)
+                   if name not in ignore and d > 0 and s < hi
+                   and s + d > lo), key=lambda e: e[0])
+    acc = {}
+    for a, b in xplane.gaps(busy[0], lo, hi):
+        best, best_key = "no host span", (0, 0)
+        for s, e, name in host:
+            if s >= b:
+                break
+            ov = min(e, b) - max(s, a)
+            if ov > 0 and (ov, -(e - s)) > best_key:
+                best, best_key = name, (ov, -(e - s))
+        acc[best] = acc.get(best, 0) + (b - a)
+    return [[name, ns / 1e9] for name, ns in
+            sorted(acc.items(), key=lambda kv: -kv[1])[:10]]
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_idle_gaps_are_those_of_the_plain_double_loop(seed):
+    """Nested, overlapping and request-long spans, ties included."""
+    import random
+
+    rng = random.Random(seed)
+    for _ in range(60):
+        t, ops = 0, []
+        for _ in range(rng.randint(1, 60)):
+            t += rng.randint(0, 3) * MS // 2
+            ops.append(("%op = x", t, rng.randint(1, 5) * MS))
+            t += ops[-1][2]
+        host = [(rng.choice(["serve.step", "serve.step/fetch", "rpc.call",
+                             "actor/Generator.Generate"]),
+                 rng.randint(0, t),
+                 rng.choice([0, 1, 1, 2, 5, 50, 500]) * MS
+                 + rng.randint(0, 3))
+                for _ in range(rng.randint(0, 80))]
+        tr = made(ops, host, window=(0, t))
+        assert xplane.idle_gaps_by_host_span(tr) == \
+            gaps_every_span_for_every_gap(tr)
+
+
 def test_span_time_with_no_device_op():
     ops = [("%fusion.1 = x", 10 * MS, 10 * MS)]
     host = [("serve.step", 5 * MS, 20 * MS), ("serve.step", 40 * MS, 10 * MS)]
@@ -188,7 +234,7 @@ def test_recorded_readers(recorded, tmp_path):
            "counters": {"per_chip_batch": 16, "seq": 1024,
                         "compiles_in_window": 0,
                         "model_flops_traced": tokens
-                        * work.train_flops_per_token(cfg, 1024)}}
+                        * family.of(cfg).train_flops_per_token(cfg, 1024)}}
 
     def read(metric):
         with open(os.path.join(tiny_root, "benchmark", "metrics",
