@@ -657,15 +657,15 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     wr_b = tables[jnp.arange(n_slots), pos // bt]
 
     def decode(impl):
-        return jax.jit(lambda p, kb, vb: gen.decode_step_paged(
-            p, tok, pos, wide, kb, vb, tables, wr_b, pos % bt,
-            attn_impl=impl)[0])
+        return jax.jit(lambda p, kb, vb: gen.decode_step_banks(
+            p, tok, pos, wide, {"k": kb, "v": vb}, tables, wr_b,
+            pos % bt, attn_impl=impl)[0])
 
     check_kernels(decode("kernel"), (params, kb, vb), (KERNEL_NAME,),
                   n_slots)
     out["paged_decode_step_err"] = round(_close(
         decode("kernel")(params, kb, vb), decode("gather")(params, kb, vb),
-        'decode_step_paged attn="kernel" vs "gather" logits'), 5)
+        'decode_step_banks attn="kernel" vs "gather" logits'), 5)
 
     # Lowerings with no Pallas in them that no TPU compiler had seen.
     moe = tfm.preset("tiny-moe", attn_impl="xla")

@@ -369,6 +369,11 @@ class ServingLedger:
         #: migrate histogram/summary keys stay absent until > 0, so
         #: a non-disaggregated replica's Info() is migration-free.
         self._migrated = 0
+        #: A dropless router's load (moe_load): running totals per
+        #: held expert, last the assignments held elsewhere; absent
+        #: from the summary until an iteration reported some.
+        self._moe_load: list[int] = []
+        self._moe_iters = 0
 
     # --------------------------------------------------- request seams
 
@@ -442,6 +447,26 @@ class ServingLedger:
             return
         for rec, n in zip(recs, counts):
             rec.tok_t.extend([now] * int(n))
+
+    def moe_load(self, counts) -> None:
+        """One decode iteration's router load, as the step counted it
+        on the device: assignments per held expert, summed over the
+        expert layers, and last those that fell on no held expert.
+        Kept as running totals (``summary()["moe_load"]``) and emitted
+        as a ``serve.moe_load`` record through the one seam."""
+        counts = [int(c) for c in counts]
+        with self._lock:
+            if len(self._moe_load) != len(counts):
+                self._moe_load = [0] * len(counts)
+            self._moe_load = [a + b for a, b in
+                              zip(self._moe_load, counts)]
+            self._moe_iters += 1
+        # ":" between the counts: a profiler annotation's metadata is
+        # itself a comma-separated list.
+        with trace.span("serve.moe_load",
+                        held=":".join(str(c) for c in counts[:-1]),
+                        elsewhere=counts[-1]):
+            pass
 
     def shed_untracked(self) -> None:
         """A shed before any record existed (the chaos admit seam)."""
@@ -580,7 +605,12 @@ class ServingLedger:
             spec_acc = self._spec_accepted
             spec_toks = self._spec_tokens
             migrated = self._migrated
+            moe_load, moe_iters = list(self._moe_load), self._moe_iters
         out = {}
+        if moe_iters:
+            out["moe_load"] = {"iterations": moe_iters,
+                               "held": moe_load[:-1],
+                               "elsewhere": moe_load[-1]}
         if spec_prop:
             # Only once speculation actually ran: a non-speculative
             # replica's Info() stays spec-free, so fleet views can

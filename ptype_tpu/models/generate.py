@@ -61,6 +61,7 @@ jax.tree_util.register_pytree_node(
 
 def init_cache(cfg: tfm.TransformerConfig, batch: int,
                max_seq: int | None = None) -> KVCache:
+    _gqa_one_group(cfg, "the contiguous K,V cache")
     S = max_seq or cfg.max_seq
     shape = (cfg.n_layers, batch, S, cfg.kv_heads, cfg.head_dim)
     return KVCache(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
@@ -190,7 +191,8 @@ def prefill(params: dict, tokens: jax.Array, cfg: tfm.TransformerConfig,
         with jax.named_scope("attn"):
             o = attn(q, k, v)
         x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=cap)
+        x, _aux, _load = tfm.mlp_residual(x, layer, cfg,
+                                          moe_capacity=cap)
         with jax.named_scope("kv_write"):
             kc = lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
             vc = lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
@@ -199,7 +201,7 @@ def prefill(params: dict, tokens: jax.Array, cfg: tfm.TransformerConfig,
     x, (kcs, vcs) = lax.scan(body, x,
                              (params["blocks"], cache.k, cache.v))
     with jax.named_scope("head"):
-        x = tfm.rms_norm(x, params["final_norm"])
+        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         x_last = (x[:, -1] if last_index is None
                   else x[jnp.arange(B), last_index])
         logits = _head_logits(params, x_last, cfg)
@@ -237,13 +239,14 @@ def decode_step(params: dict, token: jax.Array, pos: jax.Array,
         o = _cached_attention(q, kc, vc, pos + 1, cfg,
                               valid_from=valid_from)
         x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg, moe_capacity=B)
+        x, _aux, _load = tfm.mlp_residual(x, layer, cfg,
+                                          moe_capacity=B)
         return x, (kc, vc)
 
     x, (kcs, vcs) = lax.scan(body, x,
                              (params["blocks"], cache.k, cache.v))
     with jax.named_scope("head"):
-        x = tfm.rms_norm(x, params["final_norm"])
+        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x[:, 0], cfg)
     return logits, KVCache(kcs, vcs)
 
@@ -287,78 +290,122 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
         return o.reshape(B, Q, H, Dh)
 
 
-def _paged_layers(params, tokens, positions, cfg, kb, vb, tables, wr_b,
-                  wr_o, limits, moe_capacity, attend=None):
+def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
+                  wr_o, limits, moe_capacity, attend=None, live=None,
+                  chunk: bool = False):
     """Embedding and the ONE layer loop of the paged programs (decode
-    step, prefill chunk, speculative verify and draft). The banks ride
-    the scan's CARRY, whole, and a layer reaches its own rows through
-    the indices of its scatter and gather: in the free flat view
-    ``(L * n_blocks, block_tokens, Kh, Dh)`` layer ``l``'s block ``b``
-    is row ``l * n_blocks + b``. (Scanned, every layer sliced its bank
-    out of the stack and wrote all of it back, and the program copied
-    both banks again: a scanned output cannot alias a donated input.)
+    step, prefill chunk, speculative verify and draft). ``banks`` is
+    the cache as the model describes it (``tfm.cache_spec``: a dict of
+    ``(L, n_blocks, block_tokens, ...)`` arrays — ``k``, ``v`` for GQA,
+    ``ckv``, ``ki`` for latent attention). The banks ride the scan's
+    CARRY, whole, and a layer reaches its own rows through the indices
+    of its scatter and gather: in the free flat view ``(L * n_blocks,
+    block_tokens, ...)`` layer ``l``'s block ``b`` is row ``l *
+    n_blocks + b``. (Scanned, every layer sliced its bank out of the
+    stack and wrote all of it back, and the program copied the banks
+    again: a scanned output cannot alias a donated input.) A stack of
+    several groups (``tfm.layer_groups``) is one scan a group, the
+    same carry walking through them.
 
     ``tokens``/``positions``/``wr_b``/``wr_o`` (B, Q): each position's
-    K/V row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name the
-    trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q)) as
-    :func:`_paged_attention_gather` takes them. ``attend(q, kc, vc)``,
-    if given, replaces the gather path on the layer's own bank, sliced
-    out of the carry (the Pallas kernel wants one layer, head-major).
-    Returns ``(x (B, Q, D) before the final norm, kb, vb)``."""
-    L, n_blocks = kb.shape[:2]
-    flat = (L * n_blocks,) + kb.shape[2:]
+    cache row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name
+    the trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q))
+    as :func:`_paged_attention_gather` takes them. ``attend(q, kc,
+    vc)``, if given, replaces the gather path on the layer's own bank,
+    sliced out of the carry (the Pallas kernel wants one layer,
+    head-major; GQA only). ``chunk``: the queries are a prefill
+    chunk's, many to a table (latent attention gathers by it,
+    ``sparse_mla.attend_paged``). Returns ``(x (B, Q, D) before the
+    final norm, banks, load)``; ``load`` is a dropless router's counts
+    summed over its layers (``tfm._moe_dropless``; of the tokens
+    ``live`` (B, Q) marks, if given), None without one."""
+    shapes = {n: b.shape for n, b in banks.items()}
+    L, n_blocks = next(iter(shapes.values()))[:2]
+    flat = {n: b.reshape((L * n_blocks,) + b.shape[2:])
+            for n, b in banks.items()}
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
-    sin, cos = tfm.rope_tables(cfg, positions=positions)
+
+    if cfg.latent is None:
+        sin, cos = tfm.rope_tables(cfg, positions=positions)
+
+        def attention(x, bf, layer, base):
+            q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
+            kf, vf = bf["k"], bf["v"]
+            with jax.named_scope("kv_write"):
+                kf = kf.at[base + wr_b, wr_o].set(k)
+                vf = vf.at[base + wr_b, wr_o].set(v)
+            if attend is None:
+                o = _paged_attention_gather(q, kf, vf, base + tables,
+                                            limits, cfg)
+            else:
+                with jax.named_scope("attn"):
+                    o = attend(
+                        q, lax.dynamic_slice_in_dim(kf, base, n_blocks),
+                        lax.dynamic_slice_in_dim(vf, base, n_blocks))
+            return o, {"k": kf, "v": vf}
+    else:
+        from ptype_tpu.models import sparse_mla
+
+        if attend is not None:
+            raise ValueError("the paged-attention kernel reads K and V "
+                             "per head; latent attention takes the "
+                             "gather path")
+
+        def attention(x, bf, layer, base):
+            q_nope, q_rope, ckv, qi, ki, wi = sparse_mla.project(
+                x, layer, cfg, positions)
+            with jax.named_scope("kv_write"):
+                cf = bf["ckv"].at[base + wr_b, wr_o].set(ckv)
+                kif = bf["ki"].at[base + wr_b, wr_o].set(ki)
+            o = sparse_mla.attend_paged(q_nope, q_rope, qi, wi, cf, kif,
+                                        base + tables, limits, layer,
+                                        cfg, whole_context=chunk)
+            return o, {"ckv": cf, "ki": kif}
 
     def body(carry, inputs):
-        x, kf, vf = carry
+        x, bf = carry
         layer, base = inputs  # base: the layer's first row of the view
-        q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-        with jax.named_scope("kv_write"):
-            kf = kf.at[base + wr_b, wr_o].set(k)
-            vf = vf.at[base + wr_b, wr_o].set(v)
-        if attend is None:
-            o = _paged_attention_gather(q, kf, vf, base + tables,
-                                        limits, cfg)
-        else:
-            with jax.named_scope("attn"):
-                o = attend(
-                    q, lax.dynamic_slice_in_dim(kf, base, n_blocks),
-                    lax.dynamic_slice_in_dim(vf, base, n_blocks))
+        o, bf = attention(x, bf, layer, base)
         x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux = tfm.mlp_residual(x, layer, cfg,
-                                   moe_capacity=moe_capacity)
-        return (x, kf, vf), None
+        x, _aux, load = tfm.mlp_residual(
+            x, layer, cfg, moe_capacity=moe_capacity, live=live)
+        return (x, bf), load
 
-    (x, kf, vf), _ = lax.scan(
-        body, (x, kb.reshape(flat), vb.reshape(flat)),
-        (params["blocks"], jnp.arange(L, dtype=jnp.int32) * n_blocks))
-    return x, kf.reshape(kb.shape), vf.reshape(vb.shape)
+    load = None
+    for stacked, first, n in tfm.block_groups(params, cfg):
+        (x, flat), loads = lax.scan(
+            body, (x, flat),
+            (stacked,
+             jnp.arange(first, first + n, dtype=jnp.int32) * n_blocks))
+        if loads is not None:
+            total = jnp.sum(loads, axis=0)
+            load = total if load is None else load + total
+    return x, {n: b.reshape(shapes[n]) for n, b in flat.items()}, load
 
 
-def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
-                      cfg: tfm.TransformerConfig, kb: jax.Array,
-                      vb: jax.Array, tables: jax.Array,
-                      wr_blocks: jax.Array, wr_off: jax.Array,
-                      attn_impl: str = "gather",
-                      interpret: bool | None = None
-                      ) -> tuple[jax.Array, jax.Array, jax.Array]:
+def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
+                      cfg: tfm.TransformerConfig, banks: dict,
+                      tables: jax.Array, wr_blocks: jax.Array,
+                      wr_off: jax.Array, attn_impl: str = "gather",
+                      interpret: bool | None = None, live=None):
     """One decode step through per-sequence BLOCK TABLES — the paged
-    engine step (serve_engine.PagedGeneratorActor). ``kb``/``vb``:
-    ``(L, n_blocks, block_tokens, Kh, Dh)`` banks shared by every
-    sequence; ``tables`` (B, nb) maps each row's positions onto bank
-    blocks. Each row writes its new K/V at ``(wr_blocks[b],
-    wr_off[b])`` — the engine routes INACTIVE rows to the trash block
-    so a masked lane can never scatter into a real (possibly shared)
-    block — and attends through its table: position order == table
-    order, so greedy rows match the solo :func:`generate` decode
-    token-for-token (the engine's parity bar).
+    engine step (serve_engine.PagedGeneratorActor). ``banks``: the
+    cache as ``tfm.cache_spec(cfg)`` describes it, ``(L, n_blocks,
+    block_tokens, ...)`` arrays shared by every sequence; ``tables``
+    (B, nb) maps each row's positions onto bank blocks. Each row
+    writes its new cache row at ``(wr_blocks[b], wr_off[b])`` — the
+    engine routes INACTIVE rows to the trash block so a masked lane can
+    never scatter into a real (possibly shared) block — and attends
+    through its table: position order == table order, so greedy rows
+    match the solo :func:`generate` decode token-for-token (the
+    engine's parity bar).
 
     ``attn_impl="kernel"`` uses the Pallas paged-attention kernel
     (ops/paged_attention, gated behind its ``check_tpu_lowering``);
-    the default is the XLA gather path. Returns
-    ``(logits (B, V), kb, vb)``."""
+    the default is the XLA gather path. Returns ``(logits (B, V),
+    banks, load)``, ``load`` as :func:`_paged_layers` gives it (of the
+    rows ``live`` (B,) marks, if given)."""
     attend = None
     if attn_impl == "kernel":
         from ptype_tpu.ops.paged_attention import paged_attention
@@ -367,34 +414,34 @@ def decode_step_paged(params: dict, token: jax.Array, pos: jax.Array,
             return paged_attention(q, kc, vc, tables, pos,
                                    interpret=interpret)
 
-    x, kb, vb = _paged_layers(
-        params, token[:, None], pos[:, None], cfg, kb, vb, tables,
+    x, banks, load = _paged_layers(
+        params, token[:, None], pos[:, None], cfg, banks, tables,
         wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
-        attend)
+        attend, None if live is None else live[:, None])
     with jax.named_scope("head"):
-        x = tfm.rms_norm(x, params["final_norm"])
+        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x[:, 0], cfg)
-    return logits, kb, vb
+    return logits, banks, load
 
 
-def prefill_paged_chunk(params: dict, tokens: jax.Array,
+def prefill_chunk_banks(params: dict, tokens: jax.Array,
                         start: jax.Array, length: jax.Array,
-                        cfg: tfm.TransformerConfig, kb: jax.Array,
-                        vb: jax.Array, table: jax.Array
-                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                        cfg: tfm.TransformerConfig, banks: dict,
+                        table: jax.Array):
     """One CHUNK of paged prefill for a single sequence — the bounded
     unit chunked admission interleaves with decode steps. ``tokens``
     (1, C): prompt positions ``[start, start + length)`` right-padded
     to the chunk bucket C; ``table`` (nb,) the sequence's block table.
-    K/V for real tokens scatter into their blocks (pad columns go to
-    the trash block); attention runs per-query-causal against the
-    gathered table, i.e. query ``c`` sees every previously-written
-    position plus the chunk through itself — mathematically the same
-    full causal prefill, split at chunk boundaries. Returns
-    ``(logits (1, V) at the chunk's LAST REAL token, kb, vb)`` — only
-    the final chunk's logits feed the first sampled token."""
+    Cache rows for real tokens scatter into their blocks (pad columns
+    go to the trash block); attention runs per-query-causal against the
+    table, i.e. query ``c`` sees every previously-written position plus
+    the chunk through itself — mathematically the same full causal
+    prefill, split at chunk boundaries (with an indexer, each query
+    selects among exactly those). Returns ``(logits (1, V) at the
+    chunk's LAST REAL token, banks, load)`` — only the final chunk's
+    logits feed the first sampled token."""
     B, C = tokens.shape
-    bt = kb.shape[2]
+    bt = next(iter(banks.values())).shape[2]
     nb = table.shape[0]
     pos_vec = start + jnp.arange(C)  # (C,) positions of chunk columns
     valid = jnp.arange(C) < length
@@ -408,14 +455,15 @@ def prefill_paged_chunk(params: dict, tokens: jax.Array,
     # prefill's B*S bound — dropping is a training regularizer).
     cap = C if cfg.n_experts else None
 
-    x, kb, vb = _paged_layers(params, tokens, pos_vec[None], cfg, kb, vb,
-                              table[None], wr_b[None], wr_o[None],
-                              limits[None], cap)
+    x, banks, load = _paged_layers(
+        params, tokens, pos_vec[None], cfg, banks, table[None],
+        wr_b[None], wr_o[None], limits[None], cap, live=valid[None],
+        chunk=True)
     with jax.named_scope("head"):
-        x = tfm.rms_norm(x, params["final_norm"])
+        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         x_last = x[jnp.arange(B), jnp.asarray(length)[None] - 1]
         logits = _head_logits(params, x_last, cfg)
-    return logits, kb, vb
+    return logits, banks, load
 
 
 # ------------------------------------------------- speculative decoding
@@ -430,6 +478,18 @@ _DRAFT_FOLD = 0x5bec
 _ACCEPT_FOLD = 0xacce
 
 
+def _gqa_one_group(cfg: tfm.TransformerConfig, what: str) -> None:
+    """The speculative paths and the contiguous cache hold K and V per
+    head for one stacked group of layers: say so, not a shape error."""
+    if not cfg.plain:
+        raise ValueError(
+            f"{what} needs a GQA stack of one group; this configuration "
+            f"has latent attention, several layer groups or a dropless "
+            f"router, which only "
+            f"the plain paged decode step and prefill chunk run "
+            f"(its own next-token module would be the drafter)")
+
+
 def truncated_draft_params(params: dict, cfg: tfm.TransformerConfig,
                            n_layers: int = 1
                            ) -> tuple[dict, tfm.TransformerConfig]:
@@ -440,6 +500,7 @@ def truncated_draft_params(params: dict, cfg: tfm.TransformerConfig,
     stacked on the scan axis, so truncation is one leading slice).
     Returns ``(draft_params, draft_cfg)`` for
     ``SpecConfig(draft_params=..., draft_cfg=...)``."""
+    _gqa_one_group(cfg, "a truncated draft")
     if not 1 <= n_layers <= cfg.n_layers:
         raise ValueError(
             f"truncated draft needs 1 <= n_layers <= {cfg.n_layers}, "
@@ -458,7 +519,7 @@ def verify_step_paged(params: dict, tokens: jax.Array,
                       ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Target-model verification of one speculation window in ONE
     batched forward — the speculative-decoding counterpart of
-    :func:`decode_step_paged`. ``tokens`` (B, W): each row's last
+    :func:`decode_step_banks`. ``tokens`` (B, W): each row's last
     committed token followed by its draft proposals, at positions
     ``pos0 + [0..W)``; every position's K/V scatters through the block
     tables (``wr_b``/``wr_o`` (B, W) — the engine routes inactive
@@ -468,12 +529,13 @@ def verify_step_paged(params: dict, tokens: jax.Array,
     Returns ``(logits (B, W, V) f32, kb, vb)``: ``logits[:, j]`` is
     the target distribution for the token AT position ``pos0 + j + 1``
     given the prefix through ``tokens[:, j]`` — exactly the logits W
-    sequential :func:`decode_step_paged` calls would produce, which is
+    sequential :func:`decode_step_banks` calls would produce, which is
     what makes greedy speculative acceptance bit-identical to the
     non-speculative engine. Rejected positions need no KV cleanup:
     their writes land inside the row's already-reserved blocks and the
     position-limit mask hides them until a later token overwrites them
     (rollback is a position rewind, never a reallocation)."""
+    _gqa_one_group(cfg, "speculative verification")
     B, W = tokens.shape
     pos = pos0[:, None] + jnp.arange(W)[None, :]   # (B, W)
     # MoE: zero-drop capacity over the whole window (same reasoning
@@ -481,12 +543,13 @@ def verify_step_paged(params: dict, tokens: jax.Array,
     cap = B * W if cfg.n_experts else None
 
     # Per-query causal limits: query j attends through pos0 + j.
-    x, kb, vb = _paged_layers(params, tokens, pos, cfg, kb, vb, tables,
-                              wr_b, wr_o, pos + 1, cap)
+    x, banks, _ = _paged_layers(params, tokens, pos, cfg,
+                                {"k": kb, "v": vb}, tables, wr_b, wr_o,
+                                pos + 1, cap)
     with jax.named_scope("head"):
-        x = tfm.rms_norm(x, params["final_norm"])
+        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x, cfg)
-    return logits, kb, vb
+    return logits, banks["k"], banks["v"]
 
 
 def draft_propose_paged(params: dict, tok: jax.Array,
@@ -517,6 +580,7 @@ def draft_propose_paged(params: dict, tok: jax.Array,
     ``draft_logits[:, j]`` the logits it was drawn from (acceptance
     recomputes the filtered distribution from these, so q is scored
     exactly as sampled)."""
+    _gqa_one_group(cfg, "a speculative draft")
     B = tok.shape[0]
     dkeys = jax.vmap(
         lambda kk: jax.random.fold_in(kk, _DRAFT_FOLD))(keys)
@@ -525,11 +589,12 @@ def draft_propose_paged(params: dict, tok: jax.Array,
         tok, kb, vb = carry
         j, wb, wo = inputs
         pos = pos0 + j  # (B,)
-        x, kb, vb = _paged_layers(
-            params, tok[:, None], pos[:, None], cfg, kb, vb, tables,
-            wb[:, None], wo[:, None], pos + 1, B)
+        x, banks, _ = _paged_layers(
+            params, tok[:, None], pos[:, None], cfg, {"k": kb, "v": vb},
+            tables, wb[:, None], wo[:, None], pos + 1, B)
+        kb, vb = banks["k"], banks["v"]
         with jax.named_scope("head"):
-            x = tfm.rms_norm(x, params["final_norm"])
+            x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
             lg = _head_logits(params, x[:, 0], cfg)  # (B, V) f32
         with jax.named_scope("sample"):
             if sampled:

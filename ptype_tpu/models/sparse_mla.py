@@ -1,0 +1,274 @@
+"""Latent K,V attention with a learned sparse-attention indexer.
+
+The attention of the DeepSeek-V2/V3 line (MLA) with DeepSeek-V3.2's
+indexer in front of it, as ``TransformerConfig.latent`` sizes it:
+
+- **Latent cache.** A token holds, a layer, the RMS-normed latent
+  ``cKV`` (``kv_rank``) with one rotary key ``kR`` (``rope_dim``)
+  shared by all heads — bank ``ckv``, its row padded to whole lane
+  tiles (``LatentAttention.cache_dim``) — and the indexer's key ``kI``
+  (``index_dim``) — bank ``ki`` — instead of K and V per head.
+- **Indexer.** ``I[t, s] = Σ_j w[t, j] · ReLU(qI[t, j] · kI[s])`` over
+  ``index_heads``; a query attends the ``index_topk`` keys ``s ≤ t``
+  with the largest ``I`` (all of them while fewer are held).
+- **Two forms of one attention.** *Expanded* (:func:`attend_expanded`,
+  the contiguous forward): per-head keys and values are made from the
+  latent and every unselected key is masked. *Absorbed*
+  (:func:`attend_paged`, the paged decode step and prefill chunk):
+  ``W_UK`` is folded into the query and ``W_UV`` applied after the
+  sum, so the selected rows of ``ckv`` are gathered through the block
+  table and read as they lie. tests/benchmark/test_bench_glm.py holds
+  the two to each other and to the plain reference.
+
+Rotary positions rotate adjacent pairs (``rope_interleave``), on all of
+``kR`` / ``q^rope`` and on the leading ``index_rope_dim`` dims of the
+indexer's q and k. Scopes: ``qkv`` (norms and projections), ``index``
+(the indexer's projections and scores), ``select`` (top-k),
+``kv_gather``, ``attn``.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ptype_tpu.models import transformer as tfm
+
+#: Queries scored and gathered at a time by the paged path: a prefill
+#: chunk of 512 queries against 20k keys would hold 1.3 GB of indexer
+#: scores and 1.2 GB of gathered latents at once.
+QUERY_BLOCK = 128
+#: The indexer's LayerNorm epsilon (DeepSeek-V3.2's inference code).
+INDEX_NORM_EPS = 1e-6
+_NEG = -1e30
+
+
+def init_attention(key, cfg: tfm.TransformerConfig, n: int) -> dict:
+    """The attention half of ``n`` stacked layers."""
+    la, D, H, pd = cfg.latent, cfg.d_model, cfg.n_heads, cfg.param_dtype
+    resid = 0.02 / (2.0 * cfg.n_layers) ** 0.5
+    ks = jax.random.split(key, 10)
+
+    def norm(k, shape, scale=0.02):
+        return tfm.scaled_normal(k, shape, scale, pd)
+
+    return {
+        "attn_norm": jnp.ones((n, D), pd),
+        "w_dq": norm(ks[0], (n, D, la.q_rank)),
+        "q_norm": jnp.ones((n, la.q_rank), pd),
+        "w_uq": norm(ks[1], (n, la.q_rank, H, la.qk_dim)),
+        "w_dkv": norm(ks[2], (n, D, la.row_dim)),
+        "kv_norm": jnp.ones((n, la.kv_rank), pd),
+        "w_uk": norm(ks[3], (n, la.kv_rank, H, la.nope_dim)),
+        "w_uv": norm(ks[4], (n, la.kv_rank, H, la.v_dim)),
+        "wo": norm(ks[5], (n, H, la.v_dim, D), resid),
+        "w_iq": norm(ks[6], (n, la.q_rank, la.index_heads, la.index_dim)),
+        "w_ik": norm(ks[7], (n, D, la.index_dim)),
+        "ik_norm": jnp.ones((n, la.index_dim), pd),
+        "ik_norm_b": norm(ks[8], (n, la.index_dim)),
+        "w_iw": norm(ks[9], (n, D, la.index_heads)),
+    }
+
+
+def rope_interleaved(x, sin, cos):
+    """Rotate adjacent pairs ``(x[2i], x[2i+1])`` of the last dim.
+    ``sin``/``cos``: (B or 1, Q, d/2); ``x``: (B, Q, d), or
+    (B, Q, heads, d)."""
+    if x.ndim == sin.ndim + 1:
+        sin, cos = sin[..., None, :], cos[..., None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rope_lead(x, sin, cos, n: int):
+    """Rotate the leading ``n`` dims of the last axis; the rest pass."""
+    return jnp.concatenate(
+        [rope_interleaved(x[..., :n], sin, cos), x[..., n:]], axis=-1)
+
+
+def _layer_norm(x, scale, bias, eps):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean((x32 - mu) ** 2, axis=-1, keepdims=True)
+    y = (x32 - mu) * lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def project(x, layer, cfg: tfm.TransformerConfig, positions):
+    """Everything attention needs of ``x`` (B, Q, D) at ``positions``
+    ((Q,) shared or (B, Q)): ``q_nope`` (B, Q, H, nope), ``q_rope``
+    (B, Q, H, rope), the cache row ``ckv`` (B, Q, cache_dim) =
+    [normed latent ; rotated shared key ; zeros to whole lane tiles],
+    and the indexer's ``qi``
+    (B, Q, J, di), ``ki`` (B, Q, di) and head weights ``wi``
+    (B, Q, J) float32."""
+    la, dt, eps = cfg.latent, cfg.dtype, cfg.norm_eps
+    positions = jnp.asarray(positions)
+    if positions.ndim == 1:
+        positions = positions[None]
+    with jax.named_scope("qkv"):
+        h = tfm.rms_norm(x, layer["attn_norm"], eps)
+        cq = tfm.rms_norm(
+            jnp.einsum("bsd,dr->bsr", h, layer["w_dq"].astype(dt)),
+            layer["q_norm"], eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, layer["w_uq"].astype(dt))
+        sin, cos = tfm.rope_tables(cfg, positions=positions,
+                                   dim=la.rope_dim)
+        q_nope = q[..., :la.nope_dim]
+        q_rope = rope_interleaved(q[..., la.nope_dim:], sin, cos)
+        kv = jnp.einsum("bsd,dc->bsc", h, layer["w_dkv"].astype(dt))
+        pad = la.cache_dim - la.row_dim  # the bank's row is whole tiles
+        ckv = jnp.concatenate(
+            [tfm.rms_norm(kv[..., :la.kv_rank], layer["kv_norm"], eps),
+             rope_interleaved(kv[..., la.kv_rank:], sin, cos),
+             jnp.zeros(kv.shape[:-1] + (pad,), kv.dtype)], axis=-1)
+    with jax.named_scope("index"):
+        isin, icos = tfm.rope_tables(cfg, positions=positions,
+                                     dim=la.index_rope_dim)
+        qi = _rope_lead(
+            jnp.einsum("bsr,rjk->bsjk", cq, layer["w_iq"].astype(dt)),
+            isin, icos, la.index_rope_dim)
+        ki = _rope_lead(
+            _layer_norm(
+                jnp.einsum("bsd,dk->bsk", h, layer["w_ik"].astype(dt)),
+                layer["ik_norm"], layer["ik_norm_b"], INDEX_NORM_EPS),
+            isin, icos, la.index_rope_dim)
+        wi = jnp.einsum("bsd,dj->bsj", h, layer["w_iw"].astype(dt),
+                        preferred_element_type=jnp.float32)
+        wi = wi * (la.index_heads ** -0.5 * la.index_dim ** -0.5)
+    return q_nope, q_rope, ckv, qi, ki, wi
+
+
+def index_scores(qi, wi, ki):
+    """``I`` (B, Q, T) float32 of queries ``qi`` (B, Q, J, di), ``wi``
+    (B, Q, J) on keys ``ki`` (B, T, di)."""
+    dots = jnp.einsum("bqjd,btd->bqjt", qi, ki,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(wi[..., None] * jax.nn.relu(dots), axis=2)
+
+
+def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
+    """The expanded form over one contiguous sequence from position 0:
+    x (B, S, D) → o (B, S, H, v). Per-head keys and values are made
+    from every token's latent; a query's unselected keys are masked."""
+    la, dt = cfg.latent, cfg.dtype
+    B, S, _ = x.shape
+    q_nope, q_rope, ckv, qi, ki, wi = project(x, layer, cfg,
+                                              jnp.arange(S))
+    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
+    with jax.named_scope("index"):
+        I = jnp.where(causal[None], index_scores(qi, wi, ki), _NEG)
+    with jax.named_scope("select"):
+        k = min(la.index_topk, S)
+        _, idx = lax.top_k(I, k)  # (B, S, k)
+        chosen = jnp.zeros((B, S, S), jnp.bool_).at[
+            jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None],
+            idx].set(True)
+        mask = chosen & causal[None]
+    with jax.named_scope("attn"):
+        c_kv = ckv[..., :la.kv_rank]
+        k_r = ckv[..., la.kv_rank:la.row_dim]
+        k_nope = jnp.einsum("bsc,chn->bshn", c_kv,
+                            layer["w_uk"].astype(dt))
+        v = jnp.einsum("bsc,chv->bshv", c_kv, layer["w_uv"].astype(dt))
+        scores = (jnp.einsum("bqhn,bshn->bhqs", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhr,bsr->bhqs", q_rope, k_r,
+                               preferred_element_type=jnp.float32))
+        scores = scores / jnp.sqrt(jnp.float32(la.qk_dim))
+        scores = jnp.where(mask[:, None], scores, _NEG)
+        probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+        return jnp.einsum("bhqs,bshv->bqhv", probs, v)
+
+
+def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
+                 limits, layer, cfg: tfm.TransformerConfig,
+                 whole_context: bool = False):
+    """The absorbed form through a block table. Queries (B, Q, ...) as
+    :func:`project` gives them; ``ckv_bank`` (rows, bt, cache_dim)
+    and ``ki_bank`` (rows, bt, di) the banks in the flat view;
+    ``tables`` (B, nb) this layer's rows of them in position order;
+    ``limits`` (B,) or (B, Q): a query attends positions ``< limit``.
+    The indexer scores every position of the table, the top
+    ``index_topk`` are selected, and their rows of ``ckv`` alone are
+    gathered and read. ``whole_context`` (a prefill chunk says so:
+    many queries a table): a row's whole latent context is gathered
+    once, by blocks, and each query picks its rows from that.
+    → o (B, Q, H, v)."""
+    la, dt = cfg.latent, cfg.dtype
+    B, Q, H, _ = q_nope.shape
+    nb, bt = tables.shape[1], ckv_bank.shape[1]
+    T = nb * bt
+    k = min(la.index_topk, T)
+    limits = jnp.asarray(limits)
+    if limits.ndim == 1:
+        limits = jnp.broadcast_to(limits[:, None], (B, Q))
+    with jax.named_scope("index"):
+        ki = ki_bank[tables].reshape(B, T, la.index_dim)
+    ctx = None
+    if whole_context:
+        # The whole latent context in position order is one gather of
+        # blocks (26 MB at 20k keys), and every query then picks its
+        # rows by position. (Through the table a row at a time, the
+        # 262,144 block ids of 128 queries cost more than the sort:
+        # chip run, PR 28.) Decode rows, each with a table of its own,
+        # read only what they select.
+        with jax.named_scope("kv_gather"):
+            ctx = ckv_bank[tables].reshape(B, T, la.cache_dim)
+    with jax.named_scope("attn"):
+        # Absorb W_UK into the query: (q^nope W_UK^T) · cKV = q^nope ·
+        # (W_UK cKV); the rotary part rides beside it, so one product
+        # against the cache row [cKV ; kR ; 0] gives the score.
+        qa = jnp.concatenate(
+            [jnp.einsum("bqhn,chn->bqhc", q_nope,
+                        layer["w_uk"].astype(dt)), q_rope,
+             jnp.zeros((B, Q, H, la.cache_dim - la.row_dim), dt)],
+            axis=-1)
+
+    def block(qa, qi, wi, limits):
+        Qb = qa.shape[1]
+        with jax.named_scope("index"):
+            I = index_scores(qi, wi, ki)
+            I = jnp.where(jnp.arange(T)[None, None] < limits[..., None],
+                          I, _NEG)
+        with jax.named_scope("select"):
+            # Rows flat: a (B, 1, T) operand sorts in (1, 128) tiles,
+            # five times slower than (B, T) (chip run, PR 28).
+            _, idx = lax.top_k(I.reshape(B * Qb, T), k)
+            idx = idx.reshape(B, Qb, k)  # positions
+            ok = idx < limits[..., None]
+        with jax.named_scope("kv_gather"):
+            if ctx is not None:
+                sel = jax.vmap(lambda c, i: c[i])(ctx, idx)
+            else:
+                blk = jnp.take_along_axis(tables[:, None, :], idx // bt,
+                                          axis=-1)
+                sel = ckv_bank[blk, idx % bt]  # (B, Qb, k, cache_dim)
+        with jax.named_scope("attn"):
+            scores = jnp.einsum("bqhc,bqkc->bqhk", qa, sel,
+                                preferred_element_type=jnp.float32)
+            scores = scores / jnp.sqrt(jnp.float32(la.qk_dim))
+            scores = jnp.where(ok[:, :, None], scores, _NEG)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dt)
+            ol = jnp.einsum("bqhk,bqkc->bqhc", probs,
+                            sel[..., :la.kv_rank])
+            return jnp.einsum("bqhc,chv->bqhv", ol,
+                              layer["w_uv"].astype(dt))
+
+    if Q <= QUERY_BLOCK:
+        return block(qa, qi, wi, limits)
+    if Q % QUERY_BLOCK:
+        raise ValueError(f"{Q} queries do not divide into blocks of "
+                         f"{QUERY_BLOCK}")
+
+    def split(a):
+        return jnp.moveaxis(a.reshape(
+            B, Q // QUERY_BLOCK, QUERY_BLOCK, *a.shape[2:]), 1, 0)
+
+    o = lax.map(lambda xs: block(*xs),
+                (split(qa), split(qi), split(wi), split(limits)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, Q, H, la.v_dim)

@@ -14,7 +14,11 @@ anything:
 - **bf16 compute, f32 params.** Matmuls run in bfloat16 on the MXU;
   parameters and the softmax/logit paths stay f32 for stability.
 - **RMSNorm + RoPE + SwiGLU + GQA** — one architecture covers the
-  125M optimus preset and the Llama-3-8B FSDP baseline config.
+  125M optimus preset and the Llama-3-8B FSDP baseline config. Beside
+  it, for serving: latent K,V attention behind a sparse-attention
+  indexer (models/sparse_mla.py), a stack of several layer groups
+  (:func:`layer_groups`: one scan a run of identical layers) and a
+  dropless router over a share of the experts (:func:`_moe_dropless`).
 - **Sharding by annotation.** :func:`param_specs` returns a PartitionSpec
   pytree (fsdp/model axes); the train layer jits with those shardings and
   GSPMD inserts the collectives (ICI-mapped; scaling-book recipe).
@@ -25,7 +29,10 @@ anything:
   is shared: ``embed``, ``qkv`` (norm, projections, RoPE), ``kv_write``
   and ``kv_gather`` (models/generate.py), ``attn``, ``attn_out``,
   ``mlp``, ``head`` (final norm + LM head), ``loss``, ``optimizer``
-  (train/trainer.py), ``sample`` (serve_engine/engine.py). A scope is
+  (train/trainer.py), ``sample`` (serve_engine/engine.py); inside
+  ``mlp``, a dropless expert layer's ``router``, ``experts`` and
+  ``shared_expert``; latent attention's ``index`` and ``select``
+  (models/sparse_mla.py). A scope is
   HLO metadata only: it names the operation in a device trace (the
   profiler's ``tf_op`` stat) and changes no compiled program.
   ``benchmark/readers/scope_time_pct.py`` buckets device time by them.
@@ -42,6 +49,42 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ptype_tpu.parallel.topology import DATA_AXIS
+
+
+@dataclass(frozen=True)
+class LatentAttention:
+    """Widths of latent K,V attention (DeepSeek-V2 MLA) and of the
+    sparse-attention indexer that picks its keys (DeepSeek-V3.2)."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    index_heads: int
+    index_dim: int
+    index_topk: int
+    #: Leading dims of the indexer's q and k that rotate.
+    index_rope_dim: int = 64
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def row_dim(self) -> int:
+        """Values of a token's cache row: latent + shared rotary key."""
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Width of the row as the bank stores it: ``row_dim``, padded
+        with zeros to whole 128-lane tiles once it is wider than one
+        (576 → 640). With a 576-wide bank the TPU compiler gives the
+        bank it returns another layout than the one it takes, and a
+        step copies the whole bank twice (AOT compile for v5e, PR 28)."""
+        row = self.row_dim
+        return row if row <= 128 else -(-row // 128) * 128
 
 
 @dataclass(frozen=True)
@@ -93,10 +136,49 @@ class TransformerConfig:
     capacity_factor: float = 1.25
     #: Coefficient of the router load-balancing aux loss.
     moe_aux_coef: float = 0.01
+    #: RMSNorm epsilon.
+    norm_eps: float = 1e-6
+    #: Latent (low-rank) K,V attention with a learned sparse-attention
+    #: indexer (models/sparse_mla.py) in place of GQA; None → GQA.
+    latent: "LatentAttention | None" = None
+    #: Leading layers with a dense MLP before the expert layers (the
+    #: layer stack is then two groups, each its own ``lax.scan``).
+    n_dense_layers: int = 0
+    #: SwiGLU hidden size of one routed or shared expert (None → d_ff).
+    d_ff_expert: int | None = None
+    #: Experts every token passes through, beside the routed ones.
+    n_shared_experts: int = 0
+    #: "softmax" (GShard top-k with capacity, :func:`_moe_mlp`) or
+    #: "sigmoid_bias": sigmoid scores, selection by score + a learned
+    #: correction bias, normalised gates × ``routed_scale``, no token
+    #: dropped (:func:`_moe_dropless`).
+    moe_router: str = "softmax"
+    routed_scale: float = 1.0
+    #: (first, count): the slice of the ``n_experts`` this program
+    #: holds, as one member of an expert-parallel group; the router
+    #: keeps ``n_experts`` outputs and what the absent experts would
+    #: add is left out. None → all of them.
+    experts_held: tuple[int, int] | None = None
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
+
+    @property
+    def plain(self) -> bool:
+        """A GQA stack of one group with the capacity router or none:
+        what training, sharding, speculation and the contiguous cache
+        are written for."""
+        return (self.latent is None and self.moe_router == "softmax"
+                and not (self.n_experts and self.n_dense_layers))
+
+    @property
+    def expert_ff(self) -> int:
+        return self.d_ff_expert or self.d_ff
+
+    @property
+    def held(self) -> tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def head_dim(self) -> int:
@@ -158,6 +240,8 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     init: truncated-normal-free simple scaled normals (0.02 embed / GPT
     residual scaling on the out-projections).
     """
+    if not cfg.plain:
+        return _init_grouped(rng, cfg)
     L, D, H, K = cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.kv_heads
     Dh, F, V = cfg.head_dim, cfg.d_ff, cfg.vocab_size
     pd = cfg.param_dtype
@@ -200,6 +284,112 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
     return params
 
 
+def layer_groups(cfg: TransformerConfig) -> tuple[tuple[str, int], ...]:
+    """The layer stack as runs of identical layers, ``(kind, count)``
+    with kind ``"dense"`` or ``"experts"``: each run is one
+    ``lax.scan`` over its own stacked parameters."""
+    if cfg.n_experts and cfg.n_dense_layers:
+        return (("dense", cfg.n_dense_layers),
+                ("experts", cfg.n_layers - cfg.n_dense_layers))
+    return (("experts" if cfg.n_experts else "dense", cfg.n_layers),)
+
+
+def block_groups(params: dict, cfg: TransformerConfig) -> list[tuple]:
+    """``[(stacked layer params, first layer index, count)]``, one a
+    group: ``params["blocks"]`` is the one stacked dict of a stack
+    with one group, or a tuple of them in layer order."""
+    blocks = params["blocks"]
+    if isinstance(blocks, dict):
+        blocks = (blocks,)
+    groups = layer_groups(cfg)
+    if len(blocks) != len(groups):
+        raise ValueError(
+            f"the parameters hold {len(blocks)} layer group(s); this "
+            f"configuration's stack is {groups}")
+    out, first = [], 0
+    for stacked, (_kind, n) in zip(blocks, groups):
+        out.append((stacked, first, n))
+        first += n
+    return out
+
+
+def cache_spec(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
+    """What one token holds in one layer's cache: named arrays and
+    their shapes. The block pool allocates its banks from this, the
+    migrator packs by it, and the paged programs carry the banks as
+    one dict with these names (every layer of a model holds the same)."""
+    if cfg.latent is not None:
+        return {"ckv": (cfg.latent.cache_dim,),
+                "ki": (cfg.latent.index_dim,)}
+    return {"k": (cfg.kv_heads, cfg.head_dim),
+            "v": (cfg.kv_heads, cfg.head_dim)}
+
+
+def scaled_normal(key, shape, scale, dtype) -> jax.Array:
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def _init_mlp(key, cfg: TransformerConfig, kind: str, n: int) -> dict:
+    """The MLP half of ``n`` stacked layers of one kind."""
+    D, pd = cfg.d_model, cfg.param_dtype
+    resid = 0.02 / (2.0 * cfg.n_layers) ** 0.5
+    ks = jax.random.split(key, 8)
+    norm = partial(scaled_normal, dtype=pd)
+
+    if kind == "dense":
+        F = cfg.d_ff
+        return {"mlp_norm": jnp.ones((n, D), pd),
+                "w_gate": norm(ks[0], (n, D, F), 0.02),
+                "w_up": norm(ks[1], (n, D, F), 0.02),
+                "w_down": norm(ks[2], (n, F, D), resid)}
+    F, E = cfg.expert_ff, cfg.n_experts
+    held = cfg.held[1]
+    out = {"mlp_norm": jnp.ones((n, D), pd),
+           "router": norm(ks[3], (n, D, E), 0.02),
+           "w_gate": norm(ks[0], (n, held, D, F), 0.02),
+           "w_up": norm(ks[1], (n, held, D, F), 0.02),
+           "w_down": norm(ks[2], (n, held, F, D), resid)}
+    if cfg.moe_router == "sigmoid_bias":
+        out["router_bias"] = norm(ks[4], (n, E), 0.02).astype(jnp.float32)
+    if cfg.n_shared_experts:
+        Fs = F * cfg.n_shared_experts
+        out.update(ws_gate=norm(ks[5], (n, D, Fs), 0.02),
+                   ws_up=norm(ks[6], (n, D, Fs), 0.02),
+                   ws_down=norm(ks[7], (n, Fs, D), resid))
+    return out
+
+
+def _init_grouped(rng: jax.Array, cfg: TransformerConfig) -> dict:
+    """Parameters of a stack with several groups, latent attention or a
+    dropless router: one stacked dict a group."""
+    D, V, pd = cfg.d_model, cfg.vocab_size, cfg.param_dtype
+    H, K, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    resid = 0.02 / (2.0 * cfg.n_layers) ** 0.5
+    norm = partial(scaled_normal, dtype=pd)
+    blocks = []
+    for g, (kind, n) in enumerate(layer_groups(cfg)):
+        ka, km = jax.random.split(jax.random.fold_in(rng, g + 1))
+        if cfg.latent is not None:
+            from ptype_tpu.models import sparse_mla
+
+            attn = sparse_mla.init_attention(ka, cfg, n)
+        else:
+            ks = jax.random.split(ka, 4)
+            attn = {"attn_norm": jnp.ones((n, D), pd),
+                    "wq": norm(ks[0], (n, D, H, Dh), 0.02),
+                    "wk": norm(ks[1], (n, D, K, Dh), 0.02),
+                    "wv": norm(ks[2], (n, D, K, Dh), 0.02),
+                    "wo": norm(ks[3], (n, H, Dh, D), resid)}
+        blocks.append({**attn, **_init_mlp(km, cfg, kind, n)})
+    k0, k1 = jax.random.split(jax.random.fold_in(rng, 0))
+    params = {"embed": norm(k0, (V, D), 0.02),
+              "blocks": blocks[0] if len(blocks) == 1 else tuple(blocks),
+              "final_norm": jnp.ones((D,), pd)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = norm(k1, (D, V), 0.02)
+    return params
+
+
 def count_params(params) -> int:
     return sum(p.size for p in jax.tree_util.tree_leaves(params))
 
@@ -208,6 +398,11 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int,
                     n_params: int | None = None) -> float:
     """Fwd+bwd training FLOPs per token (PaLM appendix B convention):
     ``6·N_matmul + 12·L·D·S`` — the MFU denominator."""
+    if n_params is None and not cfg.plain:
+        raise ValueError(
+            "flops_per_token counts the GQA block of a stack with one "
+            "group; a latent-attention or grouped stack is not trained "
+            "here, and its serving counts are the benchmark family's")
     if n_params is None:
         # ACTIVE matmul params only (norms excluded — negligible; for
         # MoE, the top-k routed experts count, not the full bank).
@@ -234,12 +429,13 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 def rope_tables(cfg: TransformerConfig, seq_len: int | None = None,
-                positions: jax.Array | None = None):
+                positions: jax.Array | None = None,
+                dim: int | None = None):
     """(sin, cos) tables, shape (S, head_dim/2), f32. Pass either a
     ``seq_len`` (positions 0..S-1, the training path) or explicit
     ``positions`` (the decode path, models/generate.py) — one formula
     for both, so RoPE changes can never diverge between them."""
-    half = cfg.head_dim // 2
+    half = (dim or cfg.head_dim) // 2
     inv_freq = 1.0 / (
         cfg.rope_theta ** (jnp.arange(0, half, dtype=jnp.float32) / half)
     )
@@ -410,6 +606,84 @@ def _moe_mlp(h, layer, cfg: TransformerConfig, capacity: int | None = None):
     return y.reshape(B, S, D), aux
 
 
+def _swiglu(h, w_gate, w_up, w_down, dt):
+    gate = jnp.einsum("bsd,df->bsf", h, w_gate.astype(dt))
+    up = jnp.einsum("bsd,df->bsf", h, w_up.astype(dt))
+    return jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                      w_down.astype(dt))
+
+
+def _moe_dropless(h, layer, cfg: TransformerConfig, live=None):
+    """Dropless top-k MoE over the experts held here, with a shared
+    expert. h: (B, S, D) → (y, load).
+
+    The router keeps its ``n_experts`` outputs: sigmoid scores ``s``,
+    the ``expert_top_k`` largest ``s + bias`` chosen (``bias`` the
+    learned correction, selection only), gates ``routed_scale · s /
+    Σ_chosen s``. Of a token's choices those that fall on
+    ``cfg.held`` are computed, grouped by held expert: each expert's
+    tokens are gathered into its rows of an (held, T, D) buffer and the
+    SwiGLUs run as one batched product a matrix. A token chooses an
+    expert at most once, so T rows an expert is the most any load can
+    fill: no token is dropped however crowded one expert is, and no
+    capacity is set. (``lax.ragged_dot`` over the sorted assignments
+    does the same with no padding, but it is a custom call: inside the
+    layer scan the compiler copies each layer's three expert matrices
+    out of the stack for it, 1.2 GB a layer at GLM-5's widths — AOT
+    compile for v5e, PR 28 — where a dot reads its slice in place.)
+    What the absent experts would have added is left out. ``load``
+    (held + 1,) int32: assignments per held expert, and last those
+    whose expert is not held; of the tokens ``live`` (B, S) marks, if
+    given (a decode step computes its inactive lanes too, all alike:
+    counted, they would read as one crowded expert)."""
+    B, S, D = h.shape
+    k = cfg.expert_top_k
+    first, count = cfg.held
+    dt = cfg.dtype
+    T = B * S
+    x = h.reshape(T, D)
+    with jax.named_scope("router"):
+        s = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", x.astype(jnp.float32),
+            layer["router"].astype(jnp.float32), precision="highest"))
+        _, idx = lax.top_k(s + layer["router_bias"].astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        g = cfg.routed_scale * w / jnp.maximum(
+            jnp.sum(w, axis=-1, keepdims=True), 1e-20)
+        local = idx - first
+        here = ((local >= 0) & (local < count)).reshape(-1)  # (T·k,)
+        e = jnp.where(here, local.reshape(-1), count)
+        onehot = jax.nn.one_hot(e, count + 1, dtype=jnp.int32)
+        counted = onehot if live is None else onehot * jnp.repeat(
+            live.reshape(T).astype(jnp.int32), k)[:, None]
+        load = jnp.sum(counted, axis=0)
+        # Row of each assignment within its expert, in token order.
+        pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=-1) - 1
+        tok = jnp.arange(T * k) // k
+        # The inverse map (expert, row) -> token, as _moe_mlp builds it
+        # (a scatter of one int32 an assignment; the D-wide rows are
+        # gathered). Assignments held elsewhere name row T: dropped.
+        inv = jnp.zeros((count, T), jnp.int32).at[
+            e, jnp.where(here, pos, T)].set(tok + 1, mode="drop",
+                                            unique_indices=True)
+        gate = jnp.where(here, g.reshape(-1), 0.0)
+    with jax.named_scope("experts"):
+        X = jnp.where((inv > 0)[..., None],
+                      x[jnp.maximum(inv - 1, 0)].astype(dt), 0)
+        a = jnp.einsum("ecd,edf->ecf", X, layer["w_gate"].astype(dt))
+        u = jnp.einsum("ecd,edf->ecf", X, layer["w_up"].astype(dt))
+        Y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(a) * u,
+                       layer["w_down"].astype(dt))
+        ys = Y[jnp.minimum(e, count - 1), jnp.clip(pos, 0, T - 1)]
+        ys = ys * gate[:, None].astype(dt)
+        y = jnp.sum(ys.reshape(T, k, D), axis=1).reshape(B, S, D)
+    if "ws_gate" in layer:
+        with jax.named_scope("shared_expert"):
+            y = y + _swiglu(h, layer["ws_gate"], layer["ws_up"],
+                            layer["ws_down"], dt)
+    return y, load
+
+
 @jax.named_scope("qkv")
 def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos):
     """Pre-norm + Q/K/V projections + RoPE. x: (B, S, D) → three
@@ -417,7 +691,7 @@ def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos):
     prefill/decode paths (models/generate.py) — the block math lives
     here once."""
     dt = cfg.dtype
-    h = rms_norm(x, layer["attn_norm"])
+    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
@@ -433,29 +707,42 @@ def attn_residual(x, o, layer, cfg: TransformerConfig):
 
 @jax.named_scope("mlp")
 def mlp_residual(x, layer, cfg: TransformerConfig,
-                 moe_capacity: int | None = None):
-    """Pre-norm MLP (dense SwiGLU or MoE) + residual. → (x, aux)."""
+                 moe_capacity: int | None = None, live=None):
+    """Pre-norm MLP + residual: a dense SwiGLU, or, in a layer that
+    holds a router, the experts behind it as ``cfg.moe_router`` routes
+    them. → (x, aux, load): the capacity router's load-balancing loss
+    (0.0 without one) and the dropless router's load counts
+    (:func:`_moe_dropless`; None without one)."""
     dt = cfg.dtype
-    h = rms_norm(x, layer["mlp_norm"])
-    if cfg.n_experts:
+    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+    if "router" in layer:
+        if cfg.moe_router == "sigmoid_bias":
+            y, load = _moe_dropless(h, layer, cfg, live)
+            return x + y, jnp.float32(0.0), load
         y, aux = _moe_mlp(h, layer, cfg, capacity=moe_capacity)
-        return x + y, aux
+        return x + y, aux, None
     gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
     up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
     x = x + jnp.einsum(
         "bsf,fd->bsd", jax.nn.silu(gate) * up, layer["w_down"].astype(dt)
     )
-    return x, jnp.float32(0.0)
+    return x, jnp.float32(0.0), None
 
 
 def _block(x, layer, sin, cos, cfg: TransformerConfig, attn_fn):
     """One transformer block; x: (B, S, D) in compute dtype.
     Returns (x, moe_aux) — aux is 0.0 for dense MLPs."""
-    q, k, v = qkv_proj(x, layer, cfg, sin, cos)
-    with jax.named_scope("attn"):
-        o = attn_fn(q, k, v, cfg)
+    if cfg.latent is not None:
+        from ptype_tpu.models import sparse_mla
+
+        o = sparse_mla.attend_expanded(x, layer, cfg)
+    else:
+        q, k, v = qkv_proj(x, layer, cfg, sin, cos)
+        with jax.named_scope("attn"):
+            o = attn_fn(q, k, v, cfg)
     x = attn_residual(x, o, layer, cfg)
-    return mlp_residual(x, layer, cfg)
+    x, aux, _load = mlp_residual(x, layer, cfg)
+    return x, aux
 
 
 def hidden_with_aux(params: dict, tokens: jax.Array,
@@ -479,10 +766,13 @@ def hidden_with_aux(params: dict, tokens: jax.Array,
         policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                   if cfg.remat_policy == "dots" else None)
         body = jax.checkpoint(body, policy=policy)
-    x, auxs = lax.scan(body, x, params["blocks"], unroll=cfg.scan_unroll)
+    aux = None
+    for stacked, _first, _n in block_groups(params, cfg):
+        x, auxs = lax.scan(body, x, stacked, unroll=cfg.scan_unroll)
+        aux = jnp.sum(auxs) if aux is None else aux + jnp.sum(auxs)
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"])
-    return x, jnp.sum(auxs)
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return x, jnp.float32(0.0) if aux is None else aux
 
 
 def _head_weight(params: dict, cfg: TransformerConfig) -> jax.Array:
@@ -644,6 +934,12 @@ def param_specs(cfg: TransformerConfig,
     every matmul weight (ZeRO-3-style, allgathered by GSPMD per layer).
     Block specs carry a leading None for the scan/layer dim.
     """
+    if not cfg.plain:
+        raise ValueError(
+            "param_specs shards the GQA block of a stack with one "
+            "group; a latent-attention, grouped or dropless-expert "
+            "stack runs on one device (serving) until its sharding "
+            "and the experts' all-to-all are written")
     D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
     H, K, E = cfg.n_heads, cfg.kv_heads, cfg.n_experts
     fsdp = partial(_maybe, "fsdp", axis_sizes=axis_sizes)

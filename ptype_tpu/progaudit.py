@@ -605,14 +605,13 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
         bank = _bank_aval(cfg, n_blocks, block_tokens)
         i32 = jnp.int32
 
-        def decode_step(params, kb, vb, tok, pos, tables, wr_b, wr_o):
-            return gen.decode_step_paged(params, tok, pos, cfg, kb,
-                                         vb, tables, wr_b, wr_o)
+        def decode_step(params, banks, tok, pos, tables, wr_b, wr_o):
+            return gen.decode_step_banks(params, tok, pos, cfg, banks,
+                                         tables, wr_b, wr_o)[:2]
 
-        def prefill_chunk(params, kb, vb, tokens, start, length,
-                          table):
-            return gen.prefill_paged_chunk(
-                params, tokens, start, length, cfg, kb, vb, table)
+        def prefill_chunk(params, banks, tokens, start, length, table):
+            return gen.prefill_chunk_banks(
+                params, tokens, start, length, cfg, banks, table)[:2]
 
         row = jax.ShapeDtypeStruct((B,), i32)
         scalar = jax.ShapeDtypeStruct((), i32)
@@ -629,8 +628,8 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
         # Banks donated (the engine's donate_argnums shape): a dropped
         # donation, or a loop that cannot alias them, copies the whole
         # KV pool every step.
-        return audit(fn, (params_avals, bank, bank) + rest, name=name,
-                     donate_argnums=(1, 2), expect_collectives=0,
+        return audit(fn, (params_avals, {"k": bank, "v": bank}) + rest,
+                     name=name, donate_argnums=(1,), expect_collectives=0,
                      max_temp_bytes=_nbytes(bank))
 
     return builder
@@ -708,8 +707,8 @@ def _build_kv_pack(preset: str, n_blocks: int, block_tokens: int):
         # Residuals donated (consumed into the pre-quantization sum,
         # replaced by the new per-block error): a dropped donation
         # doubles the wire path's live residual memory per transfer.
-        return audit(make_pack_prog(), (blk, blk, blk, blk),
-                     name="serve.kv_pack", donate_argnums=(2, 3),
+        return audit(make_pack_prog(), (blk, blk),
+                     name="serve.kv_pack", donate_argnums=(1,),
                      expect_collectives=0)
 
     return builder
@@ -730,21 +729,18 @@ def _build_kv_unpack(preset: str, n_blocks: int, block_tokens: int):
         blk = jax.ShapeDtypeStruct(shape, jnp.float32)
         # The wire avals come from the pack program itself, so the
         # audited unpack consumes exactly what pack emits.
-        qk, sk, _, qv, sv, _ = jax.eval_shape(
-            make_pack_prog(), blk, blk, blk, blk)
+        q, s, _ = jax.eval_shape(make_pack_prog(), blk, blk)
         bank = jax.ShapeDtypeStruct(
             (cfg.n_layers, n_blocks, block_tokens, kvh, hd),
             jnp.float32)
-        args = (bank, bank,
-                jax.ShapeDtypeStruct(qk.shape, qk.dtype),
-                jax.ShapeDtypeStruct(sk.shape, sk.dtype),
-                jax.ShapeDtypeStruct(qv.shape, qv.dtype),
-                jax.ShapeDtypeStruct(sv.shape, sv.dtype),
+        args = (bank,
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(s.shape, s.dtype),
                 jax.ShapeDtypeStruct((), jnp.int32))
         # Banks donated (scatter-in-place): a dropped donation copies
         # the decode replica's WHOLE KV pool per imported block.
         return audit(make_unpack_prog(shape, jnp.float32), args,
-                     name="serve.kv_unpack", donate_argnums=(0, 1),
+                     name="serve.kv_unpack", donate_argnums=(0,),
                      expect_collectives=0)
 
     return builder

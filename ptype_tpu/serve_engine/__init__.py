@@ -22,7 +22,7 @@ The host-mesh probe behind ``bench.py --serve``'s
 the top-level ``bench.py``.
 
 The decode attention path is an XLA gather through the block table
-(``models/generate.decode_step_paged``); the optional Pallas kernel
+(``models/generate.decode_step_banks``); the optional Pallas kernel
 lives in :mod:`ptype_tpu.ops.paged_attention`, gated behind the same
 ``check_tpu_lowering`` machinery as the flash kernel.
 """

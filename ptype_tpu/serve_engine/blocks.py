@@ -1,7 +1,12 @@
 """Block pool: device-resident paged KV storage + content addressing.
 
-One bank of fixed-size KV blocks ``(L, n_blocks, block_tokens, Kh,
-Dh)`` backs every live sequence on a serving actor. Sequences hold
+Banks of fixed-size blocks back every live sequence on a serving
+actor: one ``(L, n_blocks, block_tokens, ...)`` array for each named
+per-token array the model says a layer's cache holds
+(``transformer.cache_spec``: ``k`` and ``v`` of ``(Kh, Dh)`` for GQA,
+the latent ``ckv`` and the indexer's key ``ki`` for latent attention).
+The pool allocates from that description, the migrator packs by it and
+the engine's programs carry the banks as one dict. Sequences hold
 *block tables* (ordered block ids); position ``p`` of a sequence lives
 in table entry ``p // block_tokens`` at offset ``p % block_tokens``.
 Three lifetimes per block:
@@ -100,13 +105,15 @@ class BlockPool:
                              "reserved trash block)")
         self.block_tokens = int(block_tokens)
         self.n_blocks = int(n_blocks)
-        shape = (cfg.n_layers, n_blocks, block_tokens, cfg.kv_heads,
-                 cfg.head_dim)
-        #: The banks, committed to ``device`` when one is given. The
-        #: engine owns these references — jitted steps/prefills donate
-        #: and replace them.
-        self.k = jnp.zeros(shape, cfg.dtype, device=device)
-        self.v = jnp.zeros(shape, cfg.dtype, device=device)
+        #: name -> per-token shape: what a block holds, from the model.
+        self.spec = tfm.cache_spec(cfg)
+        #: The banks, one a named array, committed to ``device`` when
+        #: one is given. The engine owns these references — jitted
+        #: steps/prefills donate the dict and replace it.
+        self.banks = {
+            name: jnp.zeros((cfg.n_layers, n_blocks, block_tokens)
+                            + tuple(shape), cfg.dtype, device=device)
+            for name, shape in self.spec.items()}
         self._lock = lockcheck.lock("serve_engine.pool")
         # Block 0 never allocated: the trash target for masked writes.
         self._free: list[int] = list(range(1, n_blocks))
@@ -120,6 +127,29 @@ class BlockPool:
         self._reserved = 0
         self.evictions = 0
         self.sealed = 0
+
+    @property
+    def k(self):
+        """A GQA model's K bank (``banks["k"]``)."""
+        return self.banks["k"]
+
+    @k.setter
+    def k(self, bank) -> None:
+        self.banks["k"] = bank
+
+    @property
+    def v(self):
+        return self.banks["v"]
+
+    @v.setter
+    def v(self, bank) -> None:
+        self.banks["v"] = bank
+
+    def block_shapes(self) -> dict[str, tuple[int, ...]]:
+        """name -> ``(L, block_tokens, ...)``: one block of each bank,
+        all layers — the unit the migrator packs."""
+        return {n: (b.shape[0],) + b.shape[2:]
+                for n, b in self.banks.items()}
 
     # --------------------------------------------------------- capacity
 

@@ -4,7 +4,7 @@
 the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
 
 - **Paged decode**: one engine step decodes every live slot through
-  per-sequence block tables (``models/generate.decode_step_paged``) —
+  per-sequence block tables (``models/generate.decode_step_banks``) —
   resident KV memory tracks actual token counts (pool blocks), not
   ``n_slots × reach`` contiguous banks. Greedy rows still match their
   solo decode token-for-token (gathered table order == position
@@ -248,8 +248,7 @@ class PagedGeneratorActor(GeneratorActor):
         #: residual store (docs/OPERATIONS.md "Disaggregated
         #: serving"). One per engine — residuals are keyed by chain
         #: hash, so they follow block CONTENT, not requests.
-        self._migrator = KVMigrator(
-            (cfg.n_layers, bt, cfg.kv_heads, cfg.head_dim), cfg.dtype)
+        self._migrator = KVMigrator(self.pool.block_shapes(), cfg.dtype)
         #: export_id -> finished prefill row whose block refs are
         #: parked for migration (released by ReleaseExport).
         self._exports: dict[int, _PagedRow] = {}
@@ -260,6 +259,16 @@ class PagedGeneratorActor(GeneratorActor):
         self._migrations = 0
         self._migrate_bytes = 0
         self._migrate_dedup_hits = 0
+        if attn == "kernel" and cfg.latent is not None:
+            raise ValueError(
+                "attn='kernel' reads K and V per head; a latent-"
+                "attention configuration takes attn='gather'")
+        if spec is not None and not cfg.plain:
+            raise ValueError(
+                "speculative decoding drafts with a truncated GQA "
+                "stack of one group; this configuration has latent "
+                "attention or several layer groups (its own next-token "
+                "module would be the drafter, and is not run)")
         if attn == "kernel" and jax.default_backend() != "cpu":
             from ptype_tpu.ops.paged_attention import check_tpu_lowering
 
@@ -338,7 +347,7 @@ class PagedGeneratorActor(GeneratorActor):
         self._max_stall_ms = 0.0
         self._last_stall_ms = 0.0
 
-        def engine_step(sampled, params, kb, vb, tok, pos, tables,
+        def engine_step(sampled, params, banks, tok, pos, tables,
                         active, keys, eidx, temps, topk, topp):
             B = tok.shape[0]
             bt_ = self.block_tokens
@@ -350,9 +359,9 @@ class PagedGeneratorActor(GeneratorActor):
             wr_b = jnp.where(active,
                              tables[jnp.arange(B), pos // bt_], 0)
             wr_o = pos % bt_
-            logits, kb, vb = gen.decode_step_paged(
-                params, tok, pos, self.cfg, kb, vb, tables, wr_b,
-                wr_o, attn_impl=self.attn)
+            logits, banks, load = gen.decode_step_banks(
+                params, tok, pos, self.cfg, banks, tables, wr_b,
+                wr_o, attn_impl=self.attn, live=active)
             with jax.named_scope("sample"):
                 if sampled:
                     nxt = gen.sample_token_rows(logits, keys, eidx,
@@ -364,11 +373,17 @@ class PagedGeneratorActor(GeneratorActor):
                     # inspection).
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 nxt = jnp.where(active, nxt, 0)
-            return (kb, vb, nxt, jnp.where(active, pos + 1, pos),
-                    jnp.where(active, eidx + 1, eidx))
+            # A dropless router's layers count their load on the
+            # device (transformer._moe_dropless): the step then returns,
+            # for the host's one fetch, the tokens with the counts of
+            # the live lanes behind them.
+            fetch = (() if load is None
+                     else (jnp.concatenate([nxt, load]),))
+            return (banks, nxt, jnp.where(active, pos + 1, pos),
+                    jnp.where(active, eidx + 1, eidx)) + fetch
 
         # Donate the banks: the engine must not copy the pool per step.
-        self._engine_step = jax.jit(engine_step, donate_argnums=(2, 3),
+        self._engine_step = jax.jit(engine_step, donate_argnums=(2,),
                                     static_argnums=(0,))
         #: Device mirrors of the slot state; None = host copy is
         #: authoritative and must be re-uploaded (set dirty by
@@ -637,8 +652,7 @@ class PagedGeneratorActor(GeneratorActor):
                 for i in want:
                     h = row.hashes[i] if i < nfull else None
                     payload, nb = self._migrator.pack_block(
-                        self.pool.k, self.pool.v, row.table[i], h,
-                        kv_wire)
+                        self.pool.banks, row.table[i], h, kv_wire)
                     entry = {"idx": int(i),
                              "hash": int(h) if h is not None else None}
                     entry.update(payload)
@@ -799,10 +813,9 @@ class PagedGeneratorActor(GeneratorActor):
         with self._lock:
             with jitwatch.hot_region("serve.migrate"):
                 for i in sorted(entries):
-                    self.pool.k, self.pool.v = \
-                        self._migrator.unpack_block(
-                            self.pool.k, self.pool.v, entries[i],
-                            t["table"][i], mode)
+                    self.pool.banks = self._migrator.unpack_block(
+                        self.pool.banks, entries[i], t["table"][i],
+                        mode)
         for i in sorted(entries):
             if i < nfull:
                 self.pool.seal(t["table"][i], t["hashes"][i],
@@ -1049,13 +1062,14 @@ class PagedGeneratorActor(GeneratorActor):
     def _chunk_prog(self, C: int):
         prog = self._chunk_progs.get(C)
         if prog is None:
-            def prefill_chunk(params, kb, vb, tokens, start, length,
+            def prefill_chunk(params, banks, tokens, start, length,
                               table):
-                return gen.prefill_paged_chunk(
-                    params, tokens, start, length, self.cfg, kb, vb,
+                logits, banks, _load = gen.prefill_chunk_banks(
+                    params, tokens, start, length, self.cfg, banks,
                     table)
+                return logits, banks
 
-            prog = jax.jit(prefill_chunk, donate_argnums=(1, 2))
+            prog = jax.jit(prefill_chunk, donate_argnums=(1,))
             self._chunk_progs[C] = prog
         return prog
 
@@ -1087,9 +1101,9 @@ class PagedGeneratorActor(GeneratorActor):
             # buffers; one that dispatches after sees the NEW bank
             # refs — never a half-donated alias.
             with self._lock:
-                logits, self.pool.k, self.pool.v = self._chunk_prog(
+                logits, self.pool.banks = self._chunk_prog(
                     padded.shape[1])(
-                    self.params, self.pool.k, self.pool.v,
+                    self.params, self.pool.banks,
                     jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
                     jnp.asarray(table_arr))
             row.prefill_pos += n
@@ -1323,16 +1337,20 @@ class PagedGeneratorActor(GeneratorActor):
             # raise at the call — the steady-state step re-uploads
             # NOTHING, and jitwatch counts its compiles.
             with jitwatch.hot_region("serve.decode"):
-                (self.pool.k, self.pool.v, nxt, d["pos"],
-                 d["eidx"]) = self._engine_step(
-                    sampled, self.params, self.pool.k, self.pool.v,
+                (self.pool.banks, nxt, d["pos"], d["eidx"],
+                 *fetch) = self._engine_step(
+                    sampled, self.params, self.pool.banks,
                     d["tok"], d["pos"], d["tables"], d["active"],
                     d["keys"], d["eidx"], d["temps"], d["topk"],
                     d["topp"])
         d["tok"] = nxt
         with annotate("serve.step/fetch"):
             # The host waits for the device here.
-            nxt_host = np.array(nxt)  # host mirror for retire bookkeeping
+            nxt_host = np.array(fetch[0] if fetch else nxt)  # host
+            #   mirror for retire bookkeeping
+        if fetch:
+            self.ledger.moe_load(nxt_host[self.n_slots:])
+            nxt_host = nxt_host[:self.n_slots]
         with annotate("serve.step/emit"):
             self._pos[self._active] += 1
             self._eidx[self._active] += 1
@@ -1374,8 +1392,8 @@ class PagedGeneratorActor(GeneratorActor):
         C = max(16, _pow2(L))
         padded = np.zeros((1, C), np.int32)
         padded[0, :L] = toks
-        _, self._dpool.k, self._dpool.v = self._draft_chunk_prog(C)(
-            self._spec.draft_params, self._dpool.k, self._dpool.v,
+        _, self._dpool.banks = self._draft_chunk_prog(C)(
+            self._spec.draft_params, self._dpool.banks,
             jnp.asarray(padded), jnp.int32(0), jnp.int32(L),
             jnp.asarray(table_arr))
         self._dpool.k.block_until_ready()
@@ -1385,13 +1403,13 @@ class PagedGeneratorActor(GeneratorActor):
         if prog is None:
             dcfg = self._spec.draft_cfg
 
-            def draft_prefill_chunk(params, kb, vb, tokens, start,
+            def draft_prefill_chunk(params, banks, tokens, start,
                                     length, table):
-                return gen.prefill_paged_chunk(
-                    params, tokens, start, length, dcfg, kb, vb,
-                    table)
+                return gen.prefill_chunk_banks(
+                    params, tokens, start, length, dcfg, banks,
+                    table)[:2]
 
-            prog = jax.jit(draft_prefill_chunk, donate_argnums=(1, 2))
+            prog = jax.jit(draft_prefill_chunk, donate_argnums=(1,))
             self._draft_chunk_progs[C] = prog
         return prog
 
@@ -1417,8 +1435,8 @@ class PagedGeneratorActor(GeneratorActor):
         padded[0, :n] = seq[start:end]
         table_arr = np.zeros(self.nb, np.int32)
         table_arr[:len(row.draft_table)] = row.draft_table
-        _, self._dpool.k, self._dpool.v = self._draft_chunk_prog(C)(
-            self._spec.draft_params, self._dpool.k, self._dpool.v,
+        _, self._dpool.banks = self._draft_chunk_prog(C)(
+            self._spec.draft_params, self._dpool.banks,
             jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
             jnp.asarray(table_arr))
         self._dpos[slot] = end

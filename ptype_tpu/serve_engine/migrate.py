@@ -33,9 +33,9 @@ through block i), so the decode side's content-verified residency
 check is exact, and dedup hits are counted, never re-sent.
 
 The pack/unpack programs carry the dispatch-discipline contracts the
-rest of the data plane lives by: pack DONATES the residual buffers
+rest of the data plane lives by: pack DONATES the residual buffer
 (consumed into the pre-quantization sum, replaced by the new error),
-unpack DONATES the target banks (scatter-in-place) — both registered
+unpack DONATES the target bank (scatter-in-place) — both registered
 with ``progaudit`` as ``serve.kv_pack`` / ``serve.kv_unpack``
 (donation consumed, no callbacks, no f64), and the engine runs them
 inside a ``jitwatch.hot_region("serve.migrate")``.
@@ -75,128 +75,133 @@ def _unwire_leaf(leaf: dict) -> np.ndarray:
 
 
 def make_pack_prog(q_block: int | None = DEFAULT_QUANT_BLOCK):
-    """One jitted program quantizing a single block's K/V pair for the
-    wire: ``(k_blk, v_blk, res_k, res_v) -> (qk, sk, new_res_k, qv,
-    sv, new_res_v)``. The residuals are DONATED — consumed into the
-    pre-quantization sum and replaced by the new per-block error (the
-    ``serve.kv_pack`` progaudit contract)."""
+    """One jitted program quantizing one block of ONE bank for the
+    wire: ``(blk, res) -> (q, s, new_res)``. The residual is DONATED —
+    consumed into the pre-quantization sum and replaced by the new
+    per-block error (the ``serve.kv_pack`` progaudit contract). A
+    block's banks go through it one named array at a time, whatever
+    the model's cache holds (``transformer.cache_spec``)."""
 
-    def pack(kblk, vblk, rk, rv):
-        wk, nrk = quantize_leaf(kblk, q_block, rk)
-        wv, nrv = quantize_leaf(vblk, q_block, rv)
-        return wk["q"], wk["s"], nrk, wv["q"], wv["s"], nrv
+    def pack(blk, res):
+        w, new_res = quantize_leaf(blk, q_block, res)
+        return w["q"], w["s"], new_res
 
-    return jax.jit(pack, donate_argnums=(2, 3))
+    return jax.jit(pack, donate_argnums=(1,))
 
 
 def make_unpack_prog(block_shape, bank_dtype):
-    """One jitted program scattering a quantized block pair into the
-    target banks at ``bid``: ``(kb, vb, qk, sk, qv, sv, bid) -> (kb,
-    vb)``. The banks are DONATED — the import is a scatter-in-place,
-    never a bank copy (the ``serve.kv_unpack`` progaudit contract)."""
+    """One jitted program scattering a quantized block into its target
+    bank at ``bid``: ``(bank, q, s, bid) -> bank``. The bank is
+    DONATED — the import is a scatter-in-place, never a bank copy (the
+    ``serve.kv_unpack`` progaudit contract)."""
     shape = [int(d) for d in block_shape]
     dstr = np.dtype(bank_dtype).name
 
-    def unpack(kb, vb, qk, sk, qv, sv, bid):
-        kblk = dequantize_leaf(
-            {_Q8_KEY: 1, "q": qk, "s": sk, "shape": shape, "dtype": dstr})
-        vblk = dequantize_leaf(
-            {_Q8_KEY: 1, "q": qv, "s": sv, "shape": shape, "dtype": dstr})
-        kb = kb.at[:, bid].set(kblk.astype(kb.dtype))
-        vb = vb.at[:, bid].set(vblk.astype(vb.dtype))
-        return kb, vb
+    def unpack(bank, q, s, bid):
+        blk = dequantize_leaf(
+            {_Q8_KEY: 1, "q": q, "s": s, "shape": shape, "dtype": dstr})
+        return bank.at[:, bid].set(blk.astype(bank.dtype))
 
-    return jax.jit(unpack, donate_argnums=(0, 1))
+    return jax.jit(unpack, donate_argnums=(0,))
 
 
 def make_unpack_exact_prog():
-    """Exact-mode import scatter (no dequantize): ``(kb, vb, k_blk,
-    v_blk, bid) -> (kb, vb)``, banks donated."""
+    """Exact-mode import scatter (no dequantize): ``(bank, blk, bid) ->
+    bank``, the bank donated."""
 
-    def unpack(kb, vb, kblk, vblk, bid):
-        kb = kb.at[:, bid].set(kblk.astype(kb.dtype))
-        vb = vb.at[:, bid].set(vblk.astype(vb.dtype))
-        return kb, vb
+    def unpack(bank, blk, bid):
+        return bank.at[:, bid].set(blk.astype(bank.dtype))
 
-    return jax.jit(unpack, donate_argnums=(0, 1))
+    return jax.jit(unpack, donate_argnums=(0,))
 
 
 class KVMigrator:
     """Per-engine wire state: the jitted pack/unpack programs plus the
     prefill-side error-feedback residual store.
 
+    ``block_shapes``: name -> ``(L, block_tokens, ...)``, one block of
+    each of the pool's banks (``BlockPool.block_shapes()``): the same
+    description of what a token holds that the pool allocated from. A
+    payload has one entry a name.
+
     Residuals are keyed by the block's CHAIN hash (content-stable —
     the same key the pool's dedup index and the gateway's prefix
-    directory use), bounded by an LRU of ``max_residuals`` block
-    pairs; the unsealed partial tail block of a prompt has no hash
-    and carries no residual (it is exported at most once per
-    request). Thread contract: calls come from the engine's RPC
-    handler threads under the engine's dispatch lock — the same lock
-    that orders bank-donating programs."""
+    directory use), bounded by an LRU of ``max_residuals`` blocks; the
+    unsealed partial tail block of a prompt has no hash and carries no
+    residual (it is exported at most once per request). Thread
+    contract: calls come from the engine's RPC handler threads under
+    the engine's dispatch lock — the same lock that orders
+    bank-donating programs."""
 
-    def __init__(self, block_shape, bank_dtype, *,
+    def __init__(self, block_shapes: dict, bank_dtype, *,
                  q_block: int | None = DEFAULT_QUANT_BLOCK,
                  max_residuals: int = 64):
-        self.block_shape = tuple(int(d) for d in block_shape)
+        self.block_shapes = {n: tuple(int(d) for d in shape)
+                             for n, shape in block_shapes.items()}
         self.bank_dtype = np.dtype(bank_dtype)
         self.q_block = q_block
         self.max_residuals = int(max_residuals)
         self._pack = make_pack_prog(q_block)
-        self._unpack = make_unpack_prog(self.block_shape, bank_dtype)
+        self._unpack = {n: make_unpack_prog(shape, bank_dtype)
+                        for n, shape in self.block_shapes.items()}
         self._unpack_exact = make_unpack_exact_prog()
-        #: hash -> (res_k, res_v), LRU oldest-first.
-        self._res: collections.OrderedDict[int, tuple] = \
+        #: hash -> {name: residual}, LRU oldest-first.
+        self._res: collections.OrderedDict[int, dict] = \
             collections.OrderedDict()
 
     # ------------------------------------------------------------- pack
 
-    def pack_block(self, kb, vb, bid: int, h: int | None,
+    def pack_block(self, banks: dict, bid: int, h: int | None,
                    mode: str) -> tuple[dict, int]:
-        """Encode block ``bid`` of banks ``(kb, vb)`` for the wire.
-        Returns ``(payload, nbytes)`` — the payload is codec-
-        marshalable (numpy leaves only)."""
+        """Encode block ``bid`` of ``banks`` for the wire. Returns
+        ``(payload, nbytes)`` — the payload is codec-marshalable
+        (numpy leaves only)."""
         if mode not in WIRE_MODES:
             raise ValueError(f"kv_wire must be one of {WIRE_MODES}, "
                              f"got {mode!r}")
         if mode == "exact":
             # device_get, not np.asarray: the engine packs inside an
             # armed hot_region, where only EXPLICIT transfers are
-            # legal — the wire hop IS the contract here.
-            k = np.ascontiguousarray(jax.device_get(kb[:, bid]))
-            v = np.ascontiguousarray(jax.device_get(vb[:, bid]))
-            payload = {"k": _wire_leaf(k), "v": _wire_leaf(v)}
-            return payload, k.nbytes + v.nbytes
-        rk = rv = None
+            # legal — the wire hop IS the contract here. One get for
+            # all of the block's arrays.
+            host = jax.device_get({name: banks[name][:, bid]
+                                   for name in self.block_shapes})
+            blks = {n: np.ascontiguousarray(a) for n, a in host.items()}
+            return ({n: _wire_leaf(a) for n, a in blks.items()},
+                    sum(a.nbytes for a in blks.values()))
+        res = self._res.pop(h, None) if h is not None else None
+        wire, new_res = {}, {}
+        for name, shape in self.block_shapes.items():
+            r = (res[name] if res is not None
+                 else jnp.zeros(shape, self.bank_dtype))
+            q, s, new_res[name] = self._pack(banks[name][:, bid], r)
+            wire[name] = {"q": q, "s": s}
         if h is not None:
-            rk, rv = self._res.pop(h, (None, None))
-        if rk is None:
-            rk = jnp.zeros(self.block_shape, self.bank_dtype)
-            rv = jnp.zeros(self.block_shape, self.bank_dtype)
-        qk, sk, nrk, qv, sv, nrv = self._pack(kb[:, bid], vb[:, bid],
-                                              rk, rv)
-        if h is not None:
-            self._res[h] = (nrk, nrv)
+            self._res[h] = new_res
             while len(self._res) > self.max_residuals:
                 self._res.popitem(last=False)
-        qk, sk = jax.device_get(qk), jax.device_get(sk)
-        qv, sv = jax.device_get(qv), jax.device_get(sv)
-        payload = {"k": {"q": qk, "s": sk}, "v": {"q": qv, "s": sv}}
-        return payload, (qk.nbytes + sk.nbytes + qv.nbytes + sv.nbytes)
+        payload = jax.device_get(wire)
+        return payload, sum(leaf["q"].nbytes + leaf["s"].nbytes
+                            for leaf in payload.values())
 
     # ----------------------------------------------------------- unpack
 
-    def unpack_block(self, kb, vb, payload: dict, bid: int, mode: str):
-        """Scatter one wire payload into banks at ``bid``; returns the
-        new ``(kb, vb)`` (the old ones are donated)."""
-        if mode == "exact":
-            return self._unpack_exact(
-                kb, vb, jnp.asarray(_unwire_leaf(payload["k"])),
-                jnp.asarray(_unwire_leaf(payload["v"])),
-                jnp.int32(bid))
-        pk, pv = payload["k"], payload["v"]
-        return self._unpack(
-            kb, vb, jnp.asarray(pk["q"]), jnp.asarray(pk["s"]),
-            jnp.asarray(pv["q"]), jnp.asarray(pv["s"]), jnp.int32(bid))
+    def unpack_block(self, banks: dict, payload: dict, bid: int,
+                     mode: str) -> dict:
+        """Scatter one wire payload into ``banks`` at ``bid``; returns
+        the new banks (the old ones are donated)."""
+        out = {}
+        for name in self.block_shapes:
+            leaf = payload[name]
+            if mode == "exact":
+                out[name] = self._unpack_exact(
+                    banks[name], jnp.asarray(_unwire_leaf(leaf)),
+                    jnp.int32(bid))
+            else:
+                out[name] = self._unpack[name](
+                    banks[name], jnp.asarray(leaf["q"]),
+                    jnp.asarray(leaf["s"]), jnp.int32(bid))
+        return out
 
     # -------------------------------------------------------- residuals
 

@@ -48,12 +48,12 @@ def _lowered(program: str) -> str:
     try:
         if program == "decode":
             low = eng._engine_step.lower(
-                False, eng.params, eng.pool.k, eng.pool.v, eng._tok,
-                eng._pos, eng._tables, eng._active, eng._keys, eng._eidx,
+                False, eng.params, eng.pool.banks, eng._tok, eng._pos,
+                eng._tables, eng._active, eng._keys, eng._eidx,
                 eng._temps, eng._topk, eng._topp)
         else:
             low = eng._chunk_prog(16).lower(
-                eng.params, eng.pool.k, eng.pool.v,
+                eng.params, eng.pool.banks,
                 jnp.zeros((1, 16), jnp.int32), jnp.int32(0),
                 jnp.int32(16), jnp.zeros(eng.nb, jnp.int32))
     finally:

@@ -64,35 +64,41 @@ def _migrate(pre, dec, prompt, max_new, kv_wire="exact"):
 # ------------------------------------------------------- wire (unit)
 
 
+def _unpack(mig, kb, vb, payload, bid, mode):
+    """Into empty banks of a GQA model's two names."""
+    out = mig.unpack_block({"k": jnp.zeros_like(kb),
+                            "v": jnp.zeros_like(vb)}, payload, bid, mode)
+    return out["k"], out["v"]
+
+
 def test_kv_migrator_roundtrip_and_residual_lru():
     shape = (2, BT, 2, 8)
     rng = np.random.default_rng(3)
     kb = jnp.asarray(rng.normal(size=(2, 4) + shape[1:]), jnp.float32)
     vb = jnp.asarray(rng.normal(size=(2, 4) + shape[1:]), jnp.float32)
-    mig = KVMigrator(shape, jnp.float32, max_residuals=3)
+    mig = KVMigrator({"k": shape, "v": shape}, jnp.float32,
+                     max_residuals=3)
     # Exact mode: bit-identical through the wire.
-    payload, nb = mig.pack_block(kb, vb, 1, None, "exact")
+    payload, nb = mig.pack_block({"k": kb, "v": vb}, 1, None, "exact")
     assert nb == 2 * int(np.prod(shape)) * 4
-    k2, v2 = mig.unpack_block(jnp.zeros_like(kb), jnp.zeros_like(vb),
-                              payload, 2, "exact")
+    k2, v2 = _unpack(mig, kb, vb, payload, 2, "exact")
     np.testing.assert_array_equal(np.asarray(k2[:, 2]),
                                   np.asarray(kb[:, 1]))
     np.testing.assert_array_equal(np.asarray(v2[:, 2]),
                                   np.asarray(vb[:, 1]))
     # q8: close, and the wire is ~4x smaller than raw f32.
-    payload, nbq = mig.pack_block(kb, vb, 1, 7, "q8")
+    payload, nbq = mig.pack_block({"k": kb, "v": vb}, 1, 7, "q8")
     assert nbq < nb / 2
-    k3, v3 = mig.unpack_block(jnp.zeros_like(kb), jnp.zeros_like(vb),
-                              payload, 0, "q8")
+    k3, v3 = _unpack(mig, kb, vb, payload, 0, "q8")
     np.testing.assert_allclose(np.asarray(k3[:, 0]),
                                np.asarray(kb[:, 1]), atol=0.05)
     # Residuals: keyed by hash, LRU-bounded.
     assert mig.residual_count() == 1
     for h in range(20, 26):
-        mig.pack_block(kb, vb, 0, h, "q8")
+        mig.pack_block({"k": kb, "v": vb}, 0, h, "q8")
     assert mig.residual_count() == 3
     with pytest.raises(ValueError, match="kv_wire"):
-        mig.pack_block(kb, vb, 0, None, "zstd")
+        mig.pack_block({"k": kb, "v": vb}, 0, None, "zstd")
     assert WIRE_MODES == ("q8", "exact")
 
 
@@ -108,18 +114,17 @@ def test_exact_wire_bf16_banks_survive_the_socket_codec():
     rng = np.random.default_rng(5)
     kb = jnp.asarray(rng.normal(size=(2, 4) + shape[1:]), jnp.bfloat16)
     vb = jnp.asarray(rng.normal(size=(2, 4) + shape[1:]), jnp.bfloat16)
-    mig = KVMigrator(shape, jnp.bfloat16)
-    payload, nb = mig.pack_block(kb, vb, 1, None, "exact")
+    mig = KVMigrator({"k": shape, "v": shape}, jnp.bfloat16)
+    payload, nb = mig.pack_block({"k": kb, "v": vb}, 1, None, "exact")
     assert nb == 2 * int(np.prod(shape)) * 2
     wired = codec.decode(codec.encode(payload))  # the socket hop
-    k2, v2 = mig.unpack_block(jnp.zeros_like(kb), jnp.zeros_like(vb),
-                              wired, 3, "exact")
+    k2, v2 = _unpack(mig, kb, vb, wired, 3, "exact")
     np.testing.assert_array_equal(np.asarray(k2[:, 3]),
                                   np.asarray(kb[:, 1]))
     np.testing.assert_array_equal(np.asarray(v2[:, 3]),
                                   np.asarray(vb[:, 1]))
     # q8 leaves (int8 q, f32 s) are codec-native even off bf16 banks.
-    payload, _ = mig.pack_block(kb, vb, 0, 9, "q8")
+    payload, _ = mig.pack_block({"k": kb, "v": vb}, 0, 9, "q8")
     codec.decode(codec.encode(payload))
 
 

@@ -32,8 +32,8 @@ def _noise_banks(seed):
 @pytest.mark.parametrize("attn_impl", ["gather", "kernel"])
 def test_paged_programs_match_contiguous_greedy(attn_impl):
     """Two rows that SHARE their first block and a third, inactive
-    lane routed to the trash block, through ``prefill_paged_chunk``
-    and ``decode_step_paged``: each live row equals the untouched
+    lane routed to the trash block, through ``prefill_chunk_banks``
+    and ``decode_step_banks``: each live row equals the untouched
     contiguous ``generate()`` token for token and the contiguous
     ``prefill`` + ``decode_step`` logit for logit (a random-init model
     mostly echoes its input, so tokens alone would forgive a layer
@@ -52,16 +52,17 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
     kb0, vb0 = _noise_banks(4)
 
     chunk = jax.jit(lambda kb, vb, toks, start, length, table:
-                    gen.prefill_paged_chunk(params, toks, start, length,
-                                            CFG3, kb, vb, table))
+                    gen.prefill_chunk_banks(params, toks, start, length,
+                                            CFG3, {"k": kb, "v": vb},
+                                            table)[:2])
 
     def prefill(kb, vb, row, start):
         toks = np.zeros((1, 32), np.int32)
         n = len(prompts[row]) - start
         toks[0, :n] = prompts[row][start:]
-        lg, kb, vb = chunk(kb, vb, jnp.asarray(toks), jnp.int32(start),
-                           jnp.int32(n), tables[row])
-        return np.asarray(lg[0]), kb, vb
+        lg, banks = chunk(kb, vb, jnp.asarray(toks), jnp.int32(start),
+                          jnp.int32(n), tables[row])
+        return np.asarray(lg[0]), banks["k"], banks["v"]
 
     lg_a, kb, vb = prefill(kb0, vb0, 0, 0)
     shared_k, shared_v = np.asarray(kb[:, 3]), np.asarray(vb[:, 3])
@@ -71,9 +72,10 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
     @jax.jit
     def step(kb, vb, tok, pos, active):
         wr_b = jnp.where(active, tables[jnp.arange(3), pos // BT], 0)
-        lg, kb, vb = gen.decode_step_paged(
-            params, tok, pos, CFG3, kb, vb, tables, wr_b, pos % BT,
-            attn_impl=attn_impl)
+        lg, banks, _ = gen.decode_step_banks(
+            params, tok, pos, CFG3, {"k": kb, "v": vb}, tables, wr_b,
+            pos % BT, attn_impl=attn_impl)
+        kb, vb = banks["k"], banks["v"]
         nxt = jnp.where(active, jnp.argmax(lg, -1).astype(jnp.int32), 0)
         return kb, vb, lg, nxt, jnp.where(active, pos + 1, pos)
 
@@ -132,18 +134,20 @@ def test_paged_write_lands_in_its_own_layer_rows_only(program):
     wr_o = ap % BT
     if program == "decode":
         wr_b, wr_o = wr_b[:, :1], wr_o[:, :1]
-        _, kb, vb = gen.decode_step_paged(
-            params, tok, pos0, CFG3, kb0, vb0, tables, wr_b[:, 0],
-            wr_o[:, 0])
+        _, banks, _ = gen.decode_step_banks(
+            params, tok, pos0, CFG3, {"k": kb0, "v": vb0}, tables,
+            wr_b[:, 0], wr_o[:, 0])
+        kb, vb = banks["k"], banks["v"]
     elif program == "chunk":
         # One sequence: positions 20..24 of table 1, 3 pads to trash.
         n = 5
         wr_b = jnp.concatenate([jnp.full((n,), 9, i32),
                                 jnp.zeros((3,), i32)])[None]
         wr_o = ((20 + jnp.arange(8)) % BT)[None]
-        _, kb, vb = gen.prefill_paged_chunk(
-            params, jnp.ones((1, 8), i32), i32(20), i32(n), CFG3, kb0,
-            vb0, tables[1])
+        _, banks, _ = gen.prefill_chunk_banks(
+            params, jnp.ones((1, 8), i32), i32(20), i32(n), CFG3,
+            {"k": kb0, "v": vb0}, tables[1])
+        kb, vb = banks["k"], banks["v"]
     elif program == "verify":
         toks = jnp.asarray([[11, 5, 6], [12, 7, 8]], i32)
         _, kb, vb = gen.verify_step_paged(params, toks, pos0, CFG3, kb0,
