@@ -666,6 +666,18 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     out["paged_decode_step_err"] = round(_close(
         decode("kernel")(params, kb, vb), decode("gather")(params, kb, vb),
         'decode_step_banks attn="kernel" vs "gather" logits'), 5)
+    # The engine's default step: over the live rows' block list, against
+    # the same step through the tables.
+    blocks = gen.live_block_list(
+        np.asarray(tables), np.asarray(pos) // bt + 1,
+        np.ones(n_slots, bool), bt)
+    listed = jax.jit(lambda p, kb, vb, blocks: gen.decode_step_banks(
+        p, tok, pos, wide, {"k": kb, "v": vb}, tables, wr_b, pos % bt,
+        blocks=blocks)[0])
+    out["paged_decode_blocks_err"] = round(_close(
+        listed(params, kb, vb, blocks), decode("gather")(params, kb, vb),
+        "decode_step_banks over the block list vs the tables, logits"),
+        5)
 
     # Lowerings with no Pallas in them that no TPU compiler had seen.
     moe = tfm.preset("tiny-moe", attn_impl="xla")

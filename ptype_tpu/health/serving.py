@@ -374,6 +374,11 @@ class ServingLedger:
         #: from the summary until an iteration reported some.
         self._moe_load: list[int] = []
         self._moe_iters = 0
+        #: The decode step's live-block list (kv_list): running totals
+        #: of steps, listed blocks, tiles run, tokens attended and
+        #: tokens the tiles covered; absent from the summary until a
+        #: step ran over a list.
+        self._kv_list = [0, 0, 0, 0, 0]
 
     # --------------------------------------------------- request seams
 
@@ -467,6 +472,20 @@ class ServingLedger:
                         held=":".join(str(c) for c in counts[:-1]),
                         elsewhere=counts[-1]):
             pass
+
+    def kv_list(self, blocks: int, tiles: int, live_tokens: int,
+                tile_tokens: int) -> None:
+        """One decode step over the live rows' block list
+        (``generate.live_block_list``): ``blocks`` listed, ``tiles`` of
+        ``tile_tokens`` tokens run, ``live_tokens`` attended (Σ live
+        context). Running totals behind ``summary()``'s ``kv_blocks``,
+        ``kv_tiles`` (means a step) and ``kv_tile_fill`` (tokens
+        attended ÷ tokens the tiles covered: what of the step's
+        attention work was not padding or duplication)."""
+        with self._lock:
+            for i, v in enumerate((1, blocks, tiles, live_tokens,
+                                   tiles * tile_tokens)):
+                self._kv_list[i] += v
 
     def shed_untracked(self) -> None:
         """A shed before any record existed (the chaos admit seam)."""
@@ -606,7 +625,13 @@ class ServingLedger:
             spec_toks = self._spec_tokens
             migrated = self._migrated
             moe_load, moe_iters = list(self._moe_load), self._moe_iters
+            kv_steps, kv_blocks, kv_tiles, kv_live, kv_covered = \
+                self._kv_list
         out = {}
+        if kv_steps:
+            out["kv_blocks"] = round(kv_blocks / kv_steps, 2)
+            out["kv_tiles"] = round(kv_tiles / kv_steps, 3)
+            out["kv_tile_fill"] = round(kv_live / max(kv_covered, 1), 4)
         if moe_iters:
             out["moe_load"] = {"iterations": moe_iters,
                                "held": moe_load[:-1],

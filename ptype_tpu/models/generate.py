@@ -258,7 +258,10 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
     in POSITION order, so the gathered layout is exactly the
     contiguous cache (garbage in never-written / trash-block columns
     is masked, and masked-out columns contribute exact zeros to the
-    softmax sums — greedy rows match the contiguous path bit-for-bit).
+    softmax sums — greedy rows match the contiguous path token for
+    token, logits to float32 rounding). The prefill chunk and the
+    speculative programs attend through it; the decode step only when
+    it is given no block list (:func:`_live_block_attention`).
 
     ``pos_limit``: (B,) per-row limits (decode, Q=1) or (B, Q)
     per-query limits (chunked prefill: query c attends positions
@@ -290,9 +293,108 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
         return o.reshape(B, Q, H, Dh)
 
 
+#: Blocks in one tile of the live-block list (4,096 tokens at 16 a
+#: block: 8 MB of K, 8 MB of V and 17 MB of float32 scores at mistral's
+#: widths and 32 lanes). Chosen on the chip (PERF.md §6, PR 29).
+LIVE_TILE_BLOCKS = 256
+
+
+def live_block_list(tables, nalloc, active, block_tokens: int,
+                    tile: int | None = None):
+    """The K,V blocks the live rows hold, as the decode step's
+    attention reads them (:func:`_live_block_attention`). Host side,
+    numpy: ``tables`` (n_slots, nb) block ids in position order,
+    ``nalloc`` (n_slots,) blocks allocated a row, ``active`` (n_slots,)
+    bool. Returns ``(blocks, n_tiles)``: ``blocks`` int32 ``(3,
+    max_tiles, tile)`` holds, row after row, each allocated block's id,
+    its owning lane and the position of its first token, padded with
+    the trash block (id 0) under an owner no lane has (``n_slots``);
+    ``n_tiles`` int32 is the number of tiles in use, the step's trip
+    count. A block two rows share (prefix reuse) is listed once a row.
+    ``max_tiles * tile`` covers every lane at its whole reach, so the
+    shape never changes while the engine lives."""
+    ns, nb = tables.shape
+    tile = min(int(tile or LIVE_TILE_BLOCKS), ns * nb)
+    max_tiles = -(-(ns * nb) // tile)
+    held = (np.arange(nb)[None, :] < np.asarray(nalloc)[:, None]) \
+        & np.asarray(active, bool)[:, None]
+    lane, col = np.nonzero(held)  # row-major: row after row
+    n = lane.size
+    blocks = np.zeros((3, max_tiles * tile), np.int32)
+    blocks[1] = ns
+    blocks[0, :n] = tables[lane, col]
+    blocks[1, :n] = lane
+    blocks[2, :n] = col * block_tokens
+    return (blocks.reshape(3, max_tiles, tile),
+            np.int32(-(-n // tile)))
+
+
+def _live_block_attention(q, kf, vf, base, blocks, limits):
+    """Decode attention over the blocks live rows hold: work follows
+    Σ live context, not lanes x reach. q: (B, 1, H, Dh); ``kf``/``vf``:
+    the flat banks ``(L * n_blocks, block_tokens, Kh, Dh)``, ``base``
+    the layer's first row in them; ``blocks`` as
+    :func:`live_block_list` gives it; ``limits`` (B,): lane ``b``
+    attends positions ``< limits[b]`` of its own blocks.
+
+    One loop over the list's tiles in use (a ``while`` whose trip count
+    is data, so ONE compiled program whatever the load): a tile's
+    blocks are gathered once, EVERY lane's queries are scored against
+    them (``M = B * G`` rows a KV head: a real matmul, where a product a
+    block would be ``G x block_tokens``), what is not the lane's own or
+    lies past its limit is masked, and the tile folds into a running
+    max / sum / accumulator per (lane, head): the same float32 softmax
+    as :func:`_paged_attention_gather`, accumulated tile by tile, so
+    equal to it to float32 rounding. The banks are closed over and only
+    read: nothing of them is loop state. A lane that owns no listed
+    block (inactive) gets zeros."""
+    lst, n_tiles = blocks
+    B, _, H, Dh = q.shape
+    bt, Kh = kf.shape[1], kf.shape[2]
+    G = H // Kh
+    S = lst.shape[2] * bt
+    f32 = jnp.float32
+    with jax.named_scope("attn"):
+        qg = q.reshape(B, Kh, G, Dh)
+        lanes = jnp.arange(B, dtype=jnp.int32)
+        limits = jnp.asarray(limits, jnp.int32)
+        offs = jnp.arange(bt, dtype=jnp.int32)
+
+        def fold(t, carry):
+            m, l, acc = carry
+            ids, owner, first = lst[0, t], lst[1, t], lst[2, t]
+            with jax.named_scope("kv_gather"):
+                ks = kf[base + ids].reshape(S, Kh, Dh)
+                vs = vf[base + ids].reshape(S, Kh, Dh)
+            s = jnp.einsum("bkgd,skd->bkgs", qg, ks).astype(f32)
+            s = s / jnp.sqrt(f32(Dh))
+            owner = jnp.repeat(owner, bt)
+            at = (first[:, None] + offs[None, :]).reshape(S)
+            mask = ((owner[None, :] == lanes[:, None])
+                    & (at[None, :] < limits[:, None]))[:, None, None, :]
+            s = jnp.where(mask, s, f32(-1e30))
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            # A lane with nothing in the tiles so far has m_new at the
+            # mask value, where exp(s - m_new) would read 1.
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), f32(0))
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            pv = jnp.einsum("bkgs,skd->bkgd", p.astype(q.dtype), vs,
+                            preferred_element_type=f32)
+            return m_new, l, acc * alpha[..., None] + pv
+
+        m, l, acc = lax.fori_loop(
+            0, n_tiles, fold,
+            (jnp.full((B, Kh, G), -1e30, f32),
+             jnp.zeros((B, Kh, G), f32),
+             jnp.zeros((B, Kh, G, Dh), f32)))
+        o = acc / jnp.where(l > 0, l, f32(1))[..., None]
+        return o.astype(q.dtype).reshape(B, 1, H, Dh)
+
+
 def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                   wr_o, limits, moe_capacity, attend=None, live=None,
-                  chunk: bool = False):
+                  chunk: bool = False, blocks=None):
     """Embedding and the ONE layer loop of the paged programs (decode
     step, prefill chunk, speculative verify and draft). ``banks`` is
     the cache as the model describes it (``tfm.cache_spec``: a dict of
@@ -315,7 +417,11 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     sliced out of the carry (the Pallas kernel wants one layer,
     head-major; GQA only). ``chunk``: the queries are a prefill
     chunk's, many to a table (latent attention gathers by it,
-    ``sparse_mla.attend_paged``). Returns ``(x (B, Q, D) before the
+    ``sparse_mla.attend_paged``). ``blocks``: the live rows' block list
+    (:func:`live_block_list`); given to a GQA decode step with no
+    ``attend``, the step attends over the list
+    (:func:`_live_block_attention`) and not through ``tables``, which
+    then only route the writes. Returns ``(x (B, Q, D) before the
     final norm, banks, load)``; ``load`` is a dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
     ``live`` (B, Q) marks, if given), None without one."""
@@ -335,14 +441,17 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             with jax.named_scope("kv_write"):
                 kf = kf.at[base + wr_b, wr_o].set(k)
                 vf = vf.at[base + wr_b, wr_o].set(v)
-            if attend is None:
-                o = _paged_attention_gather(q, kf, vf, base + tables,
-                                            limits, cfg)
-            else:
+            if attend is not None:
                 with jax.named_scope("attn"):
                     o = attend(
                         q, lax.dynamic_slice_in_dim(kf, base, n_blocks),
                         lax.dynamic_slice_in_dim(vf, base, n_blocks))
+            elif blocks is not None:
+                o = _live_block_attention(q, kf, vf, base, blocks,
+                                          limits)
+            else:
+                o = _paged_attention_gather(q, kf, vf, base + tables,
+                                            limits, cfg)
             return o, {"k": kf, "v": vf}
     else:
         from ptype_tpu.models import sparse_mla
@@ -388,7 +497,8 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
                       cfg: tfm.TransformerConfig, banks: dict,
                       tables: jax.Array, wr_blocks: jax.Array,
                       wr_off: jax.Array, attn_impl: str = "gather",
-                      interpret: bool | None = None, live=None):
+                      interpret: bool | None = None, live=None,
+                      blocks=None):
     """One decode step through per-sequence BLOCK TABLES — the paged
     engine step (serve_engine.PagedGeneratorActor). ``banks``: the
     cache as ``tfm.cache_spec(cfg)`` describes it, ``(L, n_blocks,
@@ -397,13 +507,19 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     writes its new cache row at ``(wr_blocks[b], wr_off[b])`` — the
     engine routes INACTIVE rows to the trash block so a masked lane can
     never scatter into a real (possibly shared) block — and attends
-    through its table: position order == table order, so greedy rows
-    match the solo :func:`generate` decode token-for-token (the
-    engine's parity bar).
+    over exactly its own positions, so greedy rows match the solo
+    :func:`generate` decode token for token (the engine's parity bar)
+    and its logits to the float32 rounding of a softmax summed in
+    another order.
 
+    ``blocks`` (``live_block_list``'s pair, GQA only): the K,V blocks
+    the live rows hold. With it the step reads those blocks and no
+    others, tile by tile (:func:`_live_block_attention`): its cost
+    follows the tokens in flight. Without it each row gathers its whole
+    table, reach and all (:func:`_paged_attention_gather`).
     ``attn_impl="kernel"`` uses the Pallas paged-attention kernel
-    (ops/paged_attention, gated behind its ``check_tpu_lowering``);
-    the default is the XLA gather path. Returns ``(logits (B, V),
+    instead of either (ops/paged_attention, gated behind its
+    ``check_tpu_lowering``). Returns ``(logits (B, V),
     banks, load)``, ``load`` as :func:`_paged_layers` gives it (of the
     rows ``live`` (B,) marks, if given)."""
     attend = None
@@ -417,7 +533,7 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     x, banks, load = _paged_layers(
         params, token[:, None], pos[:, None], cfg, banks, tables,
         wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
-        attend, None if live is None else live[:, None])
+        attend, None if live is None else live[:, None], blocks=blocks)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x[:, 0], cfg)

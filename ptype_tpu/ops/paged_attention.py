@@ -1,9 +1,11 @@
 """Paged attention — Pallas TPU decode kernel over a block table.
 
-The paged engine's default decode path is an XLA gather
-(models/generate._paged_attention_gather): it materializes the whole
-gathered (B, nb·bt, Kh, Dh) K/V per layer per step in HBM before the
-einsum reads it. This kernel skips the materialization: the block
+The paged engine's prefill chunk and speculative programs attend
+through an XLA gather (models/generate._paged_attention_gather): it
+materializes the whole gathered (B, nb·bt, Kh, Dh) K/V per layer per
+step in HBM before the einsum reads it (the default decode step reads
+only the live rows' blocks, generate._live_block_attention). This
+kernel skips the materialization: the block
 table rides **scalar prefetch** (``pltpu.PrefetchScalarGridSpec``), so
 each grid step's BlockSpec index map dials the bank block the table
 names and Mosaic DMAs exactly that (block_tokens, Dh) tile into VMEM —

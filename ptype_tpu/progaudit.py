@@ -593,10 +593,12 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
     """``serve.decode_step`` / ``serve.prefill_chunk``: the engine's
     two paged programs as it jits them, banks donated. The pool is far
     wider than a row's table (``cfg.max_seq`` tokens), so a bank
-    dominates every other temporary and the ceiling "less than one
-    bank" tells an in-place update from a copy."""
+    dominates every other temporary and the ceiling, a quarter of one
+    bank, tells an in-place update from a copy (the programs hold
+    about an eighth: activations, and the chunk's gathered table)."""
     def builder() -> AuditReport:
         import jax.numpy as jnp
+        import numpy as np
 
         from ptype_tpu.models import generate as gen
 
@@ -605,9 +607,11 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
         bank = _bank_aval(cfg, n_blocks, block_tokens)
         i32 = jnp.int32
 
-        def decode_step(params, banks, tok, pos, tables, wr_b, wr_o):
+        def decode_step(params, banks, tok, pos, tables, wr_b, wr_o,
+                        blocks):
             return gen.decode_step_banks(params, tok, pos, cfg, banks,
-                                         tables, wr_b, wr_o)[:2]
+                                         tables, wr_b, wr_o,
+                                         blocks=blocks)[:2]
 
         def prefill_chunk(params, banks, tokens, start, length, table):
             return gen.prefill_chunk_banks(
@@ -615,11 +619,17 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
 
         row = jax.ShapeDtypeStruct((B,), i32)
         scalar = jax.ShapeDtypeStruct((), i32)
+        # The live rows' block list, as the engine hands it to the
+        # step: the banks are read inside the tile loop, a second place
+        # where the compiler could take a copy of one.
+        lst, _ = gen.live_block_list(
+            np.zeros((B, nb), np.int32), np.zeros(B, np.int32),
+            np.zeros(B, bool), block_tokens)
         fn, rest = {
             "serve.decode_step": (
                 decode_step,
                 (row, row, jax.ShapeDtypeStruct((B, nb), i32), row,
-                 row)),
+                 row, (jax.ShapeDtypeStruct(lst.shape, i32), scalar))),
             "serve.prefill_chunk": (
                 prefill_chunk,
                 (jax.ShapeDtypeStruct((1, chunk), i32), scalar, scalar,
@@ -630,7 +640,7 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
         # KV pool every step.
         return audit(fn, (params_avals, {"k": bank, "v": bank}) + rest,
                      name=name, donate_argnums=(1,), expect_collectives=0,
-                     max_temp_bytes=_nbytes(bank))
+                     max_temp_bytes=_nbytes(bank) // 4)
 
     return builder
 

@@ -21,8 +21,10 @@ The host-mesh probe behind ``bench.py --serve``'s
 ``serve_prefill_stall_ms`` tail fields is ``_serve_paged_probe`` in
 the top-level ``bench.py``.
 
-The decode attention path is an XLA gather through the block table
-(``models/generate.decode_step_banks``); the optional Pallas kernel
+The decode attention path is XLA over the blocks the live rows hold
+(``models/generate.decode_step_banks`` over
+``generate.live_block_list``; the prefill chunk and the speculative
+programs gather through the block table); the optional Pallas kernel
 lives in :mod:`ptype_tpu.ops.paged_attention`, gated behind the same
 ``check_tpu_lowering`` machinery as the flash kernel.
 """

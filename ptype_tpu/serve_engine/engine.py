@@ -6,9 +6,17 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
 - **Paged decode**: one engine step decodes every live slot through
   per-sequence block tables (``models/generate.decode_step_banks``) —
   resident KV memory tracks actual token counts (pool blocks), not
-  ``n_slots × reach`` contiguous banks. Greedy rows still match their
-  solo decode token-for-token (gathered table order == position
-  order).
+  ``n_slots × reach`` contiguous banks. A GQA step's attention reads
+  the blocks its live rows hold and nothing else: the engine keeps,
+  beside the tables, a flat list of those blocks
+  (``generate.live_block_list``, rebuilt with the device mirrors on
+  admission, retire and block boundary) and the step loops over the
+  list's tiles in use, so a step costs the weights plus Σ live
+  context, whatever ``n_slots`` and the reach are — one compiled
+  program for every trip count. (Latent attention and ``attn=
+  "kernel"`` take their own paths and build no list.) Greedy rows
+  still match their solo decode token for token; logits agree to the
+  float32 rounding of a softmax accumulated tile by tile.
 - **Chunked prefill**: admission writes the prompt in bounded
   ``prefill_chunk``-token chunks INTERLEAVED with decode steps — a 4k
   prompt can no longer freeze co-batched decodes for its whole
@@ -348,7 +356,8 @@ class PagedGeneratorActor(GeneratorActor):
         self._last_stall_ms = 0.0
 
         def engine_step(sampled, params, banks, tok, pos, tables,
-                        active, keys, eidx, temps, topk, topp):
+                        active, keys, eidx, temps, topk, topp,
+                        blocks):
             B = tok.shape[0]
             bt_ = self.block_tokens
             # Write routing in-graph: inactive lanes scatter to the
@@ -361,7 +370,7 @@ class PagedGeneratorActor(GeneratorActor):
             wr_o = pos % bt_
             logits, banks, load = gen.decode_step_banks(
                 params, tok, pos, self.cfg, banks, tables, wr_b,
-                wr_o, attn_impl=self.attn, live=active)
+                wr_o, attn_impl=self.attn, live=active, blocks=blocks)
             with jax.named_scope("sample"):
                 if sampled:
                     nxt = gen.sample_token_rows(logits, keys, eidx,
@@ -389,6 +398,14 @@ class PagedGeneratorActor(GeneratorActor):
         #: authoritative and must be re-uploaded (set dirty by
         #: admission, retire, and block-boundary allocation).
         self._dev: dict | None = None
+        #: A GQA engine on the gather path hands the step the list of
+        #: blocks its live rows hold (gen.live_block_list), rebuilt
+        #: with ``_dev``: the step's attention then costs what is in
+        #: flight, not n_slots x reach. ``_kv``: the list's blocks and
+        #: tiles in use, as the dispatch span and the ledger carry
+        #: them; empty without a list (latent attention, the kernel).
+        self._live_blocks = cfg.latent is None and attn == "gather"
+        self._kv: dict = {}
 
         def sample_first(logits, key, temp, topk, topp):
             return gen.sample_token_rows(
@@ -1321,17 +1338,33 @@ class PagedGeneratorActor(GeneratorActor):
             # the same commitment or the second step of every request
             # sees a new signature and compiles again (chip run, PR 21).
             with annotate("serve.step/upload"):
+                blocks = None
+                if self._live_blocks:
+                    blocks = gen.live_block_list(
+                        self._tables, self._nalloc, self._active,
+                        self.block_tokens)
+                    self._kv = {
+                        "kv_blocks": int(
+                            self._nalloc[self._active].sum()),
+                        "kv_tiles": int(blocks[1])}
                 self._dev = jax.device_put({
                     "tok": self._tok, "pos": self._pos,
                     "tables": self._tables, "active": self._active,
                     "keys": self._keys, "eidx": self._eidx,
                     "temps": self._temps, "topk": self._topk,
-                    "topp": self._topp,
+                    "topp": self._topp, "blocks": blocks,
                 }, self.device)
         d = self._dev
         self._steps += 1
-        self._max_live = max(self._max_live, int(self._active.sum()))
-        with annotate("serve.step/dispatch"), self._lock:
+        n_live = int(self._active.sum())
+        self._max_live = max(self._max_live, n_live)
+        kv = self._kv
+        if kv:
+            self.ledger.kv_list(
+                kv["kv_blocks"], kv["kv_tiles"],
+                int(self._pos[self._active].sum()) + n_live,
+                d["blocks"][0].shape[2] * self.block_tokens)
+        with annotate("serve.step/dispatch", **kv), self._lock:
             # Armed (PTYPE_JITWATCH=1), the hot region makes any
             # unsanctioned implicit transfer into the decode step
             # raise at the call — the steady-state step re-uploads
@@ -1342,7 +1375,7 @@ class PagedGeneratorActor(GeneratorActor):
                     sampled, self.params, self.pool.banks,
                     d["tok"], d["pos"], d["tables"], d["active"],
                     d["keys"], d["eidx"], d["temps"], d["topk"],
-                    d["topp"])
+                    d["topp"], d["blocks"])
         d["tok"] = nxt
         with annotate("serve.step/fetch"):
             # The host waits for the device here.

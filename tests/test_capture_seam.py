@@ -14,6 +14,7 @@ import pytest
 from ptype_tpu import metrics as metrics_mod
 from ptype_tpu import trace
 from ptype_tpu.health.serving import ServingLedger
+from ptype_tpu.models import generate as gen
 from ptype_tpu.models import transformer as tfm
 from ptype_tpu.parallel.mesh import build_mesh
 
@@ -50,7 +51,9 @@ def _lowered(program: str) -> str:
             low = eng._engine_step.lower(
                 False, eng.params, eng.pool.banks, eng._tok, eng._pos,
                 eng._tables, eng._active, eng._keys, eng._eidx,
-                eng._temps, eng._topk, eng._topp)
+                eng._temps, eng._topk, eng._topp,
+                gen.live_block_list(eng._tables, eng._nalloc,
+                                    eng._active, eng.block_tokens))
         else:
             low = eng._chunk_prog(16).lower(
                 eng.params, eng.pool.banks,

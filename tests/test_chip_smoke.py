@@ -56,6 +56,7 @@ def test_kernels_phase(cluster):
         width_preset="tiny", prefill_len=40)
     assert set(out["flash"]) == {"mha", "gqa"}
     assert out["paged"]["serve"] < 2e-2
+    assert out["paged_decode_blocks_err"] < 2e-2
 
 
 #: One Mosaic custom call as a compiled v5e module prints it (chip run,

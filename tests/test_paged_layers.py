@@ -29,7 +29,7 @@ def _noise_banks(seed):
             jax.random.normal(k2, shape, jnp.float32))
 
 
-@pytest.mark.parametrize("attn_impl", ["gather", "kernel"])
+@pytest.mark.parametrize("attn_impl", ["gather", "kernel", "blocks"])
 def test_paged_programs_match_contiguous_greedy(attn_impl):
     """Two rows that SHARE their first block and a third, inactive
     lane routed to the trash block, through ``prefill_chunk_banks``
@@ -39,7 +39,10 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
     mostly echoes its input, so tokens alone would forgive a layer
     that read another layer's rows), the shared block is written once
     and never again, and no block outside the rows' tables (and the
-    trash block) changes in any layer."""
+    trash block) changes in any layer. ``"blocks"``: the decode step
+    attends over the live rows' block list (ISSUE 29), rebuilt as the
+    engine rebuilds it, in tiles of two blocks: rows A and B come to
+    three tiles once both hold two blocks."""
     params = tfm.init_params(jax.random.PRNGKey(0), CFG3)
     rng = np.random.default_rng(3)
     shared = rng.integers(1, CFG3.vocab_size, BT)
@@ -70,14 +73,24 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
     lg_b, kb, vb = prefill(kb, vb, 1, BT)
 
     @jax.jit
-    def step(kb, vb, tok, pos, active):
+    def step(kb, vb, tok, pos, active, blocks=None):
         wr_b = jnp.where(active, tables[jnp.arange(3), pos // BT], 0)
         lg, banks, _ = gen.decode_step_banks(
             params, tok, pos, CFG3, {"k": kb, "v": vb}, tables, wr_b,
-            pos % BT, attn_impl=attn_impl)
+            pos % BT, blocks=blocks,
+            attn_impl="gather" if attn_impl == "blocks" else attn_impl)
         kb, vb = banks["k"], banks["v"]
         nxt = jnp.where(active, jnp.argmax(lg, -1).astype(jnp.int32), 0)
         return kb, vb, lg, nxt, jnp.where(active, pos + 1, pos)
+
+    if attn_impl == "blocks":
+        plain_step = step
+
+        def step(kb, vb, tok, pos, active):
+            blocks = gen.live_block_list(
+                np.asarray(tables), np.asarray(pos) // BT + 1,
+                np.asarray(active), BT, tile=2)
+            return plain_step(kb, vb, tok, pos, active, blocks)
 
     active = jnp.asarray([True, True, False])
     logits = [np.stack([lg_a, lg_b, lg_b])]
@@ -113,6 +126,82 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
         untouched = [b for b in range(N_BLOCKS) if b not in (0, 3, 5, 7)]
         np.testing.assert_array_equal(got[:, untouched],
                                       np.asarray(was)[:, untouched])
+
+
+def _rows(lengths, nb, shared=0, seed=0):
+    """Tables for rows of ``lengths`` tokens (0: an inactive lane) over
+    distinct blocks in shuffled order, the first ``shared`` blocks of
+    every live row being row 0's."""
+    rng = np.random.default_rng(seed)
+    lengths = np.asarray(lengths)
+    nalloc = -(-lengths // BT)
+    free = list(rng.permutation(np.arange(1, 1 + int(nalloc.sum()))))
+    tables = np.zeros((len(lengths), nb), np.int32)
+    for b, n in enumerate(nalloc):
+        tables[b, :n] = [free.pop() for _ in range(n)]
+        tables[b, :min(shared, n)] = tables[0, :min(shared, n)]
+    return tables, nalloc.astype(np.int32), lengths > 0
+
+
+LIVE_CASES = {
+    # name: (row lengths, blocks of reach, shared blocks, tile)
+    "ragged-len1-fullreach-inactive": ((1, 6 * BT, 37, 0, 50), 6, 0, 4),
+    "shared-first-blocks": ((3 * BT + 5, 2 * BT + 1, 2 * BT), 6, 2, 4),
+    "ends-on-a-tile-boundary": ((2 * BT, BT + 3, 4 * BT), 4, 0, 4),
+    "spans-three-tiles": ((3 * BT + 1, 2 * BT, BT + 9, 7), 4, 0, 4),
+    "one-block-a-tile": ((BT + 1, 5), 2, 0, 1),
+    "one-tile-holds-all": ((40, 0, 17), 4, 0, None),
+}
+
+
+@pytest.mark.parametrize("extra_tiles", [0, 2])
+@pytest.mark.parametrize("case", list(LIVE_CASES))
+def test_live_block_attention_matches_the_table_gather(case,
+                                                       extra_tiles):
+    """``_live_block_attention`` over ``live_block_list``'s list
+    against ``_paged_attention_gather`` through the tables, float32 to
+    1e-5, in one compiled program whatever the trip count. The list
+    names each live row's allocated blocks once, in row order, and
+    nothing else; tiles asked beyond those filled (``extra_tiles``:
+    all padding, the trash block under an owner no lane has) change
+    nothing; an inactive lane reads zeros."""
+    lengths, nb, shared, tile = LIVE_CASES[case]
+    tables, nalloc, active = _rows(lengths, nb, shared)
+    B, Kh, G, Dh = len(lengths), 2, 2, 8
+    n_blocks = 1 + int(nalloc.sum())
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
+    kc = jax.random.normal(k1, (n_blocks, BT, Kh, Dh), jnp.float32)
+    vc = jax.random.normal(k2, (n_blocks, BT, Kh, Dh), jnp.float32)
+    q = jax.random.normal(k3, (B, 1, Kh * G, Dh), jnp.float32)
+    limits = jnp.asarray(np.maximum(lengths, 1), jnp.int32)
+
+    lst, n_tiles = gen.live_block_list(tables, nalloc, active, BT,
+                                       tile=tile)
+    width = lst.shape[2]
+    n = int(nalloc[active].sum())
+    assert lst.shape[1] * width >= B * nb
+    assert int(n_tiles) == -(-n // width)
+    if case == "ends-on-a-tile-boundary":
+        assert n == int(n_tiles) * width
+    if case == "spans-three-tiles":
+        assert int(n_tiles) == 3
+    ids, owner, first = lst.reshape(3, -1)
+    want = [(int(tables[b, j]), b, j * BT) for b in range(B)
+            if active[b] for j in range(nalloc[b])]
+    assert list(zip(ids[:n], owner[:n], first[:n])) == want
+    assert (ids[n:] == 0).all() and (owner[n:] == B).all()
+
+    attend = jax.jit(lambda q, kc, vc, lst, n_tiles, limits:
+                     gen._live_block_attention(q, kc, vc, 0,
+                                               (lst, n_tiles), limits))
+    asked = jnp.int32(min(int(n_tiles) + extra_tiles, lst.shape[1]))
+    got = np.asarray(attend(q, kc, vc, jnp.asarray(lst), asked, limits))
+    ref = np.asarray(gen._paged_attention_gather(
+        q, kc, vc, jnp.asarray(tables), limits, None))
+    np.testing.assert_allclose(got[active], ref[active], rtol=1e-5,
+                               atol=1e-5)
+    assert (got[~active] == 0).all()
+    assert attend._cache_size() == 1
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "verify",

@@ -203,8 +203,10 @@ def test_real_hot_programs_audit_clean():
     assert win.collectives == {} and dec.collectives == {}
     pre = reports["serve.prefill_chunk"]
     assert pre.donated_consumed == pre.donated_expected == 2
-    # The paged programs update their donated banks in place: all the
-    # compiled temporaries together come to less than one bank.
+    # The paged programs update their donated banks in place, and the
+    # decode step's tile loop only reads them (ISSUE 29): all the
+    # compiled temporaries together come to less than a quarter of one
+    # bank (the window's: one draft bank).
     for rep in (dec, pre, win):
         assert 0 < rep.temp_bytes < rep.max_temp_bytes, rep.to_dict()
 

@@ -137,6 +137,106 @@ def test_paged_engine_matches_contiguous_greedy_token_for_token():
         actor.close()
 
 
+def test_decode_attends_over_the_live_rows_blocks(monkeypatch,
+                                                  jitwatch_watchdog):
+    """ISSUE 29: rows are admitted, cross block boundaries and retire
+    while others decode (tiles of two blocks, so the step's trip count
+    walks from 1 up). Greedy tokens equal solo ``generate()`` token for
+    token; after EVERY rebuild the list names exactly the live rows'
+    allocated blocks, row after row, under their lanes and first
+    positions; and the step compiled once for all the trip counts."""
+    jw = jitwatch_watchdog
+    monkeypatch.setattr(gen, "LIVE_TILE_BLOCKS", 2)
+    build, seen, wrong = gen.live_block_list, [], []
+    holder = {}
+
+    def checked(tables, nalloc, active, bt, tile=None):
+        lst, n_tiles = build(tables, nalloc, active, bt, tile)
+        eng = holder["actor"]
+        want = [(bid, slot, j * bt)
+                for slot in sorted(eng._slot_state) if active[slot]
+                for j, bid in enumerate(eng._slot_state[slot].table)]
+        ids, owner, first = lst.reshape(3, -1)
+        got = list(zip(ids[:len(want)], owner[:len(want)],
+                       first[:len(want)]))
+        if (got != want or (owner[len(want):] != eng.n_slots).any()
+                or (ids[len(want):] != 0).any()
+                or int(n_tiles) != -(-len(want) // lst.shape[2])):
+            wrong.append((want, lst, n_tiles))
+        seen.append(int(n_tiles))
+        return lst, n_tiles
+
+    monkeypatch.setattr(gen, "live_block_list", checked)
+    before = jw.compiles().get("engine_step", 0)
+    actor = holder["actor"] = PagedGeneratorActor(
+        CFG, n_slots=4, block_tokens=16, prefill_chunk=24)
+    try:
+        solo = _prompt(4)
+        np.testing.assert_array_equal(       # one row, one block: 1 tile
+            np.asarray(actor.Generate(solo, 5)),
+            np.asarray(gen.generate(actor.params, CFG, solo, 5)))
+        lens = (3, 14, 30, 33, 12, 47)   # 14+9, 30+7, 12+10, 47+6 cross
+        news = (6, 9, 7, 16, 10, 6)      # a block boundary decoding
+        prompts = [_prompt(n) for n in lens]
+        outs = [None] * len(prompts)
+
+        def call(i):
+            time.sleep(0.04 * (i % 3))
+            outs[i] = actor.Generate(prompts[i], news[i])
+
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        for i, p in enumerate(prompts):
+            np.testing.assert_array_equal(
+                np.asarray(outs[i]),
+                np.asarray(gen.generate(actor.params, CFG, p, news[i])),
+                err_msg=f"req {i}")
+        assert not wrong, wrong[0]
+        assert actor.Info()["max_live_slots"] >= 3
+        assert 1 in seen and max(seen) >= 4, sorted(set(seen))
+        assert jw.compiles()["engine_step"] - before == 1, jw.compiles()
+        assert actor.pool.check_invariants() == []
+    finally:
+        actor.close()
+
+
+def test_ledger_counts_the_block_lists_tiles():
+    """One short row: every decode step runs one tile over one listed
+    block, and ``kv_tile_fill`` is the tokens attended over the tokens
+    the tiles covered. The dispatch span carries the two counts. An
+    engine with no list (the kernel) reports none."""
+    from ptype_tpu import trace
+
+    rec = trace.enable("kv-list-test")
+    actor = PagedGeneratorActor(CFG, n_slots=2, block_tokens=16)
+    try:
+        actor.Generate(_prompt(5), 8)    # 7 decode steps at pos 5..11
+        summ = actor.ledger.summary()
+        covered = 2 * actor.nb * 16      # the one tile: every lane's reach
+        assert summ["kv_blocks"] == 1.0 and summ["kv_tiles"] == 1.0
+        assert summ["kv_tile_fill"] == round(
+            sum(range(6, 13)) / (7 * covered), 4)
+        spans = [s for s in rec.spans()
+                 if s.name == "serve.step/dispatch"]
+        assert len(spans) == 7
+        assert all(s.attrs == {"kv_blocks": 1, "kv_tiles": 1}
+                   for s in spans)
+    finally:
+        trace.disable()
+        actor.close()
+    kernel = PagedGeneratorActor(CFG, n_slots=2, block_tokens=16,
+                                 attn="kernel")
+    try:
+        kernel.Generate(_prompt(5), 4)
+        assert "kv_tiles" not in kernel.ledger.summary()
+    finally:
+        kernel.close()
+
+
 def test_sampled_single_row_rides_engine_with_exact_solo_parity():
     """The sampling satellite: temperature/top-k/top-p single-row
     requests ride the CONTINUOUS path (per-slot RNG keys folded into
