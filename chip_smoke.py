@@ -560,33 +560,6 @@ def _flash_case(name, B, S, H, K, Dh, **blocks) -> dict:
     return {k_: round(e, 5) for k_, e in errs.items()}
 
 
-def _paged_case(B, H, Kh, Dh, bt, nb, n_blocks) -> float:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ptype_tpu.models.generate import _paged_attention_gather
-    from ptype_tpu.ops.paged_attention import KERNEL_NAME, paged_attention
-
-    ks = jax.random.split(jax.random.PRNGKey(1), 3)
-    q = jax.random.normal(ks[0], (B, 1, H, Dh), jnp.bfloat16)
-    kc = jax.random.normal(ks[1], (n_blocks, bt, Kh, Dh), jnp.bfloat16)
-    vc = jax.random.normal(ks[2], (n_blocks, bt, Kh, Dh), jnp.bfloat16)
-    rng = np.random.default_rng(0)
-    tables = jnp.asarray(
-        rng.permutation(n_blocks - 1)[:B * nb].reshape(B, nb) + 1,
-        jnp.int32)
-    pos = jnp.asarray(rng.integers(0, nb * bt, B), jnp.int32)
-    kernel = jax.jit(paged_attention)
-    check_kernels(kernel, (q, kc, vc, tables, pos), (KERNEL_NAME,), B)
-    got = kernel(q, kc, vc, tables, pos)
-    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    want = jax.jit(lambda q, kc, vc, t, lim: _paged_attention_gather(
-        q, kc, vc, t, lim, None))(f32(q), f32(kc), f32(vc), tables,
-                                  pos + 1)
-    return _close(got, want, f"paged attention B={B} H={H} K={Kh} bt={bt}")
-
-
 def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
                   flash_blocks: dict | None = None,
                   width_preset: str = PRESET, prefill_len: int = 200
@@ -594,8 +567,8 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     """Every Pallas kernel in ``ptype_tpu/ops`` against its float32
     reference, and the lowerings no TPU compiler had seen: the flash
     prefill (an unaligned prompt, so the pad path runs), the engine's
-    ``attn="kernel"`` decode step, the MoE train step, ragged and MoE
-    generate."""
+    decode step over the live rows' block list, the MoE train step,
+    ragged and MoE generate."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -603,7 +576,6 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     from ptype_tpu.models import generate as gen
     from ptype_tpu.models import transformer as tfm
     from ptype_tpu.ops.flash_attention import KERNEL_NAMES
-    from ptype_tpu.ops.paged_attention import KERNEL_NAME
     from ptype_tpu.serve_engine.engine import PagedGeneratorActor
     from ptype_tpu.train.trainer import Trainer
 
@@ -612,13 +584,13 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
         out["flash"][name] = _flash_case(name, B, S, H, K, Dh,
                                          **(flash_blocks or {}))
 
-    # The model's full width at cut depth: prefill and the paged decode
-    # step, kernel path against the dense/gather path on one set of
-    # params.
+    # The model's full width at cut depth: prefill, kernel path against
+    # the dense path, and the paged decode step, block list against the
+    # tables, on one set of params.
     wide = tfm.preset(width_preset, n_layers=2)
     params = jax.jit(lambda r: tfm.init_params(r, wide))(
         jax.random.PRNGKey(2))
-    H, Kh, Dh = wide.n_heads, wide.kv_heads, wide.head_dim
+    Kh, Dh = wide.kv_heads, wide.head_dim
 
     prompt = jax.random.randint(jax.random.PRNGKey(3), (2, prefill_len),
                                 0, wide.vocab_size, jnp.int32)
@@ -638,12 +610,6 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     n_slots, bt = 8, 16
     nb = wide.max_seq // bt
     n_blocks = n_slots * nb + 1
-    out["paged"] = {
-        "serve": round(_paged_case(n_slots, H, Kh, Dh, bt, nb, n_blocks),
-                       5),
-        "gqa-g4": round(_paged_case(n_slots, 8, 2, Dh, bt, nb, n_blocks),
-                        5),
-    }
     kb = jax.random.normal(
         jax.random.PRNGKey(4), (2, n_blocks, bt, Kh, Dh), wide.dtype)
     vb = jax.random.normal(
@@ -656,26 +622,16 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     tok = jnp.asarray(rng.integers(0, wide.vocab_size, n_slots), jnp.int32)
     wr_b = tables[jnp.arange(n_slots), pos // bt]
 
-    def decode(impl):
-        return jax.jit(lambda p, kb, vb: gen.decode_step_banks(
-            p, tok, pos, wide, {"k": kb, "v": vb}, tables, wr_b,
-            pos % bt, attn_impl=impl)[0])
-
-    check_kernels(decode("kernel"), (params, kb, vb), (KERNEL_NAME,),
-                  n_slots)
-    out["paged_decode_step_err"] = round(_close(
-        decode("kernel")(params, kb, vb), decode("gather")(params, kb, vb),
-        'decode_step_banks attn="kernel" vs "gather" logits'), 5)
-    # The engine's default step: over the live rows' block list, against
-    # the same step through the tables.
+    # The engine's step: over the live rows' block list, against the
+    # same step through the tables.
     blocks = gen.live_block_list(
         np.asarray(tables), np.asarray(pos) // bt + 1,
         np.ones(n_slots, bool), bt)
-    listed = jax.jit(lambda p, kb, vb, blocks: gen.decode_step_banks(
+    decode = jax.jit(lambda p, kb, vb, blocks: gen.decode_step_banks(
         p, tok, pos, wide, {"k": kb, "v": vb}, tables, wr_b, pos % bt,
         blocks=blocks)[0])
     out["paged_decode_blocks_err"] = round(_close(
-        listed(params, kb, vb, blocks), decode("gather")(params, kb, vb),
+        decode(params, kb, vb, blocks), decode(params, kb, vb, None),
         "decode_step_banks over the block list vs the tables, logits"),
         5)
 

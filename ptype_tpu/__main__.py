@@ -7,14 +7,14 @@ operations need. Commands:
 - ``info``   — devices, mesh axes from config (if any), native wire
 - ``join``   — join the cluster described by $CONFIG and idle (a seed
                or bare member; ^C to leave)
-- ``serve``  — join + serve a GeneratorActor ($PRESET, default tiny)
+- ``serve``  — join + serve a warmed PagedGeneratorActor ($PRESET,
+               default tiny; $SERVE_SLOTS live rows, default 8)
 - ``train``  — join + train ($PRESET/$STEPS/$BATCH/$SEQ/$MODE as in
                examples/optimus/trainer.py; $CKPT_DIR/$CKPT_EVERY for
                save/resume, $COMPRESS for store-mode grad wire)
 - ``eval``   — held-out loss/perplexity of a checkpoint ($CKPT_DIR;
                $PRESET/$BATCH/$SEQ/$EVAL_STEPS; $CORPUS points at a raw
                token file, else a fixed synthetic stream)
-- ``bench``  — the headline one-line JSON benchmark
 - ``standby`` — warm-standby coordinator: probe the seed, take over on
                failure ($STANDBY_ADDR to listen on; the platform
                config supplies coordinator_address + data_dir;
@@ -144,39 +144,33 @@ def _join() -> None:
         cluster.close()
 
 
-def _serve() -> None:
+def _serve_replica():
+    """The replica ``serve`` fronts: built and warmed by the factory
+    the reconciler's spawned workers use, so an operator-launched
+    replica and an autoscaled one are the same thing. $PRESET names
+    the model; $SERVE_SLOTS (read by the factory) its live rows."""
     import os
 
+    from ptype_tpu.reconciler import worker
+
+    make, warmup = worker._actor_factory(
+        "paged", os.environ.get("PRESET", "tiny"))
+    actor = make()
+    warmup(actor)
+    return actor
+
+
+def _serve() -> None:
     from ptype_tpu import compile_cache, config_from_env, join
-    from ptype_tpu.models import transformer as tfm
     # Replica lifecycle has ONE home (lint PT012): the server that
     # fronts a serving replica is constructed by reconciler/replica.py
     # — the same code path the elastic reconciler's spawned workers
-    # use, so an operator-launched replica and an autoscaled one are
-    # the same thing.
+    # use.
     from ptype_tpu.reconciler.replica import serve_actor
-    from ptype_tpu.serve import BatchingGeneratorActor
 
     compile_cache.configure()
     cfg = config_from_env()
-    model_cfg = tfm.preset(os.environ.get("PRESET", "tiny"))
-    # $SERVE_MODE=continuous: slot-based continuous batching (requests
-    # join/leave the one running decode loop at step boundaries;
-    # $SERVE_SLOTS caches). Default: dynamic batching — concurrent
-    # greedy requests coalesce into one decode round
-    # ($SERVE_WINDOW_MS/$SERVE_MAX_BATCH to tune). Sampled requests
-    # run solo in both modes.
-    if os.environ.get("SERVE_MODE") == "continuous":
-        from ptype_tpu.serve import ContinuousGeneratorActor
-
-        actor = ContinuousGeneratorActor(
-            model_cfg,
-            n_slots=int(os.environ.get("SERVE_SLOTS", "8")))
-    else:
-        actor = BatchingGeneratorActor(
-            model_cfg,
-            window_ms=float(os.environ.get("SERVE_WINDOW_MS", "5")),
-            max_batch=int(os.environ.get("SERVE_MAX_BATCH", "32")))
+    actor = _serve_replica()
     server = serve_actor(actor, "Generator", port=cfg.port)
     cfg.port = server.port
     cluster = join(cfg)
@@ -189,6 +183,7 @@ def _serve() -> None:
     finally:
         cluster.close()
         server.close()
+        actor.close()
 
 
 def _train() -> None:
@@ -254,19 +249,6 @@ def _eval() -> None:
     out = tr.evaluate(stream, steps)
     print(_json.dumps({"checkpoint_step": step, "eval_steps": steps,
                        "batch": batch, "seq": seq, **out}))
-
-
-def _bench() -> None:
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench",
-        os.path.join(os.path.dirname(__file__), "..", "bench.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.main()
 
 
 def _standby() -> None:
@@ -578,7 +560,6 @@ COMMANDS = {
     "serve": _serve,
     "train": _train,
     "eval": _eval,
-    "bench": _bench,
     "standby": _standby,
     "witness": _witness,
     "obs": _obs,

@@ -59,8 +59,8 @@ class GatewayConfig:
     #: Deadline applied when the caller passes none.
     default_deadline_s: float = 30.0
     #: Concurrent dispatches allowed per healthy replica. 1 matches the
-    #: lock-serialized GeneratorActor; raise it for the batching /
-    #: continuous engines, which turn concurrency into batch occupancy.
+    #: lock-serialized GeneratorActor; raise it for the paged engine,
+    #: which turns concurrency into batch occupancy.
     per_replica_inflight: int = 1
     #: Active health probe cadence / budget (Info round-trips).
     probe_interval_s: float = 1.0

@@ -433,8 +433,8 @@ def measure_forensics_overhead(iters: int = 20000) -> dict:
     measured the way every observability probe here is (tight loop over
     the real calls, never a wall-clock A/B): ``Histogram.observe`` with
     a trace id racing the replace-min exemplar slots vs the same
-    observe with the seam cold.  ``bench.py --forensics`` divides by
-    the engine-iteration wall for the <=1% bar."""
+    observe with the seam cold (tests/test_forensics.py reads it;
+    divide by the engine-iteration wall for the <=1% bar)."""
     import time as _time
 
     from ptype_tpu import metrics as metrics_mod

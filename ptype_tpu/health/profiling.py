@@ -534,10 +534,9 @@ def transformer_grads_cost(cfg, batch: int, seq: int,
 
 def measure_compiled_cost(preset: str = "optimus-125m", batch: int = 8,
                           seq: int = 128) -> dict:
-    """Compiled-vs-analytic FLOPs on one config — the bench probe
-    behind ``compiled_flops_per_token`` and the ISSUE 8 acceptance
-    check (``mfu_compiled`` within 10% of analytic MFU on the 125M
-    CPU-mesh config, gap REPORTED either way). MFU shares the
+    """Compiled-vs-analytic FLOPs on one config — the ISSUE 8
+    acceptance check (``mfu_compiled`` within 10% of analytic MFU on
+    the 125M CPU-mesh config, gap REPORTED either way). MFU shares the
     wall-clock and peak factors, so the MFU gap IS the FLOPs gap."""
     from ptype_tpu.models import transformer as tfm
 
@@ -619,99 +618,3 @@ def render_hbm_table(memory: dict) -> str:
     if host.get("rss_bytes"):
         lines.append(f"  host rss: {host['rss_bytes'] / 2**20:.1f} MiB")
     return "\n".join(lines)
-
-
-# ----------------------------------------------------------- bench probe
-
-
-def measure_profile_overhead(steps: int = 12, preset: str = "tiny",
-                             batch: int = 8, seq: int = 32) -> dict:
-    """Capture-disabled cost of the profiling plane on the host-mesh
-    store-DP loop — the bench.py ``profile_overhead_pct`` probe and
-    the ISSUE 8 acceptance bar (<1%).
-
-    What "armed but not capturing" adds to a step: nothing in the step
-    path checks the profiler (the endpoint is pull-only), so the whole
-    idle cost is the goodput ledger's ``mfu_compiled`` arithmetic in
-    its step-close — costed DIRECTLY (observe("train.step") with
-    compiled flops set, microseconds against a step of tens of
-    milliseconds; same method as ``telemetry.measure_trace_overhead``
-    — a wall-clock A/B on a shared host reports scheduler noise, not
-    this signal). The interleaved armed/bare wall clocks ride along
-    for transparency, and one short LIVE capture is costed separately
-    (``capture_step_ms`` — the price of actually profiling, which is
-    allowed to be visible)."""
-    from ptype_tpu import metrics as metrics_mod
-    from ptype_tpu.health import goodput as goodput_mod
-    from ptype_tpu.models import transformer as tfm
-    from ptype_tpu.parallel.mesh import build_mesh
-    from ptype_tpu.parallel.topology import DATA_AXIS
-    from ptype_tpu.parallel.tensorstore import TensorStore
-    from ptype_tpu.train.data import synthetic_batches
-    from ptype_tpu.train.store_dp import StoreDPTrainer
-
-    mesh = build_mesh({DATA_AXIS: jax.device_count()})
-    cfg = tfm.preset(preset)
-    trainer = StoreDPTrainer(cfg, TensorStore(mesh))
-    stream = synthetic_batches(cfg.vocab_size, batch, seq)
-    trainer.step(next(stream))  # compile outside every measurement
-
-    cost = trainer.compiled_cost()
-    ledger = goodput_mod.GoodputLedger(
-        registry=metrics_mod.MetricsRegistry(),
-        tokens_per_step=batch * seq,
-        flops_per_token=tfm.flops_per_token(cfg, seq))
-    ledger.set_compiled_flops(cost["flops"])
-
-    # Interleaved armed/bare arms, per-arm MIN (robust to load spikes).
-    t_on: list[float] = []
-    t_off: list[float] = []
-    for i in range(2 * steps):
-        armed = bool(i % 2)
-        if armed:
-            ledger.install()
-        else:
-            ledger.uninstall()
-        t0 = time.perf_counter()
-        trainer.step(next(stream))
-        (t_on if armed else t_off).append(time.perf_counter() - t0)
-    ledger.uninstall()
-    step_s = min(t_off)
-
-    # The idle cost, costed directly: one ledger step-close (with the
-    # mfu_compiled arithmetic live) per step.
-    probe = goodput_mod.GoodputLedger(
-        registry=metrics_mod.MetricsRegistry(),
-        tokens_per_step=batch * seq,
-        flops_per_token=tfm.flops_per_token(cfg, seq))
-    probe.set_compiled_flops(cost["flops"])
-    n = 5_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        probe.observe("train.step", step_s)
-    close_s = (time.perf_counter() - t0) / n
-
-    # The price of actually capturing (informational, not the bar).
-    start(label="bench-profile-overhead")
-    t0 = time.perf_counter()
-    for _ in range(2):
-        trainer.step(next(stream))
-    capture_step_s = (time.perf_counter() - t0) / 2
-    captured = stop()
-
-    mfu_gap = None
-    rec = probe.records()
-    if rec and "mfu_gap_pct" in rec[-1]:
-        mfu_gap = rec[-1]["mfu_gap_pct"]
-    return {
-        "bare_step_ms": round(step_s * 1e3, 2),
-        "armed_step_ms": round(min(t_on) * 1e3, 2),
-        "capture_step_ms": round(capture_step_s * 1e3, 2),
-        "ledger_close_us": round(close_s * 1e6, 2),
-        "profile_overhead_pct": round(100.0 * close_s / step_s, 4),
-        "capture_artifact_files": len(captured["files"]),
-        "compiled_flops_per_token": round(
-            cost["flops"] / cost["tokens_per_step"], 1),
-        "mfu_gap_pct": mfu_gap,
-        "steps": steps,
-    }

@@ -49,9 +49,9 @@ Timer discipline: lint rule PT010 bars raw ``time.perf_counter()`` /
 engine needs comes from a seam on this ledger (``enqueued`` /
 ``head_refused`` / ``admitted`` / ``chunk`` / ``first_token`` /
 ``tokens_emitted`` / ``iteration`` / ``retired``), so latency math has
-exactly one home and the bench can cost it
-(:func:`measure_seam_cost_us` backs ``serving_ledger_overhead_pct``
-in ``bench.py --serve``'s tail, the <1%-per-engine-iteration bar).
+exactly one home and a probe can cost it
+(:func:`measure_seam_cost_us`, against the <1%-per-engine-iteration
+bar).
 """
 
 from __future__ import annotations
@@ -766,9 +766,9 @@ def measure_seam_cost_us(iters: int = 5000) -> dict:
     (``profile_overhead_pct``): a tight loop over the real calls,
     because the signal is microseconds against a multi-millisecond
     engine step and a wall-clock A/B on a shared host reports
-    scheduler jitter, not the seam. ``bench.py --serve`` divides this
-    by the measured engine-iteration time for
-    ``serving_ledger_overhead_pct`` (<1% bar, reported not asserted).
+    scheduler jitter, not the seam. Divided by an engine-iteration
+    time it is the ledger's overhead (<1% bar);
+    tests/test_serving_obs.py reads it.
     """
     led = ServingLedger(registry=metrics_mod.MetricsRegistry())
     rec = led.enqueued(8, 8)

@@ -25,8 +25,9 @@ Derived curves:
   menu of error budgets (burn multiple = shed_rate / budget): how
   long the budget survives at this offered load.
 - Scale-up-latency vs burst steepness is a fleet drill, not ledger
-  math — ``bench.py --traffic`` runs it with the reconciler wired
-  (see docs/OPERATIONS.md "Capacity planning").
+  math: drive it with the reconciler wired, as
+  tests/test_loadgen_drills.py drives the diurnal spike (see
+  docs/OPERATIONS.md "Capacity planning").
 """
 
 from __future__ import annotations

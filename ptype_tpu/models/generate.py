@@ -393,7 +393,7 @@ def _live_block_attention(q, kf, vf, base, blocks, limits):
 
 
 def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
-                  wr_o, limits, moe_capacity, attend=None, live=None,
+                  wr_o, limits, moe_capacity, live=None,
                   chunk: bool = False, blocks=None):
     """Embedding and the ONE layer loop of the paged programs (decode
     step, prefill chunk, speculative verify and draft). ``banks`` is
@@ -412,14 +412,11 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     ``tokens``/``positions``/``wr_b``/``wr_o`` (B, Q): each position's
     cache row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name
     the trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q))
-    as :func:`_paged_attention_gather` takes them. ``attend(q, kc,
-    vc)``, if given, replaces the gather path on the layer's own bank,
-    sliced out of the carry (the Pallas kernel wants one layer,
-    head-major; GQA only). ``chunk``: the queries are a prefill
-    chunk's, many to a table (latent attention gathers by it,
-    ``sparse_mla.attend_paged``). ``blocks``: the live rows' block list
-    (:func:`live_block_list`); given to a GQA decode step with no
-    ``attend``, the step attends over the list
+    as :func:`_paged_attention_gather` takes them. ``chunk``: the
+    queries are a prefill chunk's, many to a table (latent attention
+    gathers by it, ``sparse_mla.attend_paged``). ``blocks``: the live
+    rows' block list (:func:`live_block_list`); given to a GQA decode
+    step, the step attends over the list
     (:func:`_live_block_attention`) and not through ``tables``, which
     then only route the writes. Returns ``(x (B, Q, D) before the
     final norm, banks, load)``; ``load`` is a dropless router's counts
@@ -441,12 +438,7 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             with jax.named_scope("kv_write"):
                 kf = kf.at[base + wr_b, wr_o].set(k)
                 vf = vf.at[base + wr_b, wr_o].set(v)
-            if attend is not None:
-                with jax.named_scope("attn"):
-                    o = attend(
-                        q, lax.dynamic_slice_in_dim(kf, base, n_blocks),
-                        lax.dynamic_slice_in_dim(vf, base, n_blocks))
-            elif blocks is not None:
+            if blocks is not None:
                 o = _live_block_attention(q, kf, vf, base, blocks,
                                           limits)
             else:
@@ -455,11 +447,6 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             return o, {"k": kf, "v": vf}
     else:
         from ptype_tpu.models import sparse_mla
-
-        if attend is not None:
-            raise ValueError("the paged-attention kernel reads K and V "
-                             "per head; latent attention takes the "
-                             "gather path")
 
         def attention(x, bf, layer, base):
             q_nope, q_rope, ckv, qi, ki, wi = sparse_mla.project(
@@ -496,9 +483,7 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
 def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
                       cfg: tfm.TransformerConfig, banks: dict,
                       tables: jax.Array, wr_blocks: jax.Array,
-                      wr_off: jax.Array, attn_impl: str = "gather",
-                      interpret: bool | None = None, live=None,
-                      blocks=None):
+                      wr_off: jax.Array, live=None, blocks=None):
     """One decode step through per-sequence BLOCK TABLES — the paged
     engine step (serve_engine.PagedGeneratorActor). ``banks``: the
     cache as ``tfm.cache_spec(cfg)`` describes it, ``(L, n_blocks,
@@ -516,24 +501,13 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     the live rows hold. With it the step reads those blocks and no
     others, tile by tile (:func:`_live_block_attention`): its cost
     follows the tokens in flight. Without it each row gathers its whole
-    table, reach and all (:func:`_paged_attention_gather`).
-    ``attn_impl="kernel"`` uses the Pallas paged-attention kernel
-    instead of either (ops/paged_attention, gated behind its
-    ``check_tpu_lowering``). Returns ``(logits (B, V),
-    banks, load)``, ``load`` as :func:`_paged_layers` gives it (of the
-    rows ``live`` (B,) marks, if given)."""
-    attend = None
-    if attn_impl == "kernel":
-        from ptype_tpu.ops.paged_attention import paged_attention
-
-        def attend(q, kc, vc):
-            return paged_attention(q, kc, vc, tables, pos,
-                                   interpret=interpret)
-
+    table, reach and all (:func:`_paged_attention_gather`). Returns
+    ``(logits (B, V), banks, load)``, ``load`` as :func:`_paged_layers`
+    gives it (of the rows ``live`` (B,) marks, if given)."""
     x, banks, load = _paged_layers(
         params, token[:, None], pos[:, None], cfg, banks, tables,
         wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
-        attend, None if live is None else live[:, None], blocks=blocks)
+        None if live is None else live[:, None], blocks=blocks)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x[:, 0], cfg)

@@ -12,6 +12,9 @@ zero code changes):
 ``PTYPE_REPLICA_KIND``     ``fake`` | ``paged`` | ``custom``
                            (default ``paged``)
 ``PTYPE_REPLICA_PRESET``   model preset for ``paged`` (default tiny)
+``SERVE_SLOTS``            live rows of a ``paged`` engine (default 8;
+                           the same variable ``python -m ptype_tpu
+                           serve`` reads, through this factory)
 ``PTYPE_REPLICA_FACTORY``  for ``custom``: ``module:function`` whose
                            call builds the actor — trainer replicas
                            and future engines ride the same
@@ -71,9 +74,10 @@ def _actor_factory(kind: str, preset: str):
 
             serve_class = os.environ.get("PTYPE_REPLICA_SERVE_CLASS",
                                          "unified")
-            return PagedGeneratorActor(tfm.preset(preset),
-                                       serve_class=serve_class,
-                                       device=device)
+            return PagedGeneratorActor(
+                tfm.preset(preset),
+                n_slots=int(os.environ.get("SERVE_SLOTS", "8")),
+                serve_class=serve_class, device=device)
 
         def warmup(actor):
             import jax.numpy as jnp

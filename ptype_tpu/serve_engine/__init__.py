@@ -16,17 +16,10 @@ counts instead of ``n_slots × reach``:
   request skips prefill for every already-resident full block), and
   per-slot RNG sampling on the continuous path.
 
-The host-mesh probe behind ``bench.py --serve``'s
-``serve_prefix_hit_speedup`` / ``serve_kv_util_pct`` /
-``serve_prefill_stall_ms`` tail fields is ``_serve_paged_probe`` in
-the top-level ``bench.py``.
-
 The decode attention path is XLA over the blocks the live rows hold
 (``models/generate.decode_step_banks`` over
 ``generate.live_block_list``; the prefill chunk and the speculative
-programs gather through the block table); the optional Pallas kernel
-lives in :mod:`ptype_tpu.ops.paged_attention`, gated behind the same
-``check_tpu_lowering`` machinery as the flash kernel.
+programs gather through the block table).
 """
 
 from ptype_tpu.serve_engine.blocks import (BlockPool, block_hashes,

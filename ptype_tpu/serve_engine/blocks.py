@@ -55,8 +55,8 @@ from ptype_tpu.rpc import fnv32a
 
 #: Sublane width of the f32 Mosaic tile: block_tokens must divide by
 #: it so a (block_tokens, head_dim) block tile is layout-aligned on
-#: TPU (the gather path tolerates anything; the Pallas kernel and the
-#: lane-aligned bank layout do not).
+#: TPU (the gathers tolerate anything; the lane-aligned bank layout
+#: does not).
 SUBLANES = 8
 
 

@@ -13,10 +13,10 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   admission, retire and block boundary) and the step loops over the
   list's tiles in use, so a step costs the weights plus Σ live
   context, whatever ``n_slots`` and the reach are — one compiled
-  program for every trip count. (Latent attention and ``attn=
-  "kernel"`` take their own paths and build no list.) Greedy rows
-  still match their solo decode token for token; logits agree to the
-  float32 rounding of a softmax accumulated tile by tile.
+  program for every trip count. (Latent attention selects its own
+  rows and builds no list.) Greedy rows still match their solo decode
+  token for token; logits agree to the float32 rounding of a softmax
+  accumulated tile by tile.
 - **Chunked prefill**: admission writes the prompt in bounded
   ``prefill_chunk``-token chunks INTERLEAVED with decode steps — a 4k
   prompt can no longer freeze co-batched decodes for its whole
@@ -194,9 +194,7 @@ class PagedGeneratorActor(GeneratorActor):
     sheds; ``admit_timeout_s`` bound on how long a head-of-line
     request may wait for a pool reservation before it sheds typed
     (pool exhaustion becomes a routing signal instead of a gateway
-    deadline burn; 0 = wait forever); ``attn`` "gather" (XLA,
-    default) or "kernel" (Pallas paged attention, TPU backends gated
-    by its ``check_tpu_lowering``); ``spec`` a :class:`SpecConfig`
+    deadline burn; 0 = wait forever); ``spec`` a :class:`SpecConfig`
     arming speculative decoding — draft-propose, one batched
     target-verify, exact-distribution acceptance (greedy output stays
     bit-identical to the non-speculative engine; per-slot accept
@@ -212,7 +210,6 @@ class PagedGeneratorActor(GeneratorActor):
                  n_blocks: int | None = None,
                  prefill_chunk: int | None = 64,
                  max_queue: int = 64, admit_timeout_s: float = 10.0,
-                 attn: str = "gather",
                  spec: SpecConfig | None = None,
                  metrics_registry: metrics_mod.MetricsRegistry | None
                  = None, serve_class: str = "unified", device=None):
@@ -242,9 +239,6 @@ class PagedGeneratorActor(GeneratorActor):
                               else self.reach)
         self.max_queue = int(max_queue)
         self.admit_timeout_s = float(admit_timeout_s)
-        if attn not in ("gather", "kernel"):
-            raise ValueError(f"attn must be 'gather'|'kernel', "
-                             f"got {attn!r}")
         if serve_class not in SERVE_CLASSES:
             raise ValueError(f"serve_class must be one of "
                              f"{SERVE_CLASSES}, got {serve_class!r}")
@@ -267,27 +261,12 @@ class PagedGeneratorActor(GeneratorActor):
         self._migrations = 0
         self._migrate_bytes = 0
         self._migrate_dedup_hits = 0
-        if attn == "kernel" and cfg.latent is not None:
-            raise ValueError(
-                "attn='kernel' reads K and V per head; a latent-"
-                "attention configuration takes attn='gather'")
         if spec is not None and not cfg.plain:
             raise ValueError(
                 "speculative decoding drafts with a truncated GQA "
                 "stack of one group; this configuration has latent "
                 "attention or several layer groups (its own next-token "
                 "module would be the drafter, and is not run)")
-        if attn == "kernel" and jax.default_backend() != "cpu":
-            from ptype_tpu.ops.paged_attention import check_tpu_lowering
-
-            bad = check_tpu_lowering(
-                self.n_slots, cfg.n_heads, cfg.kv_heads, cfg.head_dim,
-                n_blocks, bt, self.nb)
-            if bad:
-                raise ValueError(
-                    "paged-attention kernel cannot lower for this "
-                    "config: " + "; ".join(bad))
-        self.attn = attn
 
         # Speculative decoding (ISSUE 12): the draft model's own paged
         # KV tables ride a second BlockPool (same block geometry, its
@@ -370,7 +349,7 @@ class PagedGeneratorActor(GeneratorActor):
             wr_o = pos % bt_
             logits, banks, load = gen.decode_step_banks(
                 params, tok, pos, self.cfg, banks, tables, wr_b,
-                wr_o, attn_impl=self.attn, live=active, blocks=blocks)
+                wr_o, live=active, blocks=blocks)
             with jax.named_scope("sample"):
                 if sampled:
                     nxt = gen.sample_token_rows(logits, keys, eidx,
@@ -398,13 +377,13 @@ class PagedGeneratorActor(GeneratorActor):
         #: authoritative and must be re-uploaded (set dirty by
         #: admission, retire, and block-boundary allocation).
         self._dev: dict | None = None
-        #: A GQA engine on the gather path hands the step the list of
-        #: blocks its live rows hold (gen.live_block_list), rebuilt
-        #: with ``_dev``: the step's attention then costs what is in
-        #: flight, not n_slots x reach. ``_kv``: the list's blocks and
-        #: tiles in use, as the dispatch span and the ledger carry
-        #: them; empty without a list (latent attention, the kernel).
-        self._live_blocks = cfg.latent is None and attn == "gather"
+        #: A GQA engine hands the step the list of blocks its live
+        #: rows hold (gen.live_block_list), rebuilt with ``_dev``: the
+        #: step's attention then costs what is in flight, not n_slots
+        #: x reach. ``_kv``: the list's blocks and tiles in use, as the
+        #: dispatch span and the ledger carry them; empty without a
+        #: list (latent attention).
+        self._live_blocks = cfg.latent is None
         self._kv: dict = {}
 
         def sample_first(logits, key, temp, topk, topp):

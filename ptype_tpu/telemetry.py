@@ -22,10 +22,6 @@ half:
   simultaneous ``jax.profiler`` XPlane capture across every node via
   the built-in ``ptype.Profile`` endpoint, artifacts shipped back and
   written per node — ``python -m ptype_tpu obs profile``.
-
-Also home to :func:`measure_trace_overhead` — the bench probe backing
-``trace_overhead_pct`` in bench.py's tail record (the ~zero-cost
-contract, measured instead of asserted).
 """
 
 from __future__ import annotations
@@ -423,104 +419,3 @@ def _om_family(lines: list, snap: dict, labels: dict | None) -> None:
                             ex.get("ts", 0.0)))
             lines.append(line)
         lines.append(f"{om}_count{lab} {s.get('count', 0)}")
-
-
-# ------------------------------------------------------------ bench probe
-
-
-def measure_trace_overhead(steps: int = 16, preset: str = "tiny",
-                           batch: int = 8, seq: int = 32) -> dict:
-    """Tracing cost on the store-DP step loop — the numbers behind
-    bench.py's ``trace_overhead_pct``.
-
-    Method: the probe interleaves traced and untraced steps (drift on
-    a shared host dwarfs a naive A-then-B comparison) to establish the
-    per-step floor and the span rate, then costs the span machinery
-    DIRECTLY — a tight loop over ``with trace.span(...)`` enabled, and
-    over the bare ``trace.span`` call disabled — and scales by the
-    measured spans-per-step. The direct product is the estimator
-    because it is the only part a differential can't lie about: the
-    span machinery (allocate span, two contextvar ops, ring append) IS
-    everything tracing adds to the step loop, it measures in
-    microseconds, and the step measures in tens of milliseconds — a
-    wall-clock A/B on a noisy host reports scheduler jitter, not the
-    0.0x% signal. The raw interleaved wall clocks ride along for
-    transparency.
-
-    - ``trace_overhead_pct``: enabled span cost × span rate / step —
-      the cost of leaving tracing ON (acceptance: <5%);
-    - ``trace_disabled_overhead_pct``: disabled hook cost × span rate
-      / step — the compiled-out contract (acceptance: <1%).
-    """
-    import jax
-
-    from ptype_tpu import trace
-    from ptype_tpu.models import transformer as tfm
-    from ptype_tpu.parallel.mesh import build_mesh
-    from ptype_tpu.parallel.topology import DATA_AXIS
-    from ptype_tpu.parallel.tensorstore import TensorStore
-    from ptype_tpu.train.data import synthetic_batches
-    from ptype_tpu.train.store_dp import StoreDPTrainer
-
-    # Capture the host process's tracing state: the probe toggles
-    # enable/disable around its loops and must hand back the ORIGINAL
-    # recorder (ring, service name, dump config), not a fresh one.
-    orig_rec, orig_dump = trace.recorder(), trace._dump_dir
-    mesh = build_mesh({DATA_AXIS: jax.device_count()})
-    cfg = tfm.preset(preset)
-    trainer = StoreDPTrainer(cfg, TensorStore(mesh))
-    stream = synthetic_batches(cfg.vocab_size, batch, seq)
-
-    trainer.step(next(stream))  # compile
-    # Span rate, from the recorder's own counter over a traced pair.
-    rec = trace.enable("bench-trace-overhead")
-    trainer.step(next(stream))  # warm the traced path
-    before = rec.finished
-    trainer.step(next(stream))
-    spans_per_step = max(1.0, float(rec.finished - before))
-    trace.disable()
-
-    # Interleaved A/B: per-arm MIN step time (robust to load spikes).
-    t_on: list[float] = []
-    t_off: list[float] = []
-    for i in range(2 * steps):
-        traced = bool(i % 2)
-        if traced:
-            trace.enable("bench-trace-overhead")
-        else:
-            trace.disable()
-        t0 = time.perf_counter()
-        trainer.step(next(stream))
-        (t_on if traced else t_off).append(time.perf_counter() - t0)
-    trace.disable()
-
-    # Enabled span machinery, costed directly.
-    trace.enable("bench-trace-overhead")
-    n = 20_000
-    t0 = time.perf_counter()
-    for _ in range(n):
-        with trace.span("probe"):
-            pass
-    span_cost_s = (time.perf_counter() - t0) / n
-    trace.disable()
-
-    # The disabled hook: one global load + None check + singleton.
-    t0 = time.perf_counter()
-    for _ in range(n):
-        trace.span("probe")
-    noop_cost_s = (time.perf_counter() - t0) / n
-
-    step_s = min(t_off)
-    trace._restore(orig_rec, orig_dump)
-    return {
-        "untraced_step_ms": round(step_s * 1e3, 2),
-        "traced_step_ms": round(min(t_on) * 1e3, 2),
-        "span_cost_us": round(span_cost_s * 1e6, 2),
-        "noop_cost_us": round(noop_cost_s * 1e6, 3),
-        "spans_per_step": round(spans_per_step, 1),
-        "trace_overhead_pct": round(
-            100.0 * span_cost_s * spans_per_step / step_s, 4),
-        "trace_disabled_overhead_pct": round(
-            100.0 * noop_cost_s * spans_per_step / step_s, 6),
-        "steps": steps,
-    }

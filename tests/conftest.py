@@ -43,14 +43,14 @@ SLOW_FILES = {
     "test_data.py",
     "test_elastic.py", "test_elastic_mp.py", "test_examples.py",
     "test_failover.py",
-    "test_flash_attention.py", "test_fsdp_8b.py", "test_generate.py",
+    "test_flash_attention.py", "test_fsdp_8b.py",
     "test_loadgen_drills.py",
     "test_models.py", "test_moe.py", "test_mp_train.py",
     "test_multihost_walkthrough.py",
     "test_overlap.py", "test_param_server.py", "test_pipeline.py",
     "test_quantized_train.py", "test_reconciler_mp.py",
     "test_race.py", "test_resnet.py", "test_ring_attention.py",
-    "test_scale.py", "test_serve.py", "test_store_bench.py",
+    "test_scale.py", "test_store_bench.py",
     "test_train.py", "test_zero_train.py",
 }
 

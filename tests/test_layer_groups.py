@@ -24,6 +24,12 @@ DROPLESS = tfm.TransformerConfig(
     vocab_size=64, d_model=32, n_layers=2, n_heads=2, d_ff=64, max_seq=64,
     n_experts=8, expert_top_k=2, d_ff_expert=16, n_shared_experts=1,
     moe_router="sigmoid_bias", routed_scale=2.5, **F32)
+#: The tiny preset with two K,V heads, and the same behind latent
+#: attention with the indexer.
+DENSE = tfm.preset("tiny", n_kv_heads=2)
+LATENT = dataclasses.replace(DENSE, latent=tfm.LatentAttention(
+    q_rank=16, kv_rank=8, nope_dim=6, rope_dim=2, v_dim=8, index_heads=2,
+    index_dim=4, index_topk=8, index_rope_dim=2))
 BT, N_BLOCKS = 16, 10
 
 
@@ -78,22 +84,43 @@ def test_paged_loop_walks_the_groups_as_the_contiguous_forward_does():
 
 
 def test_pool_allocates_what_the_model_says_a_token_holds():
-    dense = tfm.preset("tiny", n_kv_heads=2)
-    assert tfm.cache_spec(dense) == {"k": (2, 16), "v": (2, 16)}
-    pool = BlockPool(dense, 6, 16)
+    assert tfm.cache_spec(DENSE) == {"k": (2, 16), "v": (2, 16)}
+    pool = BlockPool(DENSE, 6, 16)
     assert pool.banks["k"].shape == (2, 6, 16, 2, 16)
     assert pool.k is pool.banks["k"] and pool.v is pool.banks["v"]
     pool.k = pool.k + 1
     assert float(pool.banks["k"][0, 0, 0, 0, 0]) == 1.0
     assert pool.block_shapes() == {"k": (2, 16, 2, 16), "v": (2, 16, 2, 16)}
-    la = tfm.LatentAttention(q_rank=16, kv_rank=8, nope_dim=6, rope_dim=2,
-                             v_dim=8, index_heads=2, index_dim=4,
-                             index_topk=8, index_rope_dim=2)
-    latent = dataclasses.replace(dense, latent=la)
-    assert tfm.cache_spec(latent) == {"ckv": (10,), "ki": (4,)}
-    pool = BlockPool(latent, 6, 16)
+    assert tfm.cache_spec(LATENT) == {"ckv": (10,), "ki": (4,)}
+    pool = BlockPool(LATENT, 6, 16)
     assert {n: b.shape for n, b in pool.banks.items()} == {
         "ckv": (2, 6, 16, 10), "ki": (2, 6, 16, 4)}
+
+
+def _speculate_on_latent():
+    from ptype_tpu.serve_engine import PagedGeneratorActor, SpecConfig
+
+    params = tfm.init_params(jax.random.PRNGKey(0), LATENT)
+    PagedGeneratorActor(LATENT, params=params, n_slots=2,
+                        spec=SpecConfig(draft_params=params,
+                                        draft_cfg=LATENT, k=2))
+
+
+@pytest.mark.parametrize("call, sentence", [
+    (lambda: gen.truncated_draft_params({}, LATENT), "latent"),
+    (lambda: gen.init_cache(LATENT, 1), "latent"),
+    (lambda: tfm.param_specs(LATENT, {"model": 2}), "latent"),
+    (lambda: tfm.flops_per_token(LATENT, 64), "latent"),
+    (_speculate_on_latent, "next-token module"),
+], ids=["truncated_draft_params", "init_cache", "param_specs",
+        "flops_per_token", "spec_config"])
+def test_what_a_latent_configuration_cannot_run_is_refused(call, sentence):
+    """Latent attention runs the plain paged decode step and prefill
+    chunk and nothing else: the contiguous cache, the truncated draft,
+    speculation, the training shardings and the dense FLOP count each
+    say so in a sentence, not with a shape error."""
+    with pytest.raises(ValueError, match=sentence):
+        call()
 
 
 def _layer(cfg, key=0):

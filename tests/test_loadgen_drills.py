@@ -5,8 +5,7 @@ and a reconciler-armed elastic fleet through the real gateway +
 admission + scale-hint path. The elastic fleet must hold the TTFT p99
 SLO through the spike the static fleet measurably fails, and the
 traffic ledger must publish its ``loadgen.*`` series into the node
-registry the sampler exports (``make traffic-bench`` runs the full
-version with the frontier sweep and steepness curve)."""
+registry the sampler exports."""
 
 import threading
 import time

@@ -29,8 +29,8 @@ def _noise_banks(seed):
             jax.random.normal(k2, shape, jnp.float32))
 
 
-@pytest.mark.parametrize("attn_impl", ["gather", "kernel", "blocks"])
-def test_paged_programs_match_contiguous_greedy(attn_impl):
+@pytest.mark.parametrize("attend", ["tables", "blocks"])
+def test_paged_programs_match_contiguous_greedy(attend):
     """Two rows that SHARE their first block and a third, inactive
     lane routed to the trash block, through ``prefill_chunk_banks``
     and ``decode_step_banks``: each live row equals the untouched
@@ -77,13 +77,12 @@ def test_paged_programs_match_contiguous_greedy(attn_impl):
         wr_b = jnp.where(active, tables[jnp.arange(3), pos // BT], 0)
         lg, banks, _ = gen.decode_step_banks(
             params, tok, pos, CFG3, {"k": kb, "v": vb}, tables, wr_b,
-            pos % BT, blocks=blocks,
-            attn_impl="gather" if attn_impl == "blocks" else attn_impl)
+            pos % BT, blocks=blocks)
         kb, vb = banks["k"], banks["v"]
         nxt = jnp.where(active, jnp.argmax(lg, -1).astype(jnp.int32), 0)
         return kb, vb, lg, nxt, jnp.where(active, pos + 1, pos)
 
-    if attn_impl == "blocks":
+    if attend == "blocks":
         plain_step = step
 
         def step(kb, vb, tok, pos, active):

@@ -9,7 +9,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "tools"))
@@ -646,17 +645,15 @@ def test_json_output_shape(tmp_path):
     assert set(out[0]) == {"path", "line", "code", "message"}
 
 
-def test_make_lint_tier_runs_clean_within_budget():
-    """The tier-1 CI seam: ptlint over the whole repo (the ``make
-    lint`` surface) exits clean inside the 10 s wall budget."""
-    t0 = time.monotonic()
+def test_make_lint_tier_exits_clean():
+    """The tier-1 CI seam: ptlint over the ``make lint`` surface exits
+    0 (no wall-clock assertion: a loaded host is not a lint finding;
+    the subprocess timeout bounds a hung linter)."""
     proc = subprocess.run(
-        [sys.executable, "-m", "tools.ptlint",
-         "ptype_tpu", "tools", "tests", "bench.py", "chip_smoke.py"],
+        [sys.executable, "-m", "tools.ptlint", "ptype_tpu", "tools",
+         "tests", "examples", "chip_smoke.py", "__graft_entry__.py"],
         cwd=REPO, capture_output=True, text=True, timeout=60)
-    dt = time.monotonic() - t0
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert dt < 10.0, f"ptlint took {dt:.1f}s (budget 10s)"
 
 
 def test_pt015_join_in_another_method_does_not_reach_local_thread(

@@ -11,6 +11,7 @@ from ptype_tpu.config import Config, PlatformConfig
 from ptype_tpu.models import transformer as tfm
 from ptype_tpu.rpc import ConnConfig
 from ptype_tpu.serve import GeneratorActor
+from ptype_tpu.serve_engine import PagedGeneratorActor
 
 CFG = tfm.preset("tiny", dtype=jnp.float32)
 
@@ -66,74 +67,11 @@ def test_generate_over_rpc():
         server.close()
 
 
-def test_batching_generator_coalesces_and_matches_solo():
-    """Concurrent same-shape greedy requests coalesce into one decode
-    round; every caller's rows match the solo result exactly (greedy
-    rows are independent)."""
-    import threading
-
-    from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import BatchingGeneratorActor
-
-    actor = BatchingGeneratorActor(CFG, window_ms=200.0, max_batch=16)
-    try:
-        prompts = [jnp.full((1, 4), i, jnp.int32) for i in range(6)]
-        outs = [None] * 6
-        barrier = threading.Barrier(6)
-
-        def call(i):
-            barrier.wait()  # all requests land inside one window
-            outs[i] = actor.Generate(prompts[i], 5)
-
-        threads = [threading.Thread(target=call, args=(i,))
-                   for i in range(6)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=60)
-        for i in range(6):
-            want = gen.generate(actor.params, CFG, prompts[i], 5)
-            np.testing.assert_array_equal(np.asarray(outs[i]),
-                                          np.asarray(want))
-        info = actor.Info()
-        assert info["batched_requests"] == 6
-        # Coalescing actually happened: fewer rounds than requests.
-        assert info["batches"] < 6
-        # Load telemetry drained with the queue.
-        assert info["queue_depth"] == 0 and info["in_flight"] == 0
-    finally:
-        actor.close()
-
-
-def test_batching_generator_mixed_shapes_and_sampled():
-    """Shape-mismatched requests in one window split into per-shape
-    groups; sampled requests keep exact solo-path RNG semantics."""
-    from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import BatchingGeneratorActor
-
-    actor = BatchingGeneratorActor(CFG, window_ms=50.0)
-    try:
-        a = actor.Generate(jnp.zeros((1, 4), jnp.int32), 3)
-        b = actor.Generate(jnp.ones((2, 8), jnp.int32), 4)
-        assert a.shape == (1, 3) and b.shape == (2, 4)
-        s = actor.Generate(jnp.zeros((1, 4), jnp.int32), 3,
-                           temperature=0.7, seed=11)
-        want = gen.generate(actor.params, CFG,
-                            jnp.zeros((1, 4), jnp.int32), 3, 0.7,
-                            jax.random.PRNGKey(11))
-        np.testing.assert_array_equal(np.asarray(s), np.asarray(want))
-    finally:
-        actor.close()
-
-
 def test_lifecycle_methods_not_remotely_callable():
     """register() exposes only Uppercase (net/rpc-exported) methods:
     Generator.close must NOT be a remote endpoint — any client could
     otherwise shut down the server's generation."""
-    from ptype_tpu.actor import ActorServer
-    from ptype_tpu.serve import BatchingGeneratorActor
-
-    actor = BatchingGeneratorActor(CFG)
+    actor = PagedGeneratorActor(CFG, n_slots=2)
     try:
         server = ActorServer(get_ip(), 0)
         server.register(actor, "Generator")
@@ -147,39 +85,35 @@ def test_lifecycle_methods_not_remotely_callable():
         actor.close()
 
 
-def test_batching_generator_coalesces_mixed_lengths():
-    """Mixed prompt lengths coalesce into ONE ragged round, each
-    caller's rows matching its solo decode exactly."""
-    import threading
+def test_cli_serve_replica_is_the_workers_paged_engine(monkeypatch):
+    """``python -m ptype_tpu serve`` fronts what a spawned worker would:
+    a ``PagedGeneratorActor`` made and warmed by
+    ``reconciler.worker._actor_factory``, ``$SERVE_SLOTS`` live rows,
+    and no engine switch (the batching actor's ``$SERVE_*`` variables
+    are read by nothing)."""
+    from ptype_tpu import __main__ as cli
+    from ptype_tpu.reconciler import worker
 
-    from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import BatchingGeneratorActor
+    monkeypatch.setenv("PRESET", "tiny")
+    monkeypatch.setenv("SERVE_SLOTS", "3")
+    for gone, value in (("MODE", "batching"), ("WINDOW_MS", "50"),
+                        ("MAX_BATCH", "2")):
+        monkeypatch.setenv("SERVE_" + gone, value)
+    asked, warmed = [], []
+    real = worker._actor_factory
 
-    actor = BatchingGeneratorActor(CFG, window_ms=200.0, max_batch=16)
+    def factory(kind, preset):
+        asked.append((kind, preset))
+        make, _warmup = real(kind, preset)
+        # The warm-up's compiles are the reconciler tests' to pay.
+        return make, warmed.append
+
+    monkeypatch.setattr(worker, "_actor_factory", factory)
+    actor = cli._serve_replica()
     try:
-        rng = np.random.default_rng(9)
-        prompts = [jnp.asarray(rng.integers(1, CFG.vocab_size, n),
-                               jnp.int32)[None] for n in (3, 5, 8, 6)]
-        outs = [None] * len(prompts)
-        barrier = threading.Barrier(len(prompts))
-
-        def call(i):
-            barrier.wait()
-            outs[i] = actor.Generate(prompts[i], 5)
-
-        threads = [threading.Thread(target=call, args=(i,))
-                   for i in range(len(prompts))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=120)
-        for i, p in enumerate(prompts):
-            want = gen.generate(actor.params, CFG, p, 5)
-            np.testing.assert_array_equal(np.asarray(outs[i]),
-                                          np.asarray(want),
-                                          err_msg=f"req {i}")
-        info = actor.Info()
-        assert info["batches"] < len(prompts), info
+        assert type(actor) is PagedGeneratorActor
+        assert asked == [("paged", "tiny")] and warmed == [actor]
+        assert actor.n_slots == 3
     finally:
         actor.close()
 
@@ -196,9 +130,7 @@ def test_continuous_engine_rows_match_solo():
     import time
 
     from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import ContinuousGeneratorActor
-
-    actor = ContinuousGeneratorActor(CFG, n_slots=4)
+    actor = PagedGeneratorActor(CFG, n_slots=4)
     try:
         rng = np.random.default_rng(3)
         lens = (3, 7, 5, 9, 4, 6)
@@ -237,9 +169,7 @@ def test_continuous_engine_stop_token_frees_slot_early():
     kept, rest padded), and the engine spent FEWER steps than max_new
     would cost."""
     from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import ContinuousGeneratorActor
-
-    actor = ContinuousGeneratorActor(CFG, n_slots=2)
+    actor = PagedGeneratorActor(CFG, n_slots=2)
     try:
         prompt = jnp.zeros((1, 4), jnp.int32)
         max_new = 24
@@ -263,9 +193,7 @@ def test_continuous_engine_multirow_and_solo_fallback():
     """(B, S) requests split across slots and re-assemble in order;
     sampled requests keep exact solo RNG semantics via the fallback."""
     from ptype_tpu.models import generate as gen
-    from ptype_tpu.serve import ContinuousGeneratorActor
-
-    actor = ContinuousGeneratorActor(CFG, n_slots=4)
+    actor = PagedGeneratorActor(CFG, n_slots=4)
     try:
         prompt = jnp.arange(8, dtype=jnp.int32).reshape(2, 4) + 1
         out = actor.Generate(prompt, 6)
@@ -281,6 +209,7 @@ def test_continuous_engine_multirow_and_solo_fallback():
         actor.close()
 
 
+@pytest.mark.slow  # a CPU wall-clock comparison: never in tier-1
 def test_continuous_engine_throughput_beats_serialized():
     """The capacity argument, measured: under concurrent mixed-length
     greedy load the continuous engine must beat the lock-serialized
@@ -294,8 +223,6 @@ def test_continuous_engine_throughput_beats_serialized():
     ~4x; on TPU the gap is wider still)."""
     import threading
     import time
-
-    from ptype_tpu.serve import ContinuousGeneratorActor
 
     cfg_perf = tfm.preset("tiny", d_model=256, n_layers=4, d_ff=512,
                           dtype=jnp.float32)
@@ -323,7 +250,7 @@ def test_continuous_engine_throughput_beats_serialized():
         return dt, outs
 
     serialized = GeneratorActor(cfg_perf)
-    continuous = ContinuousGeneratorActor(
+    continuous = PagedGeneratorActor(
         cfg_perf, params=serialized.params, n_slots=8)
     try:
         drive(serialized)   # warm both: compile every shape involved
@@ -408,8 +335,6 @@ def test_info_and_drain_gate_do_not_ride_the_decode_lock():
     never stall probes or drain orders (the gateway evicts a replica
     whose Info stops answering)."""
     import threading
-
-    from ptype_tpu.serve import GeneratorActor
 
     actor = GeneratorActor(CFG)
     out: dict = {}

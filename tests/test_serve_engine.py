@@ -1,7 +1,6 @@
 """Paged KV-cache serving engine (ISSUE 9): block pool invariants,
 paged-vs-contiguous greedy parity, prefix reuse skipping prefill,
-chunked-prefill stall bounds, continuous-path sampling parity, the
-Pallas paged-attention kernel (interpret + lowering contract), typed
+chunked-prefill stall bounds, continuous-path sampling parity, typed
 admission sheds + the serve.admit chaos seam, and the gateway's
 pool-exhaustion / prefix-affinity load signals."""
 
@@ -207,8 +206,7 @@ def test_decode_attends_over_the_live_rows_blocks(monkeypatch,
 def test_ledger_counts_the_block_lists_tiles():
     """One short row: every decode step runs one tile over one listed
     block, and ``kv_tile_fill`` is the tokens attended over the tokens
-    the tiles covered. The dispatch span carries the two counts. An
-    engine with no list (the kernel) reports none."""
+    the tiles covered. The dispatch span carries the two counts."""
     from ptype_tpu import trace
 
     rec = trace.enable("kv-list-test")
@@ -228,13 +226,6 @@ def test_ledger_counts_the_block_lists_tiles():
     finally:
         trace.disable()
         actor.close()
-    kernel = PagedGeneratorActor(CFG, n_slots=2, block_tokens=16,
-                                 attn="kernel")
-    try:
-        kernel.Generate(_prompt(5), 4)
-        assert "kv_tiles" not in kernel.ledger.summary()
-    finally:
-        kernel.close()
 
 
 def test_sampled_single_row_rides_engine_with_exact_solo_parity():
@@ -611,67 +602,6 @@ def test_serve_admit_chaos_seam_sheds_and_pairs():
     finally:
         chaos.disarm()
         actor.close()
-
-
-# ------------------------------------------------- paged kernel
-
-
-def test_paged_kernel_interpret_matches_gather():
-    rng = np.random.default_rng(0)
-    from ptype_tpu.ops.paged_attention import paged_attention
-
-    B, bt, nb, n_blocks = 3, 16, 8, 30
-    Kh, Dh, H = CFG.kv_heads, CFG.head_dim, CFG.n_heads
-    kc = jnp.asarray(rng.normal(size=(n_blocks, bt, Kh, Dh)),
-                     jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(n_blocks, bt, Kh, Dh)),
-                     jnp.float32)
-    q = jnp.asarray(rng.normal(size=(B, 1, H, Dh)), jnp.float32)
-    tables = jnp.asarray(rng.integers(1, n_blocks, (B, nb)), jnp.int32)
-    pos = jnp.asarray([5, 37, 100], jnp.int32)
-    ref = gen._paged_attention_gather(q, kc, vc, tables, pos + 1, CFG)
-    out = paged_attention(q, kc, vc, tables, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-
-
-def test_paged_kernel_lowering_contract():
-    from ptype_tpu.ops.paged_attention import check_tpu_lowering
-
-    # The serving shapes that should run on real TPU: 128-wide heads,
-    # sublane-aligned blocks (the optimus-125m presets' geometry).
-    assert check_tpu_lowering(8, 6, 6, 128, 257, 32, 16) == []
-    assert check_tpu_lowering(8, 8, 2, 128, 513, 128, 8) == []  # GQA
-    # Misaligned block_tokens / head_dim are NAMED, on CPU, before a
-    # TPU session trips over them (the BENCH_r02 failure class).
-    assert any("block_tokens" in v
-               for v in check_tpu_lowering(8, 6, 6, 128, 257, 12, 16))
-    assert any("head_dim" in v
-               for v in check_tpu_lowering(8, 4, 4, 16, 65, 16, 8))
-    # The engine refuses to arm the kernel on a non-CPU backend when
-    # the contract fails (gated, not crash-at-decode).
-    import unittest.mock as mock
-    with mock.patch.object(jax, "default_backend",
-                           return_value="tpu"):
-        with pytest.raises(ValueError, match="lower"):
-            PagedGeneratorActor(CFG, n_slots=2, attn="kernel")
-
-
-def test_engine_with_kernel_attn_matches_gather_engine():
-    """End-to-end: the SAME engine stack with attn="kernel"
-    (interpret-mode on CPU) decodes greedy requests to the same
-    tokens as the gather path."""
-    a = PagedGeneratorActor(CFG, n_slots=2, block_tokens=16)
-    b = PagedGeneratorActor(CFG, params=a.params, n_slots=2,
-                            block_tokens=16, attn="kernel")
-    try:
-        p = _prompt(21)
-        out_a = np.asarray(a.Generate(p, 10))
-        out_b = np.asarray(b.Generate(p, 10))
-        np.testing.assert_array_equal(out_a, out_b)
-    finally:
-        a.close()
-        b.close()
 
 
 # ------------------------------------------------ gateway signals

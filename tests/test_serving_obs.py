@@ -188,8 +188,8 @@ def test_ledger_emits_no_spans_without_traceparent_or_tracing():
 def test_seam_cost_probe_prices_one_iteration():
     out = measure_seam_cost_us(iters=500)
     assert out["iters"] == 500
-    # Microseconds, not milliseconds: the <1%-per-iteration bar in
-    # bench.py --serve divides this by a multi-ms engine step.
+    # Microseconds, not milliseconds: the <1%-per-iteration bar
+    # divides this by a multi-ms engine step.
     assert 0.0 < out["seam_cost_us"] < 1000.0
 
 

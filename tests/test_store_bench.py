@@ -51,8 +51,8 @@ def test_bucketed_push_tree_beats_per_leaf(mesh8):
 
 
 def test_measure_push_tree_reports_speedup(mesh8):
-    """The bench helper (what bench.py's store_push_tree_ms rides)
-    returns a coherent record on the host mesh."""
+    """The push_tree probe returns a coherent record on the host
+    mesh."""
     r = measure_push_tree(mesh8, preset="tiny", iters=2)
     assert r["bucketed_ms"] > 0 and r["per_leaf_ms"] > 0
     assert r["n_buckets"] <= r["n_leaves"]

@@ -194,7 +194,7 @@ def test_zero_optimizer_leg_lands_in_goodput(mesh8):
 
 
 def test_measure_zero_probe(mesh8):
-    """The `make zero-bench` probe: ~8× per-replica optimizer memory
+    """The ZeRO-1 probe: ~8× per-replica optimizer memory
     at matched loss."""
     r = measure_zero(mesh8, steps=2, batch=8)
     assert r["opt_mem_ratio"] >= 7.5

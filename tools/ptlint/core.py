@@ -248,7 +248,7 @@ def main(argv: list[str]) -> int:
     paths = argv or [os.path.join(REPO, "ptype_tpu"),
                      os.path.join(REPO, "tests"),
                      os.path.join(REPO, "examples"),
-                     os.path.join(REPO, "bench.py"),
+                     os.path.join(REPO, "chip_smoke.py"),
                      os.path.join(REPO, "__graft_entry__.py"),
                      os.path.join(REPO, "tools")]
     findings, n = run_paths(paths)
