@@ -629,7 +629,7 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
         np.ones(n_slots, bool), bt)
     decode = jax.jit(lambda p, kb, vb, blocks: gen.decode_step_banks(
         p, tok, pos, wide, {"k": kb, "v": vb}, tables, wr_b, pos % bt,
-        blocks=blocks)[0])
+        live_list=blocks)[0])
     out["paged_decode_blocks_err"] = round(_close(
         decode(params, kb, vb, blocks), decode(params, kb, vb, None),
         "decode_step_banks over the block list vs the tables, logits"),

@@ -379,6 +379,9 @@ class ServingLedger:
         #: tokens the tiles covered; absent from the summary until a
         #: step ran over a list.
         self._kv_list = [0, 0, 0, 0, 0]
+        #: A latent step's live-lane list (lane_list): steps, tiles
+        #: run, lanes live and lanes the tiles covered; absent likewise.
+        self._lane_list = [0, 0, 0, 0]
 
     # --------------------------------------------------- request seams
 
@@ -486,6 +489,16 @@ class ServingLedger:
             for i, v in enumerate((1, blocks, tiles, live_tokens,
                                    tiles * tile_tokens)):
                 self._kv_list[i] += v
+
+    def lane_list(self, live: int, tiles: int, tile: int) -> None:
+        """One latent decode step over the live lanes' list
+        (``generate.live_lane_list``): ``live`` lanes listed, ``tiles``
+        of ``tile`` lanes run. Running totals behind ``summary()``'s
+        ``lane_tiles`` (mean a step) and ``lane_tile_fill`` (live lanes
+        ÷ lanes the tiles in use covered)."""
+        with self._lock:
+            for i, v in enumerate((1, tiles, live, tiles * tile)):
+                self._lane_list[i] += v
 
     def shed_untracked(self) -> None:
         """A shed before any record existed (the chaos admit seam)."""
@@ -627,11 +640,17 @@ class ServingLedger:
             moe_load, moe_iters = list(self._moe_load), self._moe_iters
             kv_steps, kv_blocks, kv_tiles, kv_live, kv_covered = \
                 self._kv_list
+            lane_steps, lane_tiles, lanes_live, lanes_covered = \
+                self._lane_list
         out = {}
         if kv_steps:
             out["kv_blocks"] = round(kv_blocks / kv_steps, 2)
             out["kv_tiles"] = round(kv_tiles / kv_steps, 3)
             out["kv_tile_fill"] = round(kv_live / max(kv_covered, 1), 4)
+        if lane_steps:
+            out["lane_tiles"] = round(lane_tiles / lane_steps, 3)
+            out["lane_tile_fill"] = round(
+                lanes_live / max(lanes_covered, 1), 4)
         if moe_iters:
             out["moe_load"] = {"iterations": moe_iters,
                                "held": moe_load[:-1],

@@ -297,6 +297,11 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
 #: block: 8 MB of K, 8 MB of V and 17 MB of float32 scores at mistral's
 #: widths and 32 lanes). Chosen on the chip (PERF.md §6, PR 29).
 LIVE_TILE_BLOCKS = 256
+#: Lanes in one tile of the live-lane list: one float32 sublane tile,
+#: so a tile's ``(8, reach)`` indexer scores sort in whole tiles.
+#: Chosen on the chip against 16, which is slower at every number of
+#: live lanes (PERF.md §6, PR 32).
+LIVE_TILE_LANES = 8
 
 
 def live_block_list(tables, nalloc, active, block_tokens: int,
@@ -327,6 +332,24 @@ def live_block_list(tables, nalloc, active, block_tokens: int,
     blocks[2, :n] = col * block_tokens
     return (blocks.reshape(3, max_tiles, tile),
             np.int32(-(-n // tile)))
+
+
+def live_lane_list(active, tile: int | None = None):
+    """The lanes that hold a request, as a latent decode step's
+    attention reads them (``sparse_mla.attend_paged``). Host side,
+    numpy: ``active`` (n_slots,) bool. Returns ``(lanes, n_tiles)``:
+    ``lanes`` int32 ``(max_tiles, tile)`` holds the live slots' ids in
+    slot order, padded with the id no lane has (``n_slots``);
+    ``n_tiles`` int32 is the number of tiles in use, the step's trip
+    count. ``max_tiles * tile`` covers every slot, so the shape never
+    changes while the engine lives."""
+    active = np.asarray(active, bool)
+    ns = active.size
+    tile = min(int(tile or LIVE_TILE_LANES), ns)
+    ids = np.flatnonzero(active)
+    lanes = np.full(-(-ns // tile) * tile, ns, np.int32)
+    lanes[:ids.size] = ids
+    return lanes.reshape(-1, tile), np.int32(-(-ids.size // tile))
 
 
 def _live_block_attention(q, kf, vf, base, blocks, limits):
@@ -394,7 +417,7 @@ def _live_block_attention(q, kf, vf, base, blocks, limits):
 
 def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                   wr_o, limits, moe_capacity, live=None,
-                  chunk: bool = False, blocks=None):
+                  chunk: bool = False, live_list=None):
     """Embedding and the ONE layer loop of the paged programs (decode
     step, prefill chunk, speculative verify and draft). ``banks`` is
     the cache as the model describes it (``tfm.cache_spec``: a dict of
@@ -414,11 +437,13 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     the trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q))
     as :func:`_paged_attention_gather` takes them. ``chunk``: the
     queries are a prefill chunk's, many to a table (latent attention
-    gathers by it, ``sparse_mla.attend_paged``). ``blocks``: the live
-    rows' block list (:func:`live_block_list`); given to a GQA decode
-    step, the step attends over the list
-    (:func:`_live_block_attention`) and not through ``tables``, which
-    then only route the writes. Returns ``(x (B, Q, D) before the
+    gathers by it, ``sparse_mla.attend_paged``). ``live_list``: what
+    the live rows hold, in the form the cache kind reads, given to a
+    decode step. GQA: the block list (:func:`live_block_list`); the
+    step attends over it (:func:`_live_block_attention`) and not
+    through ``tables``, which then only route the writes. Latent: the
+    lane list (:func:`live_lane_list`); index, selection, gather and
+    attention run over its lanes alone. Returns ``(x (B, Q, D) before the
     final norm, banks, load)``; ``load`` is a dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
     ``live`` (B, Q) marks, if given), None without one."""
@@ -438,8 +463,8 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             with jax.named_scope("kv_write"):
                 kf = kf.at[base + wr_b, wr_o].set(k)
                 vf = vf.at[base + wr_b, wr_o].set(v)
-            if blocks is not None:
-                o = _live_block_attention(q, kf, vf, base, blocks,
+            if live_list is not None:
+                o = _live_block_attention(q, kf, vf, base, live_list,
                                           limits)
             else:
                 o = _paged_attention_gather(q, kf, vf, base + tables,
@@ -456,7 +481,8 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                 kif = bf["ki"].at[base + wr_b, wr_o].set(ki)
             o = sparse_mla.attend_paged(q_nope, q_rope, qi, wi, cf, kif,
                                         base + tables, limits, layer,
-                                        cfg, whole_context=chunk)
+                                        cfg, whole_context=chunk,
+                                        lanes=live_list)
             return o, {"ckv": cf, "ki": kif}
 
     def body(carry, inputs):
@@ -483,7 +509,7 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
 def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
                       cfg: tfm.TransformerConfig, banks: dict,
                       tables: jax.Array, wr_blocks: jax.Array,
-                      wr_off: jax.Array, live=None, blocks=None):
+                      wr_off: jax.Array, live=None, live_list=None):
     """One decode step through per-sequence BLOCK TABLES — the paged
     engine step (serve_engine.PagedGeneratorActor). ``banks``: the
     cache as ``tfm.cache_spec(cfg)`` describes it, ``(L, n_blocks,
@@ -497,17 +523,23 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     and its logits to the float32 rounding of a softmax summed in
     another order.
 
-    ``blocks`` (``live_block_list``'s pair, GQA only): the K,V blocks
-    the live rows hold. With it the step reads those blocks and no
-    others, tile by tile (:func:`_live_block_attention`): its cost
-    follows the tokens in flight. Without it each row gathers its whole
-    table, reach and all (:func:`_paged_attention_gather`). Returns
+    ``live_list``: what the live rows hold, in the form the cache kind
+    reads. GQA (:func:`live_block_list`'s pair): their K,V blocks; the
+    step reads those and no others, tile by tile
+    (:func:`_live_block_attention`), so its cost follows the tokens in
+    flight. Latent (:func:`live_lane_list`'s pair): their lanes; the
+    indexer, the selection, the latent gather and the attention run
+    over those, a tile of lanes at a time
+    (``sparse_mla.attend_paged``), so their cost follows the rows in
+    flight, and an inactive lane's attention reads zeros. Without it
+    every row gathers or indexes its whole table, reach and all, live
+    or not. Returns
     ``(logits (B, V), banks, load)``, ``load`` as :func:`_paged_layers`
     gives it (of the rows ``live`` (B,) marks, if given)."""
     x, banks, load = _paged_layers(
         params, token[:, None], pos[:, None], cfg, banks, tables,
         wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
-        None if live is None else live[:, None], blocks=blocks)
+        None if live is None else live[:, None], live_list=live_list)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = _head_logits(params, x[:, 0], cfg)

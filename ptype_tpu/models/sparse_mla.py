@@ -20,6 +20,11 @@ indexer in front of it, as ``TransformerConfig.latent`` sizes it:
   table and read as they lie. tests/benchmark/test_bench_glm.py holds
   the two to each other and to the plain reference.
 
+The decode step (one query a lane) runs the absorbed form over the
+lanes that hold a request, a tile of them at a time, in one loop
+whose trip count is data (``generate.live_lane_list``): indexing,
+selection, gather and attention cost what is live, not ``n_slots``.
+
 Rotary positions rotate adjacent pairs (``rope_interleave``), on all of
 ``kR`` / ``q^rope`` and on the leading ``index_rope_dim`` dims of the
 indexer's q and k. Scopes: ``qkv`` (norms and projections), ``index``
@@ -187,7 +192,7 @@ def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
 
 def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
                  limits, layer, cfg: tfm.TransformerConfig,
-                 whole_context: bool = False):
+                 whole_context: bool = False, lanes=None):
     """The absorbed form through a block table. Queries (B, Q, ...) as
     :func:`project` gives them; ``ckv_bank`` (rows, bt, cache_dim)
     and ``ki_bank`` (rows, bt, di) the banks in the flat view;
@@ -198,6 +203,11 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
     gathered and read. ``whole_context`` (a prefill chunk says so:
     many queries a table): a row's whole latent context is gathered
     once, by blocks, and each query picks its rows from that.
+    ``lanes`` (``generate.live_lane_list``'s pair; a decode step's,
+    one query a lane): the lanes that hold a request. With it the same
+    arithmetic runs on those lanes alone, a tile of them a trip of one
+    loop whose trip count is data (ONE compiled program whatever the
+    load), and every other lane reads zeros; without it, on all ``B``.
     → o (B, Q, H, v)."""
     la, dt = cfg.latent, cfg.dtype
     B, Q, H, _ = q_nope.shape
@@ -207,8 +217,12 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
     limits = jnp.asarray(limits)
     if limits.ndim == 1:
         limits = jnp.broadcast_to(limits[:, None], (B, Q))
-    with jax.named_scope("index"):
-        ki = ki_bank[tables].reshape(B, T, la.index_dim)
+
+    def keys(tables):
+        with jax.named_scope("index"):
+            return ki_bank[tables].reshape(-1, T, la.index_dim)
+
+    ki = keys(tables) if lanes is None else None
     ctx = None
     if whole_context:
         # The whole latent context in position order is one gather of
@@ -229,8 +243,8 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
              jnp.zeros((B, Q, H, la.cache_dim - la.row_dim), dt)],
             axis=-1)
 
-    def block(qa, qi, wi, limits):
-        Qb = qa.shape[1]
+    def block(qa, qi, wi, limits, tables=tables, ki=ki):
+        n, Qb = qa.shape[:2]
         with jax.named_scope("index"):
             I = index_scores(qi, wi, ki)
             I = jnp.where(jnp.arange(T)[None, None] < limits[..., None],
@@ -238,8 +252,8 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
         with jax.named_scope("select"):
             # Rows flat: a (B, 1, T) operand sorts in (1, 128) tiles,
             # five times slower than (B, T) (chip run, PR 28).
-            _, idx = lax.top_k(I.reshape(B * Qb, T), k)
-            idx = idx.reshape(B, Qb, k)  # positions
+            _, idx = lax.top_k(I.reshape(n * Qb, T), k)
+            idx = idx.reshape(n, Qb, k)  # positions
             ok = idx < limits[..., None]
         with jax.named_scope("kv_gather"):
             if ctx is not None:
@@ -259,6 +273,23 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
             return jnp.einsum("bqhc,chv->bqhv", ol,
                               layer["w_uv"].astype(dt))
 
+    if lanes is not None:
+        # The banks are closed over and only read: a bank in the loop's
+        # state is copied (2.27 GB at GLM-5's pool: PERF.md §6, PR 29).
+        lst, n_tiles = jnp.asarray(lanes[0]), lanes[1]
+
+        def tile(t, o):
+            ids = lst[t]  # (tile,) lanes; the pad id B is no lane
+            at = jnp.minimum(ids, B - 1)
+            held = jnp.where((ids < B)[:, None], limits[at], 0)
+            own = tables[at]
+            return o.at[ids].set(
+                block(qa[at], qi[at], wi[at], held, own, keys(own)),
+                mode="drop")
+
+        with jax.named_scope("attn"):
+            return lax.fori_loop(0, n_tiles, tile,
+                                 jnp.zeros((B, Q, H, la.v_dim), dt))
     if Q <= QUERY_BLOCK:
         return block(qa, qi, wi, limits)
     if Q % QUERY_BLOCK:

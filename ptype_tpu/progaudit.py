@@ -608,10 +608,10 @@ def _build_paged_program(name: str, preset: str, n_slots: int,
         i32 = jnp.int32
 
         def decode_step(params, banks, tok, pos, tables, wr_b, wr_o,
-                        blocks):
+                        live_list):
             return gen.decode_step_banks(params, tok, pos, cfg, banks,
                                          tables, wr_b, wr_o,
-                                         blocks=blocks)[:2]
+                                         live_list=live_list)[:2]
 
         def prefill_chunk(params, banks, tokens, start, length, table):
             return gen.prefill_chunk_banks(

@@ -16,10 +16,13 @@ counts instead of ``n_slots × reach``:
   request skips prefill for every already-resident full block), and
   per-slot RNG sampling on the continuous path.
 
-The decode attention path is XLA over the blocks the live rows hold
-(``models/generate.decode_step_banks`` over
-``generate.live_block_list``; the prefill chunk and the speculative
-programs gather through the block table).
+The decode attention path is XLA over what the live rows hold
+(``models/generate.decode_step_banks`` over the engine's live list: a
+GQA step reads ``generate.live_block_list``'s blocks, a latent step
+indexes, selects, gathers and attends over
+``generate.live_lane_list``'s lanes, a tile of them a trip; the
+prefill chunk and the speculative programs gather through the block
+table).
 """
 
 from ptype_tpu.serve_engine.blocks import (BlockPool, block_hashes,

@@ -13,10 +13,13 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   admission, retire and block boundary) and the step loops over the
   list's tiles in use, so a step costs the weights plus Σ live
   context, whatever ``n_slots`` and the reach are — one compiled
-  program for every trip count. (Latent attention selects its own
-  rows and builds no list.) Greedy rows still match their solo decode
-  token for token; logits agree to the float32 rounding of a softmax
-  accumulated tile by tile.
+  program for every trip count. A latent step (an indexer selects
+  each row's keys) is handed the list of live LANES instead
+  (``generate.live_lane_list``, rebuilt at the same moments): index,
+  selection, gather and attention run over those lanes, a tile of
+  them a trip, and cost what is live whatever ``n_slots`` is. Greedy
+  rows still match their solo decode token for token; logits agree to
+  the float32 rounding of a softmax accumulated tile by tile.
 - **Chunked prefill**: admission writes the prompt in bounded
   ``prefill_chunk``-token chunks INTERLEAVED with decode steps — a 4k
   prompt can no longer freeze co-batched decodes for its whole
@@ -336,7 +339,7 @@ class PagedGeneratorActor(GeneratorActor):
 
         def engine_step(sampled, params, banks, tok, pos, tables,
                         active, keys, eidx, temps, topk, topp,
-                        blocks):
+                        live_list):
             B = tok.shape[0]
             bt_ = self.block_tokens
             # Write routing in-graph: inactive lanes scatter to the
@@ -349,7 +352,7 @@ class PagedGeneratorActor(GeneratorActor):
             wr_o = pos % bt_
             logits, banks, load = gen.decode_step_banks(
                 params, tok, pos, self.cfg, banks, tables, wr_b,
-                wr_o, live=active, blocks=blocks)
+                wr_o, live=active, live_list=live_list)
             with jax.named_scope("sample"):
                 if sampled:
                     nxt = gen.sample_token_rows(logits, keys, eidx,
@@ -377,13 +380,13 @@ class PagedGeneratorActor(GeneratorActor):
         #: authoritative and must be re-uploaded (set dirty by
         #: admission, retire, and block-boundary allocation).
         self._dev: dict | None = None
-        #: A GQA engine hands the step the list of blocks its live
-        #: rows hold (gen.live_block_list), rebuilt with ``_dev``: the
-        #: step's attention then costs what is in flight, not n_slots
-        #: x reach. ``_kv``: the list's blocks and tiles in use, as the
-        #: dispatch span and the ledger carry them; empty without a
-        #: list (latent attention).
-        self._live_blocks = cfg.latent is None
+        #: The engine hands the step the list of what its live rows
+        #: hold, rebuilt with ``_dev``: a GQA engine the blocks
+        #: (gen.live_block_list), a latent one the lanes
+        #: (gen.live_lane_list). The step's attention then costs what
+        #: is in flight, not n_slots x reach. ``_kv``: the list's
+        #: counts, as the dispatch span carries them (``kv_blocks``,
+        #: ``kv_tiles``; ``live_lanes``, ``lane_tiles``).
         self._kv: dict = {}
 
         def sample_first(logits, key, temp, topk, topp):
@@ -1317,32 +1320,39 @@ class PagedGeneratorActor(GeneratorActor):
             # the same commitment or the second step of every request
             # sees a new signature and compiles again (chip run, PR 21).
             with annotate("serve.step/upload"):
-                blocks = None
-                if self._live_blocks:
-                    blocks = gen.live_block_list(
+                if self.cfg.latent is None:
+                    live_list = gen.live_block_list(
                         self._tables, self._nalloc, self._active,
                         self.block_tokens)
                     self._kv = {
                         "kv_blocks": int(
                             self._nalloc[self._active].sum()),
-                        "kv_tiles": int(blocks[1])}
+                        "kv_tiles": int(live_list[1])}
+                else:
+                    live_list = gen.live_lane_list(self._active)
+                    self._kv = {
+                        "live_lanes": int(self._active.sum()),
+                        "lane_tiles": int(live_list[1])}
                 self._dev = jax.device_put({
                     "tok": self._tok, "pos": self._pos,
                     "tables": self._tables, "active": self._active,
                     "keys": self._keys, "eidx": self._eidx,
                     "temps": self._temps, "topk": self._topk,
-                    "topp": self._topp, "blocks": blocks,
+                    "topp": self._topp, "live_list": live_list,
                 }, self.device)
         d = self._dev
         self._steps += 1
         n_live = int(self._active.sum())
         self._max_live = max(self._max_live, n_live)
         kv = self._kv
-        if kv:
+        if self.cfg.latent is None:
             self.ledger.kv_list(
                 kv["kv_blocks"], kv["kv_tiles"],
                 int(self._pos[self._active].sum()) + n_live,
-                d["blocks"][0].shape[2] * self.block_tokens)
+                d["live_list"][0].shape[2] * self.block_tokens)
+        else:
+            self.ledger.lane_list(kv["live_lanes"], kv["lane_tiles"],
+                                  d["live_list"][0].shape[1])
         with annotate("serve.step/dispatch", **kv), self._lock:
             # Armed (PTYPE_JITWATCH=1), the hot region makes any
             # unsanctioned implicit transfer into the decode step
@@ -1354,7 +1364,7 @@ class PagedGeneratorActor(GeneratorActor):
                     sampled, self.params, self.pool.banks,
                     d["tok"], d["pos"], d["tables"], d["active"],
                     d["keys"], d["eidx"], d["temps"], d["topk"],
-                    d["topp"], d["blocks"])
+                    d["topp"], d["live_list"])
         d["tok"] = nxt
         with annotate("serve.step/fetch"):
             # The host waits for the device here.

@@ -5,6 +5,7 @@ cache described by the model (``cache_spec``) and allocated from that by
 the pool, and the dropless router over a share of the experts."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -190,3 +191,161 @@ def test_rope_rotates_adjacent_pairs():
     np.testing.assert_allclose(got[..., 0::2], rot.real, atol=1e-6)
     np.testing.assert_allclose(got[..., 1::2], rot.imag, atol=1e-6)
     np.testing.assert_allclose(np.asarray(x[:, 0]), got[:, 0], atol=1e-7)
+
+
+# ---------------------------------------- the live-lane list (ISSUE 32)
+
+LATENT32 = dataclasses.replace(LATENT, **F32)
+LANES, LANE_NB = 10, 4  # ten slots, a reach of four blocks
+
+
+@pytest.mark.parametrize("active,tile,lanes,n_tiles", [
+    ([0, 1, 0, 0, 1, 1, 0], 2, [[1, 4], [5, 7], [7, 7], [7, 7]], 2),
+    ([1, 1, 1, 1, 0, 0, 0], 4, [[0, 1, 2, 3], [7, 7, 7, 7]], 1),
+    ([0, 0, 0], 2, [[3, 3], [3, 3]], 0),
+    ([1, 0, 1], 8, [[0, 2, 3]], 1),  # a tile is at most every slot
+    ([1] * 9, None, [list(range(8)), [8] + [9] * 7], 2),
+], ids=["scattered", "exactly-a-tile", "empty", "tile-over-slots",
+        "module-tile"])
+def test_live_lane_list_names_the_live_slots_in_order(active, tile,
+                                                     lanes, n_tiles):
+    """The live slots' ids in slot order, padded with the id no lane
+    has (``n_slots``); the trip count is the tiles in use; the shape
+    depends on ``n_slots`` and the tile alone."""
+    lst, n = gen.live_lane_list(np.asarray(active, bool), tile)
+    assert lst.dtype == np.int32 and n.dtype == np.int32
+    assert lst.tolist() == lanes and int(n) == n_tiles
+    assert gen.live_lane_list(np.zeros(len(active), bool),
+                              tile)[0].shape == lst.shape
+    if tile is None:
+        assert lst.shape[1] == gen.LIVE_TILE_LANES
+
+
+LIVE_SETS = {"one-lane": [6], "scattered-three": [1, 4, 9],
+             "exactly-a-tile": [0, 1, 2, 3, 5, 6, 8, 9],
+             "a-tile-and-one": [0, 1, 2, 3, 4, 5, 6, 7, 9],
+             "all": list(range(LANES))}
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_step():
+    """A decode step over ten lanes at ragged contexts past the
+    indexer's top-k, banks pre-filled with noise."""
+    params = tfm.init_params(jax.random.PRNGKey(0), LATENT32)
+    n_blocks = 1 + LANES * LANE_NB
+    banks = {n: jax.random.normal(
+        jax.random.PRNGKey(i), (LATENT32.n_layers, n_blocks, BT) + w,
+        jnp.float32) for i, (n, w) in
+        enumerate(tfm.cache_spec(LATENT32).items())}
+    tables = jnp.asarray(1 + np.random.default_rng(0).permutation(
+        LANES * LANE_NB).reshape(LANES, LANE_NB), jnp.int32)
+    pos0 = jnp.asarray([3, 40, 17, 60, 30, 9, 25, 50, 12, 33], jnp.int32)
+
+    @jax.jit
+    def step(banks, tok, pos, active, live_list):
+        wr_b = jnp.where(active, tables[jnp.arange(LANES), pos // BT], 0)
+        lg, banks, _ = gen.decode_step_banks(
+            params, tok, pos, LATENT32, banks, tables, wr_b, pos % BT,
+            live=active, live_list=live_list)
+        nxt = jnp.where(active, jnp.argmax(lg, -1).astype(jnp.int32), 0)
+        return lg, banks, nxt, jnp.where(active, pos + 1, pos)
+
+    return params, banks, tables, pos0, step
+
+
+@pytest.mark.parametrize("live", list(LIVE_SETS))
+def test_lane_list_step_equals_the_all_lanes_step_on_live_lanes(live):
+    """Three greedy decode steps of a latent stack through
+    ``decode_step_banks``, handed the live lanes' list (tiles of
+    ``LIVE_TILE_LANES``), against the same steps over all lanes: a live
+    lane's logits to float32 rounding and its tokens exactly; the banks
+    change in the rows the live lanes wrote and the trash block alone;
+    and the attention of a lane that is not listed reads zeros."""
+    params, banks0, tables, pos, step = _latent_step()
+    active = np.zeros(LANES, bool)
+    active[LIVE_SETS[live]] = True
+    lst, n_tiles = gen.live_lane_list(active)
+    assert int(n_tiles) == -(-len(LIVE_SETS[live]) // gen.LIVE_TILE_LANES)
+    act = jnp.asarray(active)
+    tok = jnp.arange(5, 5 + LANES, dtype=jnp.int32)
+    a = b = banks0
+    tok_a = tok_b = tok
+    pos_a = pos_b = pos
+    written = set()
+    for _ in range(3):
+        written |= {(int(tables[s, pos_a[s] // BT]), int(pos_a[s] % BT))
+                    for s in LIVE_SETS[live]}
+        lg_a, a, tok_a, pos_a = step(a, tok_a, pos_a, act, None)
+        lg_b, b, tok_b, pos_b = step(b, tok_b, pos_b, act, (lst, n_tiles))
+        np.testing.assert_allclose(np.asarray(lg_b)[active],
+                                   np.asarray(lg_a)[active], atol=2e-5)
+        np.testing.assert_array_equal(np.asarray(tok_b), np.asarray(tok_a))
+    assert len(written) == 3 * len(LIVE_SETS[live])
+    for name, bank in b.items():
+        got, was = np.asarray(bank), np.asarray(banks0[name]).copy()
+        for blk, off in written:
+            assert (got[:, blk, off] != was[:, blk, off]).any()
+            was[:, blk, off] = got[:, blk, off]
+        np.testing.assert_array_equal(got[:, 1:], was[:, 1:], err_msg=name)
+
+    layer = jax.tree.map(lambda w: w[0], params["blocks"])
+    x = jax.random.normal(jax.random.PRNGKey(9),
+                          (LANES, 1, LATENT32.d_model), jnp.float32)
+    q_nope, q_rope, _, qi, _, wi = sparse_mla.project(
+        x, layer, LATENT32, pos[:, None])
+    args = (q_nope, q_rope, qi, wi, banks0["ckv"][0], banks0["ki"][0],
+            tables, pos + 1, layer, LATENT32)
+    want = np.asarray(sparse_mla.attend_paged(*args))
+    got = np.asarray(sparse_mla.attend_paged(*args, lanes=(lst, n_tiles)))
+    np.testing.assert_allclose(got[active], want[active], atol=2e-6)
+    assert (got[~active] == 0).all() and np.abs(want).min() > 0
+
+
+def test_lane_list_step_is_one_program_whatever_is_live():
+    """The list's shape depends on ``n_slots`` alone, so the trip count
+    is data: every live set above ran one compiled step."""
+    _, banks, _, pos, step = _latent_step()
+    tok = jnp.zeros(LANES, jnp.int32)
+    before = step._cache_size()
+    for ids in LIVE_SETS.values():
+        active = np.zeros(LANES, bool)
+        active[ids] = True
+        step(banks, tok, pos, jnp.asarray(active),
+             gen.live_lane_list(active))
+    assert step._cache_size() - before <= 1
+
+
+def test_latent_step_keeps_its_temporaries_under_one_bank():
+    """``progaudit``'s bound on the paged decode step, for the latent
+    step over the lane list: banks donated, and every temporary of the
+    program together under a quarter of the smaller bank (the banks are
+    read inside the tile loop, where a bank taken into the loop's state
+    would be copied: PERF.md §6, PR 29)."""
+    from ptype_tpu import progaudit
+
+    cfg, n_blocks, B = LATENT32, 1024, 16
+    nb = cfg.max_seq // BT
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.PRNGKey(0), cfg))
+    banks = {n: jax.ShapeDtypeStruct((cfg.n_layers, n_blocks, BT) + w,
+                                     jnp.float32)
+             for n, w in tfm.cache_spec(cfg).items()}
+    i32 = jnp.int32
+    row = jax.ShapeDtypeStruct((B,), i32)
+    lst, _ = gen.live_lane_list(np.zeros(B, bool))
+
+    def decode_step(params, banks, tok, pos, tables, wr_b, wr_o,
+                    live_list):
+        return gen.decode_step_banks(params, tok, pos, cfg, banks, tables,
+                                     wr_b, wr_o, live_list=live_list)[:2]
+
+    smaller = min(int(np.prod(b.shape)) * 4 for b in banks.values())
+    rep = progaudit.audit(
+        decode_step,
+        (params, banks, row, row, jax.ShapeDtypeStruct((B, nb), i32), row,
+         row, (jax.ShapeDtypeStruct(lst.shape, i32),
+               jax.ShapeDtypeStruct((), i32))),
+        name="serve.latent_decode_step", donate_argnums=(1,),
+        expect_collectives=0, max_temp_bytes=smaller // 4)
+    rep.raise_if_failed()
+    assert 0 < rep.temp_bytes < smaller // 4

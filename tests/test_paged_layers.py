@@ -77,7 +77,7 @@ def test_paged_programs_match_contiguous_greedy(attend):
         wr_b = jnp.where(active, tables[jnp.arange(3), pos // BT], 0)
         lg, banks, _ = gen.decode_step_banks(
             params, tok, pos, CFG3, {"k": kb, "v": vb}, tables, wr_b,
-            pos % BT, blocks=blocks)
+            pos % BT, live_list=blocks)
         kb, vb = banks["k"], banks["v"]
         nxt = jnp.where(active, jnp.argmax(lg, -1).astype(jnp.int32), 0)
         return kb, vb, lg, nxt, jnp.where(active, pos + 1, pos)

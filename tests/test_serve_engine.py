@@ -4,6 +4,7 @@ chunked-prefill stall bounds, continuous-path sampling parity, typed
 admission sheds + the serve.admit chaos seam, and the gateway's
 pool-exhaustion / prefix-affinity load signals."""
 
+import dataclasses
 import threading
 import time
 
@@ -223,6 +224,114 @@ def test_ledger_counts_the_block_lists_tiles():
         assert len(spans) == 7
         assert all(s.attrs == {"kv_blocks": 1, "kv_tiles": 1}
                    for s in spans)
+    finally:
+        trace.disable()
+        actor.close()
+
+
+LATENT = dataclasses.replace(
+    tfm.preset("tiny", n_kv_heads=2, dtype=jnp.float32),
+    latent=tfm.LatentAttention(
+        q_rank=16, kv_rank=8, nope_dim=6, rope_dim=2, v_dim=8,
+        index_heads=2, index_dim=4, index_topk=8, index_rope_dim=2))
+
+
+def _solo_latent(params, prompt, new):
+    """One prompt alone through the paged programs, no list: one chunk,
+    then ``new - 1`` decode steps over a one-lane table."""
+    n, bt = prompt.shape[1], 16
+    nb = LATENT.max_seq // bt
+    banks = {name: jnp.zeros((LATENT.n_layers, nb + 1, bt) + w,
+                             jnp.float32)
+             for name, w in tfm.cache_spec(LATENT).items()}
+    table = jnp.arange(1, nb + 1, dtype=jnp.int32)
+    toks = jnp.zeros((1, 64), jnp.int32).at[:, :n].set(prompt)
+    lg, banks, _ = gen.prefill_chunk_banks(
+        params, toks, jnp.int32(0), jnp.int32(n), LATENT, banks, table)
+    out = [int(jnp.argmax(lg[0]))]
+    for pos in range(n, n + new - 1):
+        lg, banks, _ = gen.decode_step_banks(
+            params, jnp.asarray(out[-1:], jnp.int32), jnp.asarray([pos]),
+            LATENT, banks, table[None], table[None][:, pos // bt],
+            jnp.asarray([pos % bt]))
+        out.append(int(jnp.argmax(lg[0])))
+    return out
+
+
+def test_latent_decode_runs_over_the_live_lanes(monkeypatch,
+                                                jitwatch_watchdog):
+    """ISSUE 32, the block-list test on a latent engine: rows are
+    admitted and retire while others decode (tiles of two lanes over
+    four slots, so the trip count walks 0..2). Greedy tokens equal each
+    prompt's solo run through the paged programs; after EVERY rebuild
+    the list names exactly the live slots, in slot order, padded with
+    ``n_slots``; the step compiled once for all the trip counts; and
+    the ledger and the dispatch span carry the list's counts."""
+    from ptype_tpu import trace
+
+    jw = jitwatch_watchdog
+    monkeypatch.setattr(gen, "LIVE_TILE_LANES", 2)
+    build, seen, wrong = gen.live_lane_list, [], []
+    holder = {}
+
+    def checked(active, tile=None):
+        lst, n_tiles = build(active, tile)
+        eng = holder["actor"]
+        want = [s for s in sorted(eng._slot_state) if active[s]]
+        flat = lst.reshape(-1)
+        if (lst.shape != (2, 2) or flat[:len(want)].tolist() != want
+                or (flat[len(want):] != eng.n_slots).any()
+                or int(n_tiles) != -(-len(want) // 2)):
+            wrong.append((want, lst, n_tiles))
+        seen.append(int(n_tiles))
+        return lst, n_tiles
+
+    monkeypatch.setattr(gen, "live_lane_list", checked)
+    before = jw.compiles().get("engine_step", 0)
+    rec = trace.enable("lane-list-test")
+    actor = holder["actor"] = PagedGeneratorActor(
+        LATENT, n_slots=4, block_tokens=16, prefill_chunk=32)
+    try:
+        lens = (5, 20, 33, 9, 41, 12)
+        news = (6, 9, 5, 12, 7, 10)
+        rng = np.random.default_rng(11)
+        prompts = [_prompt(n, rng) for n in lens]
+        outs = [None] * len(prompts)
+
+        def call(i):
+            time.sleep(0.04 * (i % 3))
+            outs[i] = actor.Generate(prompts[i], news[i])
+
+        threads = [threading.Thread(target=call, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        for i, p in enumerate(prompts):
+            assert np.asarray(outs[i])[0].tolist() == _solo_latent(
+                actor.params, p, news[i]), f"req {i}"
+        assert not wrong, wrong[0]
+        assert actor.Info()["max_live_slots"] >= 3
+        assert {1, 2} <= set(seen), sorted(set(seen))
+        assert jw.compiles()["engine_step"] - before == 1, jw.compiles()
+        assert actor.pool.check_invariants() == []
+        summ = actor.ledger.summary()
+        assert 1.0 <= summ["lane_tiles"] <= 2.0
+        assert 0.5 <= summ["lane_tile_fill"] <= 1.0
+        assert "kv_tiles" not in summ
+        spans = [s for s in rec.spans()
+                 if s.name == "serve.step/dispatch"]
+        assert spans and all(
+            set(s.attrs) == {"live_lanes", "lane_tiles"}
+            and s.attrs["lane_tiles"] == -(-s.attrs["live_lanes"] // 2)
+            for s in spans)
+        steps = len(spans)
+        assert summ["lane_tiles"] == round(
+            sum(s.attrs["lane_tiles"] for s in spans) / steps, 3)
+        assert summ["lane_tile_fill"] == round(
+            sum(s.attrs["live_lanes"] for s in spans)
+            / (2 * sum(s.attrs["lane_tiles"] for s in spans)), 4)
     finally:
         trace.disable()
         actor.close()
