@@ -382,6 +382,11 @@ class ServingLedger:
         #: A latent step's live-lane list (lane_list): steps, tiles
         #: run, lanes live and lanes the tiles covered; absent likewise.
         self._lane_list = [0, 0, 0, 0]
+        #: Two kinds of cache in one engine (cache): steps, blocks the
+        #: live rows hold in the full and in the window pool, the
+        #: blocks one kind of cache would hold for them, and window
+        #: blocks given back; absent likewise.
+        self._cache = [0, 0, 0, 0, 0]
 
     # --------------------------------------------------- request seams
 
@@ -499,6 +504,26 @@ class ServingLedger:
         with self._lock:
             for i, v in enumerate((1, tiles, live, tiles * tile)):
                 self._lane_list[i] += v
+
+    def cache(self, full_blocks: int, window_blocks: int,
+              window_freed: int, uniform_blocks: int) -> None:
+        """One decode iteration of an engine with two kinds of cache:
+        the blocks its live rows hold in the full layers' pool and in
+        the window layers', the window blocks given back since the
+        last record, and the blocks a cache of one kind (every layer
+        keeping every token) would hold for the same rows. Running
+        totals behind ``summary()``'s four of the same names (means a
+        step; ``window_freed`` the total) and a ``serve.cache`` record
+        through the one seam."""
+        with self._lock:
+            for i, v in enumerate((1, full_blocks, window_blocks,
+                                   uniform_blocks, window_freed)):
+                self._cache[i] += v
+        with trace.span("serve.cache", full_blocks=int(full_blocks),
+                        window_blocks=int(window_blocks),
+                        window_freed=int(window_freed),
+                        uniform_blocks=int(uniform_blocks)):
+            pass
 
     def shed_untracked(self) -> None:
         """A shed before any record existed (the chaos admit seam)."""
@@ -642,7 +667,13 @@ class ServingLedger:
                 self._kv_list
             lane_steps, lane_tiles, lanes_live, lanes_covered = \
                 self._lane_list
+            c_steps, c_full, c_window, c_uniform, c_freed = self._cache
         out = {}
+        if c_steps:
+            out["full_blocks"] = round(c_full / c_steps, 2)
+            out["window_blocks"] = round(c_window / c_steps, 2)
+            out["uniform_blocks"] = round(c_uniform / c_steps, 2)
+            out["window_freed"] = c_freed
         if kv_steps:
             out["kv_blocks"] = round(kv_blocks / kv_steps, 2)
             out["kv_tiles"] = round(kv_tiles / kv_steps, 3)

@@ -305,7 +305,8 @@ LIVE_TILE_LANES = 8
 
 
 def live_block_list(tables, nalloc, active, block_tokens: int,
-                    tile: int | None = None):
+                    tile: int | None = None, first=None,
+                    row_blocks: int | None = None):
     """The K,V blocks the live rows hold, as the decode step's
     attention reads them (:func:`_live_block_attention`). Host side,
     numpy: ``tables`` (n_slots, nb) block ids in position order,
@@ -317,12 +318,21 @@ def live_block_list(tables, nalloc, active, block_tokens: int,
     ``n_tiles`` int32 is the number of tiles in use, the step's trip
     count. A block two rows share (prefix reuse) is listed once a row.
     ``max_tiles * tile`` covers every lane at its whole reach, so the
-    shape never changes while the engine lives."""
+    shape never changes while the engine lives.
+
+    A window layer's table (``serve_engine``: the blocks wholly behind
+    a row's window are given back) is listed from ``first`` (n_slots,),
+    each row's first block still held; ``row_blocks`` is then the most
+    a row holds, so the list covers ``n_slots * row_blocks`` blocks and
+    not every lane's reach."""
     ns, nb = tables.shape
-    tile = min(int(tile or LIVE_TILE_BLOCKS), ns * nb)
-    max_tiles = -(-(ns * nb) // tile)
+    cover = ns * min(nb, int(row_blocks or nb))
+    tile = min(int(tile or LIVE_TILE_BLOCKS), cover)
+    max_tiles = -(-cover // tile)
     held = (np.arange(nb)[None, :] < np.asarray(nalloc)[:, None]) \
         & np.asarray(active, bool)[:, None]
+    if first is not None:
+        held &= np.arange(nb)[None, :] >= np.asarray(first)[:, None]
     lane, col = np.nonzero(held)  # row-major: row after row
     n = lane.size
     blocks = np.zeros((3, max_tiles * tile), np.int32)
@@ -352,13 +362,17 @@ def live_lane_list(active, tile: int | None = None):
     return lanes.reshape(-1, tile), np.int32(-(-ids.size // tile))
 
 
-def _live_block_attention(q, kf, vf, base, blocks, limits):
+def _live_block_attention(q, kf, vf, base, blocks, limits,
+                          window: int = 0, scope: str = "attn"):
     """Decode attention over the blocks live rows hold: work follows
     Σ live context, not lanes x reach. q: (B, 1, H, Dh); ``kf``/``vf``:
     the flat banks ``(L * n_blocks, block_tokens, Kh, Dh)``, ``base``
     the layer's first row in them; ``blocks`` as
     :func:`live_block_list` gives it; ``limits`` (B,): lane ``b``
-    attends positions ``< limits[b]`` of its own blocks.
+    attends positions ``< limits[b]`` of its own blocks and, in a
+    window layer, ``>= limits[b] - window`` (the list then holds the
+    window pool's blocks). ``scope`` names the loop in a device trace
+    (``attn_window`` / ``attn_full`` in a stack with attention kinds).
 
     One loop over the list's tiles in use (a ``while`` whose trip count
     is data, so ONE compiled program whatever the load): a tile's
@@ -377,7 +391,7 @@ def _live_block_attention(q, kf, vf, base, blocks, limits):
     G = H // Kh
     S = lst.shape[2] * bt
     f32 = jnp.float32
-    with jax.named_scope("attn"):
+    with jax.named_scope(scope):
         qg = q.reshape(B, Kh, G, Dh)
         lanes = jnp.arange(B, dtype=jnp.int32)
         limits = jnp.asarray(limits, jnp.int32)
@@ -394,7 +408,10 @@ def _live_block_attention(q, kf, vf, base, blocks, limits):
             owner = jnp.repeat(owner, bt)
             at = (first[:, None] + offs[None, :]).reshape(S)
             mask = ((owner[None, :] == lanes[:, None])
-                    & (at[None, :] < limits[:, None]))[:, None, None, :]
+                    & (at[None, :] < limits[:, None]))
+            if window:
+                mask &= at[None, :] >= limits[:, None] - window
+            mask = mask[:, None, None, :]
             s = jnp.where(mask, s, f32(-1e30))
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
             # A lane with nothing in the tiles so far has m_new at the
@@ -415,6 +432,89 @@ def _live_block_attention(q, kf, vf, base, blocks, limits):
         return o.astype(q.dtype).reshape(B, 1, H, Dh)
 
 
+#: Blocks in one tile of a full-attention layer's table walk
+#: (:func:`_table_attention`): 1,024 keys at 16 a block, so a
+#: 512-query chunk's float32 scores are 134 MB at 64 heads whatever
+#: the context.
+TABLE_TILE_BLOCKS = 64
+
+
+def _table_attention(q, kf, vf, tables, limits, window: int, scope: str):
+    """Attention through block tables in a stack with attention kinds,
+    for queries that are many to a table (a prefill chunk) or rows
+    with no block list: the tables are walked a tile of blocks at a
+    time and folded into a float32 running softmax, the trip count
+    from the data, so ONE compiled program serves every context up to
+    the reach and its temporaries do not grow with it. q: (B, Q, H,
+    Dh); ``kf``/``vf`` the flat banks, ``tables`` (B, nb) their rows
+    in position order (the layer's base added); ``limits`` (B,) or
+    (B, Q): a query attends positions ``< limit`` and, with ``window``,
+    ``>= limit - window``.
+
+    A full layer (``window`` 0) walks from block 0 in tiles of
+    :data:`TABLE_TILE_BLOCKS` up to the largest limit. A window layer
+    reads ONE tile, the ``window + Q`` positions its queries can see
+    between them, starting at the first block any of them sees:
+    what lies behind it was given back (the table names the trash
+    block there) and is masked."""
+    B, Q, H, Dh = q.shape
+    nb = tables.shape[1]
+    bt, Kh = kf.shape[1], kf.shape[2]
+    G = H // Kh
+    f32 = jnp.float32
+    limits = jnp.asarray(limits, jnp.int32)
+    hi = limits[:, None] if limits.ndim == 1 else limits  # (B, 1|Q)
+    top = jnp.max(hi, axis=1)                             # (B,)
+    if window:
+        tile = min(nb, (window + Q - 1) // bt + 2)
+        # The lowest position a REAL query sees (pads have limit 0).
+        low = jnp.min(jnp.where(hi > 0, hi, top[:, None]), axis=1)
+        col0 = jnp.maximum(low - window, 0) // bt          # (B,)
+        n_tiles = jnp.int32(1)
+    else:
+        tile = min(nb, TABLE_TILE_BLOCKS)
+        col0 = jnp.zeros((B,), jnp.int32)
+        n_tiles = -(-jnp.max(top) // (tile * bt))
+    S = tile * bt
+    with jax.named_scope(scope):
+        qg = q.reshape(B, Q, Kh, G, Dh)
+        offs = jnp.arange(S, dtype=jnp.int32)
+
+        def fold(t, carry):
+            m, l, acc = carry
+            cols = col0[:, None] + t * tile + jnp.arange(tile)[None, :]
+            with jax.named_scope("kv_gather"):
+                ids = jnp.take_along_axis(
+                    tables, jnp.minimum(cols, nb - 1), axis=1)
+                ks = kf[ids].reshape(B, S, Kh, Dh)
+                vs = vf[ids].reshape(B, S, Kh, Dh)
+            s = jnp.einsum("bqkgd,bskd->bkgqs", qg, ks).astype(f32)
+            s = s / jnp.sqrt(f32(Dh))
+            # Columns past the table sit past every limit.
+            at = (col0[:, None] + t * tile) * bt + offs[None, :]  # (B, S)
+            mask = at[:, None, :] < hi[:, :, None]             # (B, Q, S)
+            if window:
+                mask &= at[:, None, :] >= hi[:, :, None] - window
+            mask = mask[:, None, None, :, :]
+            s = jnp.where(mask, s, f32(-1e30))
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.where(mask, jnp.exp(s - m_new[..., None]), f32(0))
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.sum(p, axis=-1)
+            pv = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(q.dtype), vs,
+                            preferred_element_type=f32)
+            return m_new, l, acc * alpha[..., None] + pv
+
+        m, l, acc = lax.fori_loop(
+            0, n_tiles, fold,
+            (jnp.full((B, Kh, G, Q), -1e30, f32),
+             jnp.zeros((B, Kh, G, Q), f32),
+             jnp.zeros((B, Kh, G, Q, Dh), f32)))
+        o = acc / jnp.where(l > 0, l, f32(1))[..., None]
+        o = jnp.transpose(o, (0, 3, 1, 2, 4))  # (B, Q, Kh, G, Dh)
+        return o.astype(q.dtype).reshape(B, Q, H, Dh)
+
+
 def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                   wr_o, limits, moe_capacity, live=None,
                   chunk: bool = False, live_list=None):
@@ -430,7 +530,16 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     stack and wrote all of it back, and the program copied the banks
     again: a scanned output cannot alias a donated input.) A stack of
     several groups (``tfm.layer_groups``) is one scan a group, the
-    same carry walking through them.
+    same carry walking through them. In a stack that states attention
+    kinds (``tfm.cache_layers``: window and full layers, a run of one
+    kind a group) there are two caches, and ``banks``, ``tables``,
+    ``wr_b`` and ``live_list`` are each a dict ``{"full": ...,
+    "window": ...}`` of what is described here for one: a layer reads
+    and writes its own kind's, at its rank among that kind's layers.
+    A decode step attends over each kind's block list
+    (:func:`_live_block_attention`, a window layer's with the lower
+    limit in its mask); a prefill chunk through the tables
+    (:func:`_table_attention`).
 
     ``tokens``/``positions``/``wr_b``/``wr_o`` (B, Q): each position's
     cache row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name
@@ -447,33 +556,63 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     final norm, banks, load)``; ``load`` is a dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
     ``live`` (B, Q) marks, if given), None without one."""
-    shapes = {n: b.shape for n, b in banks.items()}
-    L, n_blocks = next(iter(shapes.values()))[:2]
-    flat = {n: b.reshape((L * n_blocks,) + b.shape[2:])
-            for n, b in banks.items()}
+    def flat_view(bs):
+        return {n: b.reshape((-1,) + b.shape[2:]) for n, b in bs.items()}
+
+    def as_banks(fs, like):
+        return {n: f.reshape(like[n].shape) for n, f in fs.items()}
+
+    kinds = tfm.cache_layers(cfg)
+    if kinds is None:
+        n_blocks = next(iter(banks.values())).shape[1]
+        flat = flat_view(banks)
+    else:
+        # Two kinds of cache: each per-kind argument is a dict of the
+        # kind's own ("full", "window"), and a layer's base is its
+        # rank among the layers of its kind times that kind's blocks.
+        n_blocks = {k: banks[k]["k"].shape[1] for k in kinds}
+        flat = {k: flat_view(banks[k]) for k in kinds}
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(cfg.dtype)
 
     if cfg.latent is None:
         sin, cos = tfm.rope_tables(cfg, positions=positions)
 
-        def attention(x, bf, layer, base):
-            q, k, v = tfm.qkv_proj(x, layer, cfg, sin, cos)
-            kf, vf = bf["k"], bf["v"]
+        def attention(x, bf, layer, base, window=None):
+            # ``window`` None: one kind of cache; else this group's
+            # kind picks its own of each per-kind argument.
+            kind = None if window is None else (
+                "window" if window else "full")
+            own, wb = (bf, wr_b) if kind is None else (bf[kind],
+                                                       wr_b[kind])
+            q, k, v = tfm.qkv_proj(
+                x, layer, cfg, sin, cos,
+                rotate=kind != "full" or not cfg.nope_full)
+            kf, vf = own["k"], own["v"]
             with jax.named_scope("kv_write"):
-                kf = kf.at[base + wr_b, wr_o].set(k)
-                vf = vf.at[base + wr_b, wr_o].set(v)
+                kf = kf.at[base + wb, wr_o].set(k)
+                vf = vf.at[base + wb, wr_o].set(v)
+            own = {"k": kf, "v": vf}
+            if kind is None:
+                if live_list is not None:
+                    o = _live_block_attention(q, kf, vf, base, live_list,
+                                              limits)
+                else:
+                    o = _paged_attention_gather(q, kf, vf, base + tables,
+                                                limits, cfg)
+                return o, own
             if live_list is not None:
-                o = _live_block_attention(q, kf, vf, base, live_list,
-                                          limits)
+                o = _live_block_attention(
+                    q, kf, vf, base, live_list[kind], limits,
+                    window=window, scope="attn_" + kind)
             else:
-                o = _paged_attention_gather(q, kf, vf, base + tables,
-                                            limits, cfg)
-            return o, {"k": kf, "v": vf}
+                o = _table_attention(q, kf, vf, base + tables[kind],
+                                     limits, window, "attn_" + kind)
+            return o, {**bf, kind: own}
     else:
         from ptype_tpu.models import sparse_mla
 
-        def attention(x, bf, layer, base):
+        def attention(x, bf, layer, base, window=None):
             q_nope, q_rope, ckv, qi, ki, wi = sparse_mla.project(
                 x, layer, cfg, positions)
             with jax.named_scope("kv_write"):
@@ -485,25 +624,37 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                                         lanes=live_list)
             return o, {"ckv": cf, "ki": kif}
 
-    def body(carry, inputs):
-        x, bf = carry
-        layer, base = inputs  # base: the layer's first row of the view
-        o, bf = attention(x, bf, layer, base)
-        x = tfm.attn_residual(x, o, layer, cfg)
-        x, _aux, load = tfm.mlp_residual(
-            x, layer, cfg, moe_capacity=moe_capacity, live=live)
-        return (x, bf), load
+    def layers_of(window):
+        def body(carry, inputs):
+            x, bf = carry
+            layer, base = inputs  # base: the layer's first row of the view
+            o, bf = attention(x, bf, layer, base, window)
+            x = tfm.attn_residual(x, o, layer, cfg)
+            x, _aux, load = tfm.mlp_residual(
+                x, layer, cfg, moe_capacity=moe_capacity, live=live)
+            return (x, bf), load
+        return body
 
     load = None
     for stacked, first, n in tfm.block_groups(params, cfg):
+        if kinds is None:
+            window = None
+            bases = jnp.arange(first, first + n,
+                               dtype=jnp.int32) * n_blocks
+        else:
+            window = cfg.attn_windows[first]
+            kind = "window" if window else "full"
+            rank = kinds[kind].index(first)
+            bases = jnp.arange(rank, rank + n,
+                               dtype=jnp.int32) * n_blocks[kind]
         (x, flat), loads = lax.scan(
-            body, (x, flat),
-            (stacked,
-             jnp.arange(first, first + n, dtype=jnp.int32) * n_blocks))
+            layers_of(window), (x, flat), (stacked, bases))
         if loads is not None:
             total = jnp.sum(loads, axis=0)
             load = total if load is None else load + total
-    return x, {n: b.reshape(shapes[n]) for n, b in flat.items()}, load
+    if kinds is None:
+        return x, as_banks(flat, banks), load
+    return x, {k: as_banks(flat[k], banks[k]) for k in kinds}, load
 
 
 def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
@@ -533,12 +684,15 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     (``sparse_mla.attend_paged``), so their cost follows the rows in
     flight, and an inactive lane's attention reads zeros. Without it
     every row gathers or indexes its whole table, reach and all, live
-    or not. Returns
+    or not. In a stack with attention kinds ``banks``, ``tables``,
+    ``wr_blocks`` and ``live_list`` are dicts of the two caches' own
+    (:func:`_paged_layers`). Returns
     ``(logits (B, V), banks, load)``, ``load`` as :func:`_paged_layers`
     gives it (of the rows ``live`` (B,) marks, if given)."""
     x, banks, load = _paged_layers(
         params, token[:, None], pos[:, None], cfg, banks, tables,
-        wr_blocks[:, None], wr_off[:, None], pos + 1, token.shape[0],
+        jax.tree.map(lambda w: w[:, None], wr_blocks), wr_off[:, None],
+        pos + 1, token.shape[0],
         None if live is None else live[:, None], live_list=live_list)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -559,16 +713,20 @@ def prefill_chunk_banks(params: dict, tokens: jax.Array,
     table, i.e. query ``c`` sees every previously-written position plus
     the chunk through itself — mathematically the same full causal
     prefill, split at chunk boundaries (with an indexer, each query
-    selects among exactly those). Returns ``(logits (1, V) at the
+    selects among exactly those). In a stack with attention kinds
+    ``banks`` and ``table`` are dicts of the two caches' own, and a
+    window layer's query sees the last ``cfg.window`` positions alone.
+    Returns ``(logits (1, V) at the
     chunk's LAST REAL token, banks, load)`` — only the final chunk's
     logits feed the first sampled token."""
     B, C = tokens.shape
-    bt = next(iter(banks.values())).shape[2]
-    nb = table.shape[0]
+    bt = jax.tree.leaves(banks)[0].shape[2]
+    nb = jax.tree.leaves(table)[0].shape[0]
     pos_vec = start + jnp.arange(C)  # (C,) positions of chunk columns
     valid = jnp.arange(C) < length
-    wr_b = jnp.where(valid, table[jnp.clip(pos_vec // bt, 0, nb - 1)],
-                     0)
+    wr_b = jax.tree.map(
+        lambda t: jnp.where(valid, t[jnp.clip(pos_vec // bt, 0, nb - 1)],
+                            0), table)
     wr_o = pos_vec % bt
     # Per-query limits: pad queries attend nothing (their garbage
     # outputs are never read — x_last indexes the last REAL token).
@@ -578,9 +736,10 @@ def prefill_chunk_banks(params: dict, tokens: jax.Array,
     cap = C if cfg.n_experts else None
 
     x, banks, load = _paged_layers(
-        params, tokens, pos_vec[None], cfg, banks, table[None],
-        wr_b[None], wr_o[None], limits[None], cap, live=valid[None],
-        chunk=True)
+        params, tokens, pos_vec[None], cfg, banks,
+        jax.tree.map(lambda t: t[None], table),
+        jax.tree.map(lambda w: w[None], wr_b), wr_o[None], limits[None],
+        cap, live=valid[None], chunk=True)
     with jax.named_scope("head"):
         x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
         x_last = x[jnp.arange(B), jnp.asarray(length)[None] - 1]
@@ -605,9 +764,10 @@ def _gqa_one_group(cfg: tfm.TransformerConfig, what: str) -> None:
     head for one stacked group of layers: say so, not a shape error."""
     if not cfg.plain:
         raise ValueError(
-            f"{what} needs a GQA stack of one group; this configuration "
-            f"has latent attention, several layer groups or a dropless "
-            f"router, which only "
+            f"{what} needs a GQA stack of one group with one kind of "
+            f"cache; this configuration has latent attention, several "
+            f"layer groups, a dropless router or window layers beside "
+            f"full ones (two caches), which only "
             f"the plain paged decode step and prefill chunk run "
             f"(its own next-token module would be the drafter)")
 

@@ -17,8 +17,11 @@ anything:
   125M optimus preset and the Llama-3-8B FSDP baseline config. Beside
   it, for serving: latent K,V attention behind a sparse-attention
   indexer (models/sparse_mla.py), a stack of several layer groups
-  (:func:`layer_groups`: one scan a run of identical layers) and a
-  dropless router over a share of the experts (:func:`_moe_dropless`).
+  (:func:`layer_groups`: one scan a run of identical layers), layers
+  of a sliding window among full ones with a cache a kind
+  (``attn_windows``, :func:`cache_layers`), a stated head width and a
+  per-head q/k norm, and a dropless router over a share of the experts
+  (:func:`_moe_dropless`).
 - **Sharding by annotation.** :func:`param_specs` returns a PartitionSpec
   pytree (fsdp/model axes); the train layer jits with those shardings and
   GSPMD inserts the collectives (ICI-mapped; scaling-book recipe).
@@ -32,7 +35,9 @@ anything:
   (train/trainer.py), ``sample`` (serve_engine/engine.py); inside
   ``mlp``, a dropless expert layer's ``router``, ``experts`` and
   ``shared_expert``; latent attention's ``index`` and ``select``
-  (models/sparse_mla.py). A scope is
+  (models/sparse_mla.py); in a stack that states attention kinds the
+  paged programs' attention is ``attn_window`` or ``attn_full`` by
+  the layer's kind, not ``attn`` (models/generate.py). A scope is
   HLO metadata only: it names the operation in a device trace (the
   profiler's ``tf_op`` stat) and changes no compiled program.
   ``benchmark/readers/scope_time_pct.py`` buckets device time by them.
@@ -159,18 +164,55 @@ class TransformerConfig:
     #: keeps ``n_experts`` outputs and what the absent experts would
     #: add is left out. None → all of them.
     experts_held: tuple[int, int] | None = None
+    #: Width of one attention head; None → ``d_model // n_heads``.
+    d_head: int | None = None
+    #: The attention kind of each layer, ``n_layers`` entries: the keys
+    #: a query sees, itself included (a sliding window), or 0 for all
+    #: of them (full attention). None → every layer full, one kind of
+    #: cache. The windowed layers of one stack share one window. Such
+    #: a stack is served (models/generate.py, serve_engine/): its
+    #: window layers keep their own, bounded cache (:func:`cache_spec`).
+    attn_windows: tuple[int, ...] | None = None
+    #: Per-head RMSNorm of q and k (one scale vector of ``head_dim`` a
+    #: layer each, shared by the heads), before any rotation.
+    qk_norm: bool = False
+    #: Full-attention layers of a stack with ``attn_windows`` carry no
+    #: rotary positions (their window layers do).
+    nope_full: bool = False
+
+    def __post_init__(self):
+        w = self.attn_windows
+        if w is None:
+            return
+        if self.latent is not None:
+            raise ValueError("attention kinds are GQA's: a latent cache "
+                             "has its own selection of keys")
+        if len(w) != self.n_layers or any(int(x) < 0 for x in w):
+            raise ValueError(
+                f"attn_windows states {len(w)} layer(s) {w}; the stack "
+                f"has {self.n_layers}, each a window >= 1 or 0 for full")
+        if len({int(x) for x in w if x}) > 1:
+            raise ValueError(
+                f"the window layers of one stack share one window (one "
+                f"bounded pool); attn_windows states {sorted(set(w))}")
 
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
     @property
+    def window(self) -> int:
+        """The one window of this stack's window layers; 0 without."""
+        return max(self.attn_windows or (0,))
+
+    @property
     def plain(self) -> bool:
-        """A GQA stack of one group with the capacity router or none:
-        what training, sharding, speculation and the contiguous cache
-        are written for."""
+        """A GQA stack of one group, every layer full attention, with
+        the capacity router or none: what training, sharding,
+        speculation and the contiguous cache are written for."""
         return (self.latent is None and self.moe_router == "softmax"
-                and not (self.n_experts and self.n_dense_layers))
+                and not (self.n_experts and self.n_dense_layers)
+                and self.attn_windows is None and not self.qk_norm)
 
     @property
     def expert_ff(self) -> int:
@@ -182,7 +224,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
 
 
 #: Named presets for the BASELINE.json configs. "tiny" is the test-size
@@ -285,13 +327,26 @@ def init_params(rng: jax.Array, cfg: TransformerConfig) -> dict:
 
 
 def layer_groups(cfg: TransformerConfig) -> tuple[tuple[str, int], ...]:
-    """The layer stack as runs of identical layers, ``(kind, count)``
-    with kind ``"dense"`` or ``"experts"``: each run is one
-    ``lax.scan`` over its own stacked parameters."""
-    if cfg.n_experts and cfg.n_dense_layers:
-        return (("dense", cfg.n_dense_layers),
-                ("experts", cfg.n_layers - cfg.n_dense_layers))
-    return (("experts" if cfg.n_experts else "dense", cfg.n_layers),)
+    """The layer stack as runs of identical layers, ``(kind, count)``:
+    each run is one ``lax.scan`` over its own stacked parameters. The
+    kind is the MLP's, ``"dense"`` or ``"experts"``, and, in a stack
+    that states attention kinds (``cfg.attn_windows``), the
+    attention's after a ``+``: ``L`` for a window layer, ``G`` for a
+    full one (``"experts+L"``), so a period ``LLLG`` over expert
+    layers is two runs a period."""
+    nd = cfg.n_dense_layers if cfg.n_experts else cfg.n_layers
+    kinds = ["dense" if l < nd else "experts"
+             for l in range(cfg.n_layers)]
+    if cfg.attn_windows is not None:
+        kinds = [f"{k}+{'L' if w else 'G'}"
+                 for k, w in zip(kinds, cfg.attn_windows)]
+    runs: list[list] = []
+    for k in kinds:
+        if runs and runs[-1][0] == k:
+            runs[-1][1] += 1
+        else:
+            runs.append([k, 1])
+    return tuple((k, n) for k, n in runs)
 
 
 def block_groups(params: dict, cfg: TransformerConfig) -> list[tuple]:
@@ -317,12 +372,31 @@ def cache_spec(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
     """What one token holds in one layer's cache: named arrays and
     their shapes. The block pool allocates its banks from this, the
     migrator packs by it, and the paged programs carry the banks as
-    one dict with these names (every layer of a model holds the same)."""
+    one dict with these names. Every layer holds the same arrays a
+    token; how MANY tokens a layer holds is its kind's
+    (:func:`cache_layers`)."""
     if cfg.latent is not None:
         return {"ckv": (cfg.latent.cache_dim,),
                 "ki": (cfg.latent.index_dim,)}
     return {"k": (cfg.kv_heads, cfg.head_dim),
             "v": (cfg.kv_heads, cfg.head_dim)}
+
+
+def cache_layers(cfg: TransformerConfig) -> dict[str, tuple[int, ...]] | None:
+    """The layers of each kind of cache, in a stack that states
+    attention kinds: ``{"full": layers, "window": layers}``. A full
+    layer keeps every token of a sequence; a window layer only the
+    last ``cfg.window`` a query can still see, so its blocks wholly
+    behind the window are given back as the sequence advances. Each
+    kind has banks, a pool and a block table a sequence of its own
+    (``serve_engine``), each bank with as many layers as the kind has.
+    None where every layer holds the same (one pool)."""
+    if cfg.attn_windows is None:
+        return None
+    return {"full": tuple(l for l, w in enumerate(cfg.attn_windows)
+                          if not w),
+            "window": tuple(l for l, w in enumerate(cfg.attn_windows)
+                            if w)}
 
 
 def scaled_normal(key, shape, scale, dtype) -> jax.Array:
@@ -331,6 +405,7 @@ def scaled_normal(key, shape, scale, dtype) -> jax.Array:
 
 def _init_mlp(key, cfg: TransformerConfig, kind: str, n: int) -> dict:
     """The MLP half of ``n`` stacked layers of one kind."""
+    kind = kind.split("+")[0]
     D, pd = cfg.d_model, cfg.param_dtype
     resid = 0.02 / (2.0 * cfg.n_layers) ** 0.5
     ks = jax.random.split(key, 8)
@@ -360,8 +435,8 @@ def _init_mlp(key, cfg: TransformerConfig, kind: str, n: int) -> dict:
 
 
 def _init_grouped(rng: jax.Array, cfg: TransformerConfig) -> dict:
-    """Parameters of a stack with several groups, latent attention or a
-    dropless router: one stacked dict a group."""
+    """Parameters of a stack with several groups, latent attention,
+    attention kinds or a dropless router: one stacked dict a group."""
     D, V, pd = cfg.d_model, cfg.vocab_size, cfg.param_dtype
     H, K, Dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
     resid = 0.02 / (2.0 * cfg.n_layers) ** 0.5
@@ -380,6 +455,9 @@ def _init_grouped(rng: jax.Array, cfg: TransformerConfig) -> dict:
                     "wk": norm(ks[1], (n, D, K, Dh), 0.02),
                     "wv": norm(ks[2], (n, D, K, Dh), 0.02),
                     "wo": norm(ks[3], (n, H, Dh, D), resid)}
+            if cfg.qk_norm:
+                attn.update(q_norm=jnp.ones((n, Dh), pd),
+                            k_norm=jnp.ones((n, Dh), pd))
         blocks.append({**attn, **_init_mlp(km, cfg, kind, n)})
     k0, k1 = jax.random.split(jax.random.fold_in(rng, 0))
     params = {"embed": norm(k0, (V, D), 0.02),
@@ -401,8 +479,10 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int,
     if n_params is None and not cfg.plain:
         raise ValueError(
             "flops_per_token counts the GQA block of a stack with one "
-            "group; a latent-attention or grouped stack is not trained "
-            "here, and its serving counts are the benchmark family's")
+            "group, every layer full attention; a latent-attention or "
+            "grouped stack, or one with attention kinds (windows, a "
+            "q/k norm), is not trained here, and its serving counts "
+            "are the benchmark family's")
     if n_params is None:
         # ACTIVE matmul params only (norms excluded — negligible; for
         # MoE, the top-k routed experts count, not the full bank).
@@ -685,16 +765,25 @@ def _moe_dropless(h, layer, cfg: TransformerConfig, live=None):
 
 
 @jax.named_scope("qkv")
-def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos):
+def qkv_proj(x, layer, cfg: TransformerConfig, sin, cos,
+             rotate: bool = True):
     """Pre-norm + Q/K/V projections + RoPE. x: (B, S, D) → three
     (B, S, H|K, Dh). Shared by training forward and the KV-cache
     prefill/decode paths (models/generate.py) — the block math lives
-    here once."""
+    here once. With ``cfg.qk_norm`` each head of q and k is
+    RMS-normed over its ``head_dim`` values first; ``rotate`` False
+    leaves the rotary positions out (a full-attention layer of a
+    ``cfg.nope_full`` stack)."""
     dt = cfg.dtype
     h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
     k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
     v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+    if cfg.qk_norm:
+        q = rms_norm(q, layer["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, layer["k_norm"], cfg.norm_eps)
+    if not rotate:
+        return q, k, v
     return apply_rope(q, sin, cos), apply_rope(k, sin, cos), v
 
 
@@ -751,6 +840,12 @@ def hidden_with_aux(params: dict, tokens: jax.Array,
     compute dtype, aux). The LM head is applied by the caller — either
     densely (:func:`forward_with_aux`) or fused with the loss
     (:func:`loss_terms`) so the (B,S,V) f32 logits never materialize."""
+    if cfg.attn_windows is not None:
+        raise ValueError(
+            "the full-sequence forward runs every layer as full "
+            "attention; a stack with attention kinds (windows) is "
+            "served through the paged programs (models/generate.py) "
+            "until the flash kernels take a window mask")
     attn_fn = attn_fn or resolve_attn_fn(cfg)
     B, S = tokens.shape
     dt = cfg.dtype
@@ -937,9 +1032,11 @@ def param_specs(cfg: TransformerConfig,
     if not cfg.plain:
         raise ValueError(
             "param_specs shards the GQA block of a stack with one "
-            "group; a latent-attention, grouped or dropless-expert "
-            "stack runs on one device (serving) until its sharding "
-            "and the experts' all-to-all are written")
+            "group, every layer full attention; a latent-attention, "
+            "grouped or dropless-expert stack, or one with attention "
+            "kinds (windows, a q/k norm), runs on one device (serving) "
+            "until its sharding and the experts' all-to-all are "
+            "written")
     D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size
     H, K, E = cfg.n_heads, cfg.kv_heads, cfg.n_experts
     fsdp = partial(_maybe, "fsdp", axis_sizes=axis_sizes)

@@ -6,7 +6,13 @@ per-token array the model says a layer's cache holds
 (``transformer.cache_spec``: ``k`` and ``v`` of ``(Kh, Dh)`` for GQA,
 the latent ``ckv`` and the indexer's key ``ki`` for latent attention).
 The pool allocates from that description, the migrator packs by it and
-the engine's programs carry the banks as one dict. Sequences hold
+the engine's programs carry the banks as one dict. A model whose
+layers do not all keep the same tokens (``transformer.cache_layers``:
+full-attention layers keep every token, window layers the last
+``window``) has one pool a kind, each with the layers of its kind, its
+own tables and its own reservations: the engine admits a row only when
+both can hold it, and gives a window layer's blocks back as the row
+advances (:meth:`BlockPool.deref` with ``keep_unit``). Sequences hold
 *block tables* (ordered block ids); position ``p`` of a sequence lives
 in table entry ``p // block_tokens`` at offset ``p % block_tokens``.
 Three lifetimes per block:
@@ -87,7 +93,10 @@ def prefix_affinity_key(tokens, block_tokens: int) -> str | None:
 
 
 class BlockPool:
-    """Ref-counted, content-addressed pool of KV blocks on device.
+    """Ref-counted, content-addressed pool of KV blocks on device,
+    for the layers of ONE kind of cache: all of a model's where every
+    layer keeps the same tokens, else ``n_layers`` of them (the full
+    layers, or the window layers; the engine holds a pool a kind).
 
     Thread contract: mutating calls come from the one engine thread;
     :meth:`stats` / :meth:`free_blocks` are read from Info/probe
@@ -95,7 +104,8 @@ class BlockPool:
     """
 
     def __init__(self, cfg: tfm.TransformerConfig, n_blocks: int,
-                 block_tokens: int, device=None):
+                 block_tokens: int, device=None,
+                 n_layers: int | None = None):
         if block_tokens % SUBLANES:
             raise ValueError(
                 f"block_tokens {block_tokens} must divide by "
@@ -109,9 +119,11 @@ class BlockPool:
         self.spec = tfm.cache_spec(cfg)
         #: The banks, one a named array, committed to ``device`` when
         #: one is given. The engine owns these references — jitted
-        #: steps/prefills donate the dict and replace it.
+        #: steps/prefills donate the dict and replace it. ``n_layers``:
+        #: the layers of this pool's kind, where the model has two.
         self.banks = {
-            name: jnp.zeros((cfg.n_layers, n_blocks, block_tokens)
+            name: jnp.zeros((n_layers or cfg.n_layers, n_blocks,
+                             block_tokens)
                             + tuple(shape), cfg.dtype, device=device)
             for name, shape in self.spec.items()}
         self._lock = lockcheck.lock("serve_engine.pool")
@@ -224,11 +236,17 @@ class BlockPool:
                 self._ref[bid] += 1
             self._reserved = max(0, self._reserved - 1)
 
-    def deref(self, bid: int) -> None:
+    def deref(self, bid: int, keep_unit: bool = False) -> None:
         """Drop one reference. At zero, a hashed block parks in the
         LRU (reusable until evicted); an unhashed one (decode tail)
-        frees outright."""
+        frees outright. ``keep_unit``: the holder takes back the
+        reserved unit the block consumed — a window layer's row gives
+        up the blocks behind its window and will allocate as many
+        again ahead of it, so what it reserved at admission (the most
+        it ever holds at once) covers its whole life."""
         with self._lock:
+            if keep_unit:
+                self._reserved += 1
             n = self._ref.get(bid, 0) - 1
             if n > 0:
                 self._ref[bid] = n

@@ -20,6 +20,17 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   them a trip, and cost what is live whatever ``n_slots`` is. Greedy
   rows still match their solo decode token for token; logits agree to
   the float32 rounding of a softmax accumulated tile by tile.
+- **Two kinds of cache** (a stack with attention kinds,
+  ``transformer.cache_layers``): full-attention layers keep every
+  token, window layers the last ``window``. Each kind has its own
+  :class:`BlockPool`, banks and table a row; a row is admitted only
+  when BOTH pools can hold it (the window pool's share is the most a
+  row ever holds there at once: ``window + prefill_chunk`` tokens);
+  the window table's blocks wholly behind ``pos - window`` are given
+  back as the row advances, in each prefill chunk and each decode
+  step, so a 32k-token row holds 32k tokens in the full layers alone.
+  A prefix hit is the longest chained prefix whose full-layer blocks
+  AND whose last window's window-layer blocks are still resident.
 - **Chunked prefill**: admission writes the prompt in bounded
   ``prefill_chunk``-token chunks INTERLEAVED with decode steps — a 4k
   prompt can no longer freeze co-batched decodes for its whole
@@ -78,7 +89,8 @@ from ptype_tpu.models import transformer as tfm
 from ptype_tpu.serve import (LIFECYCLE_CODES, GeneratorActor, _norm_prompt,
                              _pow2)
 from ptype_tpu.serve_engine.blocks import BlockPool, block_hashes
-from ptype_tpu.serve_engine.migrate import WIRE_MODES, KVMigrator
+from ptype_tpu.serve_engine.migrate import (WIRE_MODES, KVMigrator,
+                                            migrate_refusal)
 
 log = logs.get_logger("serve_engine")
 
@@ -144,7 +156,8 @@ class _PagedRow:
                  "top_k", "top_p", "key", "emitted", "done", "err",
                  "table", "hashes", "reused", "prefill_pos",
                  "reserve_left", "rec", "cancelled", "draft_table",
-                 "draft_reserve_left", "export_id", "migrated")
+                 "draft_reserve_left", "export_id", "migrated",
+                 "wtable", "wfirst", "wreserve_left")
 
     def __init__(self, prompt, max_new, stop_token, temperature,
                  top_k, top_p, key):
@@ -181,6 +194,14 @@ class _PagedRow:
         #: prefill — both already happened).
         self.export_id: int | None = None
         self.migrated = False
+        #: The window layers' block table (two kinds of cache only),
+        #: indexed as ``table`` is, by position // block_tokens:
+        #: entries before ``wfirst`` were given back (0) once wholly
+        #: behind the row's window; ``wreserve_left`` the window
+        #: pool's units the row still holds.
+        self.wtable: list[int] = []
+        self.wfirst = 0
+        self.wreserve_left = 0
 
 
 class PagedGeneratorActor(GeneratorActor):
@@ -191,7 +212,10 @@ class PagedGeneratorActor(GeneratorActor):
     also the prefix-sharing granularity); ``n_blocks`` pool size
     (default ``n_slots × reach/block_tokens + 1`` — the contiguous
     engine's worst case; shrink it to oversubscribe on real token
-    counts); ``prefill_chunk`` admission token budget per engine
+    counts; for a model with window layers beside full ones it is the
+    FULL layers' pool, and the window layers' is sized by the engine
+    from ``n_slots``, the window and ``prefill_chunk``);
+    ``prefill_chunk`` admission token budget per engine
     iteration (the decode-stall bound; ``None`` = whole-prompt, the
     legacy behavior); ``max_queue`` waiting-room bound before typed
     sheds; ``admit_timeout_s`` bound on how long a head-of-line
@@ -237,9 +261,30 @@ class PagedGeneratorActor(GeneratorActor):
         self.nb = self.reach // bt
         n_blocks = (int(n_blocks) if n_blocks
                     else self.n_slots * self.nb + 1)
-        self.pool = BlockPool(cfg, n_blocks, bt, device=device)
         self.prefill_chunk = (int(prefill_chunk) if prefill_chunk
                               else self.reach)
+        #: Two kinds of cache (``tfm.cache_layers``): ``pool`` is then
+        #: the full layers' and ``n_blocks`` its size; ``_wpool`` the
+        #: window layers', sized from what a row can hold there:
+        #: ``_wrow`` blocks while it prefills (window + chunk), ``_wdec``
+        #: while it decodes, for every slot, plus room for each slot's
+        #: last sealed window (the prefix rule needs those resident).
+        kinds = tfm.cache_layers(cfg)
+        self._wpool: BlockPool | None = None
+        self._window = cfg.window
+        if kinds is None:
+            self.pool = BlockPool(cfg, n_blocks, bt, device=device)
+        else:
+            W = self._window
+            self._wrow = min(self.nb,
+                             (W + self.prefill_chunk - 1) // bt + 2)
+            self._wdec = min(self.nb, (W - 1) // bt + 2)
+            self.pool = BlockPool(cfg, n_blocks, bt, device=device,
+                                  n_layers=len(kinds["full"]))
+            self._wpool = BlockPool(
+                cfg, self.n_slots * (self._wrow + self._wdec) + 1, bt,
+                device=device, n_layers=len(kinds["window"]))
+        self._window_freed = 0
         self.max_queue = int(max_queue)
         self.admit_timeout_s = float(admit_timeout_s)
         if serve_class not in SERVE_CLASSES:
@@ -253,7 +298,8 @@ class PagedGeneratorActor(GeneratorActor):
         #: residual store (docs/OPERATIONS.md "Disaggregated
         #: serving"). One per engine — residuals are keyed by chain
         #: hash, so they follow block CONTENT, not requests.
-        self._migrator = KVMigrator(self.pool.block_shapes(), cfg.dtype)
+        self._migrator = (KVMigrator(self.pool.block_shapes(), cfg.dtype)
+                          if self._wpool is None else None)
         #: export_id -> finished prefill row whose block refs are
         #: parked for migration (released by ReleaseExport).
         self._exports: dict[int, _PagedRow] = {}
@@ -301,6 +347,8 @@ class PagedGeneratorActor(GeneratorActor):
 
         ns = self.n_slots
         self._tables = np.zeros((ns, self.nb), np.int32)
+        self._wtables = np.zeros((ns, self.nb), np.int32)
+        self._wfirst = np.zeros(ns, np.int32)
         self._nalloc = np.zeros(ns, np.int32)
         self._tok = np.zeros(ns, np.int32)
         self._pos = np.zeros(ns, np.int32)
@@ -347,8 +395,10 @@ class PagedGeneratorActor(GeneratorActor):
             # on device lets the engine loop skip re-uploading its
             # slot state on steps where nothing was admitted/retired —
             # the steady-state decode step transfers nothing in.
-            wr_b = jnp.where(active,
-                             tables[jnp.arange(B), pos // bt_], 0)
+            wr_b = jax.tree.map(
+                lambda t: jnp.where(active,
+                                    t[jnp.arange(B), pos // bt_], 0),
+                tables)
             wr_o = pos % bt_
             logits, banks, load = gen.decode_step_banks(
                 params, tok, pos, self.cfg, banks, tables, wr_b,
@@ -543,6 +593,12 @@ class PagedGeneratorActor(GeneratorActor):
 
     # -------------------------------------------- migration (ISSUE 16)
 
+    def _one_cache(self, what: str) -> None:
+        """Migration ships one pool's blocks by one table: say so, not
+        a key error, for a model with two kinds of cache."""
+        if self._wpool is not None:
+            raise ValueError(migrate_refusal(what))
+
     def Prefill(self, prompt, max_new_tokens: int = 16,
                 temperature: float = 0.0, seed: int = 0,
                 top_k: int = 0, top_p: float = 1.0,
@@ -554,6 +610,7 @@ class PagedGeneratorActor(GeneratorActor):
         ImportBlocks/MigrateDecode on a decode-class replica;
         ``max_new_tokens`` is advisory here (the decode side reserves
         for it) — this replica only ever computes token one."""
+        self._one_cache("disaggregated prefill")
         prompt = _norm_prompt(prompt)
         if prompt.shape[0] != 1:
             raise ValueError("Prefill is single-row (the gateway "
@@ -686,6 +743,7 @@ class PagedGeneratorActor(GeneratorActor):
         ``need`` (full-block indices to ship); a pool that can't
         cover the worst case sheds typed, same contract as
         admission."""
+        self._one_cache("a migration")
         prompt = _norm_prompt(prompt)
         if prompt.shape[0] != 1:
             raise ValueError("MigratePlan is single-row")
@@ -982,6 +1040,20 @@ class PagedGeneratorActor(GeneratorActor):
                                            stall_ms) as it:
                     self._step(it)
 
+    def _banks(self):
+        """The cache as the paged programs take it: the pool's banks,
+        or with two kinds of cache a dict of each kind's."""
+        if self._wpool is None:
+            return self.pool.banks
+        return {"full": self.pool.banks, "window": self._wpool.banks}
+
+    def _put_banks(self, banks) -> None:
+        if self._wpool is None:
+            self.pool.banks = banks
+        else:
+            self.pool.banks = banks["full"]
+            self._wpool.banks = banks["window"]
+
     def _admission_round(self) -> float:
         """Prefill up to ``prefill_chunk`` prompt tokens; returns the
         wall seconds spent (the stall charged to the next step)."""
@@ -1031,6 +1103,13 @@ class PagedGeneratorActor(GeneratorActor):
             # pool only would dead-end at its first draft write.
             self.pool.unreserve(need)
             reserved = False
+        # The window layers' pool likewise: the most the row holds
+        # there at once, which what it gives back as it advances
+        # covers for its whole length.
+        wneed = 0 if self._wpool is None else min(need, self._wrow)
+        if reserved and wneed and not self._wpool.try_reserve(wneed):
+            self.pool.unreserve(need)
+            reserved = False
         if not reserved:
             # Blocks come back at retire; re-checked each loop. But a
             # bounded wait only: past admit_timeout_s AT THE QUEUE
@@ -1052,6 +1131,7 @@ class PagedGeneratorActor(GeneratorActor):
                 row.done.set()
             return
         row.reserve_left = need
+        row.wreserve_left = wneed
         if self._dpool is not None:
             row.draft_reserve_left = need
         self._queue.pop(0)
@@ -1100,11 +1180,11 @@ class PagedGeneratorActor(GeneratorActor):
             # buffers; one that dispatches after sees the NEW bank
             # refs — never a half-donated alias.
             with self._lock:
-                logits, self.pool.banks = self._chunk_prog(
-                    padded.shape[1])(
-                    self.params, self.pool.banks,
+                logits, banks = self._chunk_prog(padded.shape[1])(
+                    self.params, self._banks(),
                     jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
-                    jnp.asarray(table_arr))
+                    jax.tree.map(jnp.asarray, table_arr))
+                self._put_banks(banks)
             row.prefill_pos += n
             done = row.prefill_pos >= L
             if done:
@@ -1114,6 +1194,9 @@ class PagedGeneratorActor(GeneratorActor):
                 for i in range(row.reused, len(row.hashes)):
                     self.pool.seal(row.table[i], row.hashes[i],
                                    toks[i * bt:(i + 1) * bt])
+                    if self._wpool is not None and i >= row.wfirst:
+                        self._wpool.seal(row.wtable[i], row.hashes[i],
+                                         toks[i * bt:(i + 1) * bt])
                 with metrics_mod.annotate("serve.prefill/fetch"):
                     # The host waits here for every chunk of the prompt.
                     if row.temperature == 0.0:
@@ -1168,15 +1251,26 @@ class PagedGeneratorActor(GeneratorActor):
             # always prefills.
             row.hashes = block_hashes(toks, bt)
             cap = min(len(row.hashes), (L - 1) // bt)
+            found = []
             for i in range(cap):
                 bid = self.pool.lookup(row.hashes[i],
                                        toks[i * bt:(i + 1) * bt])
                 if bid is None:
                     break
+                found.append(bid)
+            wfound = []
+            if self._wpool is not None:
+                r, row.wfirst, wfound = self._window_hit(row, found)
+                del found[r:]
+            for bid in found:
                 self.pool.ref(bid)
                 row.reserve_left -= 1
                 row.table.append(bid)
                 row.reused += 1
+            for bid in wfound:
+                self._wpool.ref(bid)
+                row.wreserve_left -= 1
+            row.wtable = [0] * row.wfirst + wfound
             self._prefix_hits += row.reused
             self._prefix_misses += len(row.hashes) - row.reused
             row.prefill_pos = row.reused * bt
@@ -1193,7 +1287,62 @@ class PagedGeneratorActor(GeneratorActor):
         padded[0, :n] = toks[start:start + n]
         table_arr = np.zeros(self.nb, np.int32)
         table_arr[:len(row.table)] = row.table
+        if self._wpool is not None:
+            # The window layers: what lies wholly behind the chunk's
+            # first query's window goes back first, then the chunk's
+            # own blocks come out of the units that freed.
+            self._release_window(row, start)
+            while len(row.wtable) * bt < start + n:
+                row.wtable.append(self._wpool.alloc())
+                row.wreserve_left -= 1
+            wtable_arr = np.zeros(self.nb, np.int32)
+            wtable_arr[:len(row.wtable)] = row.wtable
+            table_arr = {"full": table_arr, "window": wtable_arr}
         return start, n, padded, table_arr
+
+    def _window_hit(self, row: _PagedRow, found: list
+                    ) -> tuple[int, int, list]:
+        """The prefix rule with two kinds of cache. ``found``: the
+        full-layer blocks of the prompt's longest chained prefix that
+        are resident. A prefix of ``r`` blocks can be skipped only if
+        the window layers still hold the blocks its last ``window``
+        tokens lie in (the first query after it sees those). → the
+        longest such ``r``, the first block of that window, and the
+        window pool's blocks from there to ``r`` (a shorter hit where
+        later ones were evicted; none: ``(0, 0, [])``)."""
+        bt, toks = self.block_tokens, row.prompt
+        held: dict[int, int | None] = {}  # looked up as the walk asks
+
+        def wbid(i):
+            if i not in held:
+                held[i] = self._wpool.lookup(row.hashes[i],
+                                             toks[i * bt:(i + 1) * bt])
+            return held[i]
+
+        for r in range(len(found), 0, -1):
+            lo = max(r * bt - self._window + 1, 0) // bt
+            got = [wbid(i) for i in range(lo, r)]
+            if all(b is not None for b in got):
+                return r, lo, got
+        return 0, 0, []
+
+    def _release_window(self, row: _PagedRow, pos: int) -> None:
+        """Give back the row's window-layer blocks that lie wholly
+        behind the window of a query at ``pos`` (sealed first where
+        they are whole prompt blocks this row computed, so that they
+        park in the LRU for the prefix rule; the row keeps their
+        reserved units for the blocks ahead)."""
+        bt = self.block_tokens
+        first = min(max(pos - self._window + 1, 0) // bt, len(row.wtable))
+        for i in range(row.wfirst, first):
+            if row.reused <= i < len(row.hashes):
+                self._wpool.seal(row.wtable[i], row.hashes[i],
+                                 row.prompt[i * bt:(i + 1) * bt])
+            self._wpool.deref(row.wtable[i], keep_unit=True)
+            row.wreserve_left += 1
+            row.wtable[i] = 0
+        self._window_freed += max(first - row.wfirst, 0)
+        row.wfirst = max(row.wfirst, first)
 
     def _take_slot(self, row: _PagedRow, first: int, L: int) -> None:
         """Land a prompt-complete row in a free slot (the caller
@@ -1203,6 +1352,12 @@ class PagedGeneratorActor(GeneratorActor):
         self._tables[slot] = 0
         self._tables[slot, :len(row.table)] = row.table
         self._nalloc[slot] = len(row.table)
+        if self._wpool is not None:
+            # Decoding, a row holds its window and no chunk behind it.
+            self._release_window(row, L)
+            self._wtables[slot] = 0
+            self._wtables[slot, :len(row.wtable)] = row.wtable
+            self._wfirst[slot] = row.wfirst
         self._tok[slot] = first
         self._pos[slot] = L
         self._active[slot] = True
@@ -1309,6 +1464,17 @@ class PagedGeneratorActor(GeneratorActor):
                     row.reserve_left -= 1
                     row.table.append(bid)
                     self._tables[slot, self._nalloc[slot]] = bid
+                    if self._wpool is not None:
+                        # The window layers cross the same boundary:
+                        # the block behind the window goes back, the
+                        # one ahead comes out of the unit that freed.
+                        self._release_window(row, int(self._pos[slot]))
+                        self._wtables[slot, :row.wfirst] = 0
+                        self._wfirst[slot] = row.wfirst
+                        wbid = self._wpool.alloc()
+                        row.wreserve_left -= 1
+                        row.wtable.append(wbid)
+                        self._wtables[slot, self._nalloc[slot]] = wbid
                     self._nalloc[slot] += 1
                     self._dev = None  # tables changed: re-upload
                     self._sdev = None
@@ -1320,6 +1486,7 @@ class PagedGeneratorActor(GeneratorActor):
             # the same commitment or the second step of every request
             # sees a new signature and compiles again (chip run, PR 21).
             with annotate("serve.step/upload"):
+                tables = self._tables
                 if self.cfg.latent is None:
                     live_list = gen.live_block_list(
                         self._tables, self._nalloc, self._active,
@@ -1328,6 +1495,18 @@ class PagedGeneratorActor(GeneratorActor):
                         "kv_blocks": int(
                             self._nalloc[self._active].sum()),
                         "kv_tiles": int(live_list[1])}
+                    if self._wpool is not None:
+                        wlist = gen.live_block_list(
+                            self._wtables, self._nalloc, self._active,
+                            self.block_tokens, first=self._wfirst,
+                            row_blocks=self._wdec)
+                        self._kv.update(
+                            win_blocks=int((self._nalloc - self._wfirst)[
+                                self._active].sum()),
+                            win_tiles=int(wlist[1]))
+                        live_list = {"full": live_list, "window": wlist}
+                        tables = {"full": self._tables,
+                                  "window": self._wtables}
                 else:
                     live_list = gen.live_lane_list(self._active)
                     self._kv = {
@@ -1335,7 +1514,7 @@ class PagedGeneratorActor(GeneratorActor):
                         "lane_tiles": int(live_list[1])}
                 self._dev = jax.device_put({
                     "tok": self._tok, "pos": self._pos,
-                    "tables": self._tables, "active": self._active,
+                    "tables": tables, "active": self._active,
                     "keys": self._keys, "eidx": self._eidx,
                     "temps": self._temps, "topk": self._topk,
                     "topp": self._topp, "live_list": live_list,
@@ -1346,10 +1525,16 @@ class PagedGeneratorActor(GeneratorActor):
         self._max_live = max(self._max_live, n_live)
         kv = self._kv
         if self.cfg.latent is None:
+            full_list = (d["live_list"] if self._wpool is None
+                         else d["live_list"]["full"])
             self.ledger.kv_list(
                 kv["kv_blocks"], kv["kv_tiles"],
                 int(self._pos[self._active].sum()) + n_live,
-                d["live_list"][0].shape[2] * self.block_tokens)
+                full_list[0].shape[2] * self.block_tokens)
+            if self._wpool is not None:
+                self.ledger.cache(kv["kv_blocks"], kv["win_blocks"],
+                                  self._window_freed, kv["kv_blocks"])
+                self._window_freed = 0
         else:
             self.ledger.lane_list(kv["live_lanes"], kv["lane_tiles"],
                                   d["live_list"][0].shape[1])
@@ -1359,12 +1544,13 @@ class PagedGeneratorActor(GeneratorActor):
             # raise at the call — the steady-state step re-uploads
             # NOTHING, and jitwatch counts its compiles.
             with jitwatch.hot_region("serve.decode"):
-                (self.pool.banks, nxt, d["pos"], d["eidx"],
+                (banks, nxt, d["pos"], d["eidx"],
                  *fetch) = self._engine_step(
-                    sampled, self.params, self.pool.banks,
+                    sampled, self.params, self._banks(),
                     d["tok"], d["pos"], d["tables"], d["active"],
                     d["keys"], d["eidx"], d["temps"], d["topk"],
                     d["topp"], d["live_list"])
+                self._put_banks(banks)
         d["tok"] = nxt
         with annotate("serve.step/fetch"):
             # The host waits for the device here.
@@ -1716,6 +1902,30 @@ class PagedGeneratorActor(GeneratorActor):
                     spec_rows=rows_d)]
         return bad
 
+    def check_invariants(self) -> list[str]:
+        """Audit every pool this engine holds (tests; the engine quiet
+        or on its own thread): the target pool, a drafter's, and with
+        two kinds of cache the window layers' — each pool's own
+        consistency, and that no row holds more window blocks than it
+        was admitted for."""
+        bad = self.pool.check_invariants()
+        if self._dpool is not None:
+            bad += [f"draft: {b}" for b in self._dpool.check_invariants()]
+        if self._wpool is not None:
+            bad += [f"window: {b}"
+                    for b in self._wpool.check_invariants()]
+            rows = list(self._slot_state.values())
+            if self._admitting is not None:  # ptlint: disable=PT013 -- audit for tests, engine quiet
+                rows.append(self._admitting)
+            for row in rows:
+                held = len(row.wtable) - row.wfirst
+                if held + row.wreserve_left > self._wrow or held < 0:
+                    bad.append(
+                        f"window: a row holds {held} blocks and "
+                        f"{row.wreserve_left} units; admitted for "
+                        f"{self._wrow}")
+        return bad
+
     def _retire(self, slot: int, reason: str = "complete") -> None:
         self._active[slot] = False
         self._temps[slot] = 0.0
@@ -1731,6 +1941,13 @@ class PagedGeneratorActor(GeneratorActor):
         if row.reserve_left > 0:
             self.pool.unreserve(row.reserve_left)
         row.reserve_left = 0
+        if self._wpool is not None:
+            for bid in row.wtable[row.wfirst:]:
+                self._wpool.deref(bid)
+            row.wtable, row.wfirst = [], 0
+            if row.wreserve_left > 0:
+                self._wpool.unreserve(row.wreserve_left)
+            row.wreserve_left = 0
         if self._dpool is not None:
             for bid in row.draft_table:
                 self._dpool.deref(bid)
@@ -1824,6 +2041,11 @@ class PagedGeneratorActor(GeneratorActor):
             info["queue_depth"] = len(self._queue)
         info["live_slots"] = int(self._active.sum())
         info.update(self.pool.stats())
+        if self._wpool is not None:
+            # The window layers' pool, under its own names: the keys
+            # above are the full layers', which grow with a row.
+            info.update({k.replace("kv_", "kv_window_", 1): v
+                         for k, v in self._wpool.stats().items()})
         info["block_tokens"] = self.block_tokens
         info["prefill_chunk"] = self.prefill_chunk
         info["admit_timeout_s"] = self.admit_timeout_s

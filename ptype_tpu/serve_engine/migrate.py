@@ -115,6 +115,17 @@ def make_unpack_exact_prog():
     return jax.jit(unpack, donate_argnums=(0,))
 
 
+def migrate_refusal(what: str) -> str:
+    """Why a model with two kinds of cache is not migrated: the wire
+    format ships, for each block index, one block of each bank the
+    ONE pool holds (``BlockPool.block_shapes``), by one table."""
+    return (f"{what} ships one pool's blocks by one table; this "
+            f"configuration has window layers beside full ones (two "
+            f"pools, and a window table that has given back what lies "
+            f"behind the window): pack both kinds' blocks, the window "
+            f"layers' last window alone, before it is migrated")
+
+
 class KVMigrator:
     """Per-engine wire state: the jitted pack/unpack programs plus the
     prefill-side error-feedback residual store.
