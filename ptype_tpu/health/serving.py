@@ -374,6 +374,11 @@ class ServingLedger:
         #: from the summary until an iteration reported some.
         self._moe_load: list[int] = []
         self._moe_iters = 0
+        #: The dropless layers' tile loop (moe_load): running totals of
+        #: tiles visited, held experts hit and rows the tiles covered,
+        #: and the number of expert layers a step.
+        self._moe_tiles = [0, 0, 0]
+        self._moe_layers = 1
         #: The decode step's live-block list (kv_list): running totals
         #: of steps, listed blocks, tiles run, tokens attended and
         #: tokens the tiles covered; absent from the summary until a
@@ -461,24 +466,36 @@ class ServingLedger:
         for rec, n in zip(recs, counts):
             rec.tok_t.extend([now] * int(n))
 
-    def moe_load(self, counts) -> None:
+    def moe_load(self, counts, tiles: int = 0, hit: int = 0,
+                 tile: int = 0, layers: int = 1) -> None:
         """One decode iteration's router load, as the step counted it
         on the device: assignments per held expert, summed over the
         expert layers, and last those that fell on no held expert.
         Kept as running totals (``summary()["moe_load"]``) and emitted
-        as a ``serve.moe_load`` record through the one seam."""
+        as a ``serve.moe_load`` record through the one seam. With them
+        what the layers' tile loop did (``transformer._moe_dropless``):
+        ``tiles`` of ``tile`` rows visited and held experts ``hit``,
+        each summed over the ``layers`` expert layers; behind
+        ``summary()``'s ``expert_tiles`` (mean a step), ``experts_hit``
+        (mean distinct held experts a layer a step) and
+        ``expert_tile_fill`` (routed live rows ÷ rows the tiles in use
+        covered)."""
         counts = [int(c) for c in counts]
+        tiles, hit = int(tiles), int(hit)
         with self._lock:
             if len(self._moe_load) != len(counts):
                 self._moe_load = [0] * len(counts)
             self._moe_load = [a + b for a, b in
                               zip(self._moe_load, counts)]
             self._moe_iters += 1
+            for i, v in enumerate((tiles, hit, tiles * int(tile))):
+                self._moe_tiles[i] += v
+            self._moe_layers = int(layers)
         # ":" between the counts: a profiler annotation's metadata is
         # itself a comma-separated list.
         with trace.span("serve.moe_load",
                         held=":".join(str(c) for c in counts[:-1]),
-                        elsewhere=counts[-1]):
+                        elsewhere=counts[-1], tiles=tiles):
             pass
 
     def kv_list(self, blocks: int, tiles: int, live_tokens: int,
@@ -663,6 +680,8 @@ class ServingLedger:
             spec_toks = self._spec_tokens
             migrated = self._migrated
             moe_load, moe_iters = list(self._moe_load), self._moe_iters
+            moe_tiles, moe_hit, moe_covered = self._moe_tiles
+            moe_layers = self._moe_layers
             kv_steps, kv_blocks, kv_tiles, kv_live, kv_covered = \
                 self._kv_list
             lane_steps, lane_tiles, lanes_live, lanes_covered = \
@@ -686,6 +705,11 @@ class ServingLedger:
             out["moe_load"] = {"iterations": moe_iters,
                                "held": moe_load[:-1],
                                "elsewhere": moe_load[-1]}
+            out["expert_tiles"] = round(moe_tiles / moe_iters, 3)
+            out["experts_hit"] = round(
+                moe_hit / (moe_iters * moe_layers), 3)
+            out["expert_tile_fill"] = round(
+                sum(moe_load[:-1]) / max(moe_covered, 1), 4)
         if spec_prop:
             # Only once speculation actually ran: a non-speculative
             # replica's Info() stays spec-free, so fleet views can

@@ -555,7 +555,9 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     attention run over its lanes alone. Returns ``(x (B, Q, D) before the
     final norm, banks, load)``; ``load`` is a dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
-    ``live`` (B, Q) marks, if given), None without one."""
+    ``live`` (B, Q) marks, if given) and behind them two more int32,
+    the tiles its layers' loops visited and the held experts they hit
+    (``tfm.expert_tiles``, summed likewise); None without one."""
     def flat_view(bs):
         return {n: b.reshape((-1,) + b.shape[2:]) for n, b in bs.items()}
 
@@ -624,14 +626,20 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
                                         lanes=live_list)
             return o, {"ckv": cf, "ki": kif}
 
-    def layers_of(window):
+    def layers_of(window, experts):
         def body(carry, inputs):
             x, bf = carry
-            layer, base = inputs  # base: the layer's first row of the view
+            # base: the layer's first row of the view; at, where the
+            # group's expert stacks are closed over: its index in them.
+            layer, base, *at = inputs
             o, bf = attention(x, bf, layer, base, window)
             x = tfm.attn_residual(x, o, layer, cfg)
             x, _aux, load = tfm.mlp_residual(
-                x, layer, cfg, moe_capacity=moe_capacity, live=live)
+                x, {**layer, **experts}, cfg, moe_capacity=moe_capacity,
+                live=live, at=at[0] if at else None)
+            if load is not None:
+                load = jnp.concatenate(
+                    [load, tfm.expert_tiles(load, x.shape[0] * x.shape[1])])
             return (x, bf), load
         return body
 
@@ -647,8 +655,16 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             rank = kinds[kind].index(first)
             bases = jnp.arange(rank, rank + n,
                                dtype=jnp.int32) * n_blocks[kind]
+        # A dropless router's expert stacks stay out of the scan: its
+        # tile loop reads an expert's matrices in place out of the
+        # whole group's stack (tfm._moe_dropless).
+        experts, xs = {}, (stacked, bases)
+        if cfg.moe_router == "sigmoid_bias" and "router" in stacked:
+            experts = {m: stacked[m] for m in ("w_gate", "w_up", "w_down")}
+            xs = ({m: w for m, w in stacked.items() if m not in experts},
+                  bases, jnp.arange(n, dtype=jnp.int32))
         (x, flat), loads = lax.scan(
-            layers_of(window), (x, flat), (stacked, bases))
+            layers_of(window, experts), (x, flat), xs)
         if loads is not None:
             total = jnp.sum(loads, axis=0)
             load = total if load is None else load + total

@@ -693,7 +693,32 @@ def _swiglu(h, w_gate, w_up, w_down, dt):
                       w_down.astype(dt))
 
 
-def _moe_dropless(h, layer, cfg: TransformerConfig, live=None):
+#: Rows in one tile of a dropless layer's routed rows
+#: (:func:`_moe_dropless`): a decode step's (T = slots) and a prefill
+#: chunk's. Chosen on the chip (PERF.md §6, PR 34).
+EXPERT_TILE_STEP = 8
+EXPERT_TILE_CHUNK = 128
+
+
+def expert_tile(T: int) -> int:
+    """Rows in one tile of the routed rows of ``T`` tokens: from the
+    static shape alone, the small tile below a chunk tile's worth of
+    tokens (a decode step, a short chunk bucket), the large one from
+    there on."""
+    return EXPERT_TILE_STEP if T < EXPERT_TILE_CHUNK else EXPERT_TILE_CHUNK
+
+
+def expert_tiles(load, T: int):
+    """``[tiles, hit]`` int32 of one dropless layer over ``T`` tokens,
+    from its ``load`` (:func:`_moe_dropless`): the tiles its loop
+    visits, ``Σ_e ceil(n_e / tile)`` over the held experts (its trip
+    count), and the held experts that took a row at all."""
+    n = load[:-1]
+    return jnp.stack([jnp.sum(-(-n // expert_tile(T))),
+                      jnp.sum(n > 0)]).astype(jnp.int32)
+
+
+def _moe_dropless(h, layer, cfg: TransformerConfig, live=None, at=None):
     """Dropless top-k MoE over the experts held here, with a shared
     expert. h: (B, S, D) → (y, load).
 
@@ -701,26 +726,45 @@ def _moe_dropless(h, layer, cfg: TransformerConfig, live=None):
     the ``expert_top_k`` largest ``s + bias`` chosen (``bias`` the
     learned correction, selection only), gates ``routed_scale · s /
     Σ_chosen s``. Of a token's choices those that fall on
-    ``cfg.held`` are computed, grouped by held expert: each expert's
-    tokens are gathered into its rows of an (held, T, D) buffer and the
-    SwiGLUs run as one batched product a matrix. A token chooses an
-    expert at most once, so T rows an expert is the most any load can
-    fill: no token is dropped however crowded one expert is, and no
-    capacity is set. (``lax.ragged_dot`` over the sorted assignments
-    does the same with no padding, but it is a custom call: inside the
-    layer scan the compiler copies each layer's three expert matrices
-    out of the stack for it, 1.2 GB a layer at GLM-5's widths — AOT
-    compile for v5e, PR 28 — where a dot reads its slice in place.)
-    What the absent experts would have added is left out. ``load``
-    (held + 1,) int32: assignments per held expert, and last those
-    whose expert is not held; of the tokens ``live`` (B, S) marks, if
-    given (a decode step computes its inactive lanes too, all alike:
-    counted, they would read as one crowded expert)."""
+    ``cfg.held`` are computed, as a grouped product over tiles of the
+    routed rows: the assignments are ordered by held expert (token
+    order inside an expert), each expert's run padded to a multiple of
+    :func:`expert_tile` rows so that a tile belongs to one expert, and
+    ONE loop runs over the tiles in use, ``Σ_e ceil(n_e / tile)``, a
+    trip count that is data. A trip gathers its tile's token rows,
+    takes its expert's three matrices as a slice of the stack, computes
+    the SwiGLU and writes its rows of the output; each assignment then
+    gathers its row, times its gate. An expert no row chose is not
+    read, and none is padded to T rows. The list's static bound covers
+    every load (a token chooses an expert at most once): no token is
+    dropped however crowded one expert is, and no capacity is set.
+    What the absent experts would have added is left out.
+
+    ``layer``'s ``w_gate``, ``w_up``, ``w_down`` are this layer's
+    ``(held, D, F)`` stacks or, with ``at`` (the layer's index, a
+    traced scalar), a whole group's ``(n, held, D, F)``: the paged
+    programs' layer scan closes over the group's stacks and scans the
+    index, so that the loop's dots read their expert's matrices in
+    place out of the parameters. (Sliced by the scan, a layer's stack
+    would be the loop's operand and so be copied out first, as
+    ``lax.ragged_dot``'s custom call made the compiler do, 1.2 GB a
+    layer at GLM-5's widths: AOT compile for v5e, PR 28 and PR 34.)
+
+    ``load`` (held + 1,) int32: assignments per held expert, and last
+    those whose expert is not held; of the tokens ``live`` (B, S)
+    marks, if given. A decode step's inactive lanes and a chunk's pads
+    route too, all alike: their assignments visit no tile and are not
+    counted (counted, they would read as one crowded expert)."""
     B, S, D = h.shape
     k = cfg.expert_top_k
     first, count = cfg.held
     dt = cfg.dtype
     T = B * S
+    tile = expert_tile(T)
+    # Σ_e ceil(n_e / tile) <= Σ n_e // tile + held, and n_e <= T.
+    max_tiles = min(T * min(k, count) // tile + count,
+                    count * -(-T // tile))
+    R = max_tiles * tile
     x = h.reshape(T, D)
     with jax.named_scope("router"):
         s = jax.nn.sigmoid(jnp.einsum(
@@ -732,30 +776,54 @@ def _moe_dropless(h, layer, cfg: TransformerConfig, live=None):
             jnp.sum(w, axis=-1, keepdims=True), 1e-20)
         local = idx - first
         here = ((local >= 0) & (local < count)).reshape(-1)  # (T·k,)
-        e = jnp.where(here, local.reshape(-1), count)
+        alive = (jnp.ones((T * k,), bool) if live is None
+                 else jnp.repeat(live.reshape(T), k))
+        run = here & alive  # the assignments computed here
+        e = jnp.where(run, local.reshape(-1), count)
         onehot = jax.nn.one_hot(e, count + 1, dtype=jnp.int32)
-        counted = onehot if live is None else onehot * jnp.repeat(
-            live.reshape(T).astype(jnp.int32), k)[:, None]
-        load = jnp.sum(counted, axis=0)
-        # Row of each assignment within its expert, in token order.
+        n = jnp.sum(onehot[:, :count], axis=0)
+        load = jnp.concatenate(
+            [n, jnp.sum(alive & ~here, dtype=jnp.int32)[None]])
+        # Row of each assignment within its expert, in token order,
+        # and in the list: an expert's run starts on a tile.
         pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=-1) - 1
+        tiles = -(-n // tile)
+        ends = jnp.cumsum(tiles)
+        n_tiles = expert_tiles(load, T)[0]
+        starts = jnp.append((ends - tiles) * tile, R)
+        row = jnp.where(run, starts[e] + pos, R)
+        # The inverse map list row -> token, as _moe_mlp builds it (a
+        # scatter of one int32 an assignment; the D-wide rows are
+        # gathered). What is not computed names row R: dropped.
         tok = jnp.arange(T * k) // k
-        # The inverse map (expert, row) -> token, as _moe_mlp builds it
-        # (a scatter of one int32 an assignment; the D-wide rows are
-        # gathered). Assignments held elsewhere name row T: dropped.
-        inv = jnp.zeros((count, T), jnp.int32).at[
-            e, jnp.where(here, pos, T)].set(tok + 1, mode="drop",
-                                            unique_indices=True)
-        gate = jnp.where(here, g.reshape(-1), 0.0)
+        inv = jnp.zeros((R,), jnp.int32).at[row].set(
+            tok + 1, mode="drop", unique_indices=True)
+        # tile -> its expert (tiles past those in use are never run).
+        owner = jnp.minimum(jnp.sum(
+            jnp.arange(max_tiles)[:, None] >= ends[None, :], axis=1),
+            count - 1)
+        gate = jnp.where(run, g.reshape(-1), 0.0)
     with jax.named_scope("experts"):
-        X = jnp.where((inv > 0)[..., None],
-                      x[jnp.maximum(inv - 1, 0)].astype(dt), 0)
-        a = jnp.einsum("ecd,edf->ecf", X, layer["w_gate"].astype(dt))
-        u = jnp.einsum("ecd,edf->ecf", X, layer["w_up"].astype(dt))
-        Y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(a) * u,
-                       layer["w_down"].astype(dt))
-        ys = Y[jnp.minimum(e, count - 1), jnp.clip(pos, 0, T - 1)]
-        ys = ys * gate[:, None].astype(dt)
+        stacks = [layer[m] if at is not None else layer[m][None]
+                  for m in ("w_gate", "w_up", "w_down")]
+        l = jnp.int32(0) if at is None else at
+        xt = x.astype(dt)
+
+        def trip(t, Y):
+            rows = lax.dynamic_slice(inv, (t * tile,), (tile,))
+            X = jnp.where((rows > 0)[:, None],
+                          xt[jnp.maximum(rows - 1, 0)], 0)
+            mine = (l, owner[t], 0, 0)
+            wg, wu, wd = (lax.dynamic_slice(
+                m, mine, (1, 1) + m.shape[2:])[0, 0].astype(dt)
+                for m in stacks)
+            out = jnp.dot(jax.nn.silu(jnp.dot(X, wg)) * jnp.dot(X, wu), wd)
+            return lax.dynamic_update_slice(Y, out, (t * tile, 0))
+
+        # The stacks are closed over and only read: nothing of them is
+        # loop state.
+        Y = lax.fori_loop(0, n_tiles, trip, jnp.zeros((R, D), dt))
+        ys = Y[jnp.minimum(row, R - 1)] * gate[:, None].astype(dt)
         y = jnp.sum(ys.reshape(T, k, D), axis=1).reshape(B, S, D)
     if "ws_gate" in layer:
         with jax.named_scope("shared_expert"):
@@ -796,17 +864,18 @@ def attn_residual(x, o, layer, cfg: TransformerConfig):
 
 @jax.named_scope("mlp")
 def mlp_residual(x, layer, cfg: TransformerConfig,
-                 moe_capacity: int | None = None, live=None):
+                 moe_capacity: int | None = None, live=None, at=None):
     """Pre-norm MLP + residual: a dense SwiGLU, or, in a layer that
     holds a router, the experts behind it as ``cfg.moe_router`` routes
     them. → (x, aux, load): the capacity router's load-balancing loss
     (0.0 without one) and the dropless router's load counts
-    (:func:`_moe_dropless`; None without one)."""
+    (:func:`_moe_dropless`, which also takes ``live`` and ``at``; None
+    without one)."""
     dt = cfg.dtype
     h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
     if "router" in layer:
         if cfg.moe_router == "sigmoid_bias":
-            y, load = _moe_dropless(h, layer, cfg, live)
+            y, load = _moe_dropless(h, layer, cfg, live, at)
             return x + y, jnp.float32(0.0), load
         y, aux = _moe_mlp(h, layer, cfg, capacity=moe_capacity)
         return x + y, aux, None

@@ -417,7 +417,8 @@ class PagedGeneratorActor(GeneratorActor):
             # A dropless router's layers count their load on the
             # device (transformer._moe_dropless): the step then returns,
             # for the host's one fetch, the tokens with the counts of
-            # the live lanes behind them.
+            # the live lanes behind them, and last the tiles the
+            # layers' loops visited and the held experts they hit.
             fetch = (() if load is None
                      else (jnp.concatenate([nxt, load]),))
             return (banks, nxt, jnp.where(active, pos + 1, pos),
@@ -1557,7 +1558,10 @@ class PagedGeneratorActor(GeneratorActor):
             nxt_host = np.array(fetch[0] if fetch else nxt)  # host
             #   mirror for retire bookkeeping
         if fetch:
-            self.ledger.moe_load(nxt_host[self.n_slots:])
+            *counts, tiles, hit = nxt_host[self.n_slots:]
+            self.ledger.moe_load(
+                counts, tiles, hit, tfm.expert_tile(self.n_slots),
+                self.cfg.n_layers - self.cfg.n_dense_layers)
             nxt_host = nxt_host[:self.n_slots]
         with annotate("serve.step/emit"):
             self._pos[self._active] += 1

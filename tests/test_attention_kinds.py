@@ -185,16 +185,20 @@ def test_pools_of_two_kinds_hold_the_layers_of_their_kind():
     assert len({b, c, d}) == 3 and window.check_invariants() == []
 
 
-@pytest.mark.parametrize("cfg", [MIXED, MIXED_MOE], ids=["dense", "experts"])
-def test_engine_serves_what_the_layers_by_hand_give(cfg):
+@pytest.mark.parametrize("cfg,slots", [(MIXED, 2), (MIXED_MOE, 2),
+                                       (MIXED_MOE, 7)],
+                         ids=["dense", "experts", "experts-dead-lanes"])
+def test_engine_serves_what_the_layers_by_hand_give(cfg, slots):
     """Through ``PagedGeneratorActor``: two pools, chunks of 16 over a
     window of 8, rows several windows long, a shared prefix. Every
     served token is the by-hand forward's first; both pools' books
-    balance."""
+    balance. ``experts-dead-lanes``: six of seven lanes never hold a
+    request; they route all alike, onto one pair of experts, and
+    their assignments are neither computed nor counted."""
     params = tfm.init_params(jax.random.PRNGKey(5), cfg)
-    eng = PagedGeneratorActor(cfg, params=params, n_slots=2, max_len=128,
-                              block_tokens=BT, prefill_chunk=16,
-                              n_blocks=48)
+    eng = PagedGeneratorActor(cfg, params=params, n_slots=slots,
+                              max_len=128, block_tokens=BT,
+                              prefill_chunk=16, n_blocks=48)
     rng = np.random.default_rng(4)
     shared = rng.integers(1, cfg.vocab_size, 24).astype(np.int32)
     try:
@@ -212,5 +216,13 @@ def test_engine_serves_what_the_layers_by_hand_give(cfg):
         s = eng.ledger.summary()
         assert s["window_freed"] > 0 and s["window_blocks"] <= 2
         assert "kv_window_free_blocks" in eng.Info()
+        if cfg.n_experts:
+            # One live lane: 29 decode steps x 2 choices x 7 layers,
+            # each choice a tile of its own holding the one row.
+            load = s["moe_load"]
+            assert load["iterations"] == 29 and load["elsewhere"] == 0
+            assert sum(load["held"]) == 29 * 2 * 7
+            assert s["expert_tiles"] == s["experts_hit"] * 7 == 14
+            assert s["expert_tile_fill"] == 1 / tfm.EXPERT_TILE_STEP
     finally:
         eng.close()

@@ -168,6 +168,177 @@ def test_dropless_router_drops_no_token_at_any_load(crowd):
         assert int(load[0]) == int(load[1]) == 48
 
 
+def _padded(h, layer, cfg, live=None):
+    """The product the tile loop replaced, kept here as the formula it
+    is held to: every held expert's assignments scattered into its rows
+    of an (held, T, D) buffer, one batched product a matrix over all of
+    it, a row gathered back an assignment. → (y, load), ``load`` of the
+    tokens ``live`` marks."""
+    B, S, D = h.shape
+    k, (first, count), T = cfg.expert_top_k, cfg.held, B * S
+    x = h.reshape(T, D)
+    s = jax.nn.sigmoid(jnp.einsum("td,de->te", x, layer["router"],
+                                  precision="highest"))
+    _, idx = jax.lax.top_k(s + layer["router_bias"], k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    g = cfg.routed_scale * w / jnp.sum(w, axis=-1, keepdims=True)
+    local = idx - first
+    here = ((local >= 0) & (local < count)).reshape(-1)
+    e = jnp.where(here, local.reshape(-1), count)
+    onehot = jax.nn.one_hot(e, count + 1, dtype=jnp.int32)
+    counted = onehot if live is None else onehot * jnp.repeat(
+        live.reshape(T).astype(jnp.int32), k)[:, None]
+    pos = jnp.sum(jnp.cumsum(onehot, axis=0) * onehot, axis=-1) - 1
+    inv = jnp.zeros((count, T), jnp.int32).at[
+        e, jnp.where(here, pos, T)].set(jnp.arange(T * k) // k + 1,
+                                        mode="drop")
+    X = jnp.where((inv > 0)[..., None], x[jnp.maximum(inv - 1, 0)], 0)
+    a = jnp.einsum("ecd,edf->ecf", X, layer["w_gate"])
+    u = jnp.einsum("ecd,edf->ecf", X, layer["w_up"])
+    Y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(a) * u, layer["w_down"])
+    ys = Y[jnp.minimum(e, count - 1), jnp.clip(pos, 0, T - 1)]
+    ys = ys * jnp.where(here, g.reshape(-1), 0.0)[:, None]
+    y = jnp.sum(ys.reshape(T, k, D), axis=1).reshape(B, S, D)
+    return y + tfm._swiglu(h, layer["ws_gate"], layer["ws_up"],
+                           layer["ws_down"], jnp.float32), jnp.sum(
+                               counted, axis=0)
+
+
+def _share(layer, cfg, first, count):
+    """The layer and configuration of a member that holds ``count``
+    experts from ``first``."""
+    held = {m: layer[m][first:first + count]
+            for m in ("w_gate", "w_up", "w_down")}
+    return {**layer, **held}, dataclasses.replace(
+        cfg, experts_held=(first, count))
+
+
+#: shape (B, S); held (first, count); the experts the bias sends every
+#: token to; the lanes that are dead (None: no ``live`` given).
+TILE_CASES = {
+    "no-held-expert-hit": ((2, 12), (6, 2), (0, 1), None),
+    "one-expert-takes-every-row": ((2, 12), (0, 8), (3,), None),
+    "every-expert-a-partial-tile": ((2, 12), (0, 8), (), None),
+    "assignments-not-a-multiple-of-the-tile": ((1, 13), (2, 5), (), None),
+    "dead-lanes": ((10, 1), (0, 8), (), (0, 3, 4, 8)),
+    "dead-lanes-that-would-crowd": ((10, 1), (0, 8), (), "alike"),
+    "chunk-tile": ((1, 160), (0, 8), (), None),
+    "chunk-tile-one-expert-takes-every-row": ((1, 131), (1, 6), (4,), None),
+    "chunk-tile-pads": ((1, 128), (0, 8), (), tuple(range(50, 128))),
+}
+
+
+@pytest.mark.parametrize("case", list(TILE_CASES))
+def test_tile_loop_gives_what_the_padded_product_gives(case):
+    """The grouped product over tiles of routed rows against the padded
+    ``(held, T, D)`` formula, float32: the outputs of live tokens to
+    rounding, the load exactly, and the tiles the loop visits
+    ``Σ_e ceil(n_e / tile)`` over the live held assignments, at both
+    tile constants (T under and from ``EXPERT_TILE_CHUNK``)."""
+    (B, S), (first, count), crowd, dead = TILE_CASES[case]
+    layer, cfg = _share(_layer(DROPLESS, key=3), DROPLESS, first, count)
+    if crowd:
+        layer["router_bias"] = layer["router_bias"].at[
+            jnp.asarray(crowd)].set(10.0)
+    h = jax.random.normal(jax.random.PRNGKey(11), (B, S, 32), jnp.float32)
+    live = None
+    if dead == "alike":
+        # Inactive lanes hold one token at one position: the same row.
+        dead = (1, 2, 4, 5, 6, 7, 9)
+        h = h.at[jnp.asarray(dead)].set(h[1])
+    if dead is not None:
+        mask = np.ones(B * S, bool)
+        mask[list(dead)] = False
+        live = jnp.asarray(mask.reshape(B, S))
+    T, tile = B * S, tfm.expert_tile(B * S)
+    assert tile == (tfm.EXPERT_TILE_CHUNK if T >= tfm.EXPERT_TILE_CHUNK
+                    else tfm.EXPERT_TILE_STEP)
+    y, load = jax.jit(lambda h: tfm._moe_dropless(h, layer, cfg, live))(h)
+    want, want_load = _padded(h, layer, cfg, live)
+    keep = np.ones(T, bool) if live is None else np.asarray(live).ravel()
+    np.testing.assert_allclose(np.asarray(y).reshape(T, -1)[keep],
+                               np.asarray(want).reshape(T, -1)[keep],
+                               atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    assert load.shape == (count + 1,)
+    assert int(load.sum()) == int(keep.sum()) * cfg.expert_top_k
+    n = np.asarray(load)[:-1]
+    tiles, hit = tfm.expert_tiles(load, T)
+    assert int(tiles) == int(np.sum(-(-n // tile)))
+    assert int(hit) == int(np.sum(n > 0))
+    # What each case is there for.
+    if case == "no-held-expert-hit":
+        assert int(tiles) == 0 and int(load[-1]) == T * 2
+    if "one-expert-takes-every-row" in case:
+        assert int(n[crowd[0] - first]) == T and int(tiles) > -(-T // tile)
+    if case == "every-expert-a-partial-tile":
+        assert (n > 0).all() and (n % tile > 0).all()
+    if case == "assignments-not-a-multiple-of-the-tile":
+        assert int(n.sum()) % tile
+    if case == "dead-lanes-that-would-crowd":
+        # Counted, the seven rows alike would fill a tile of their own.
+        assert int(_padded(h, layer, cfg)[1][:-1].max()) >= 7 > n.max()
+
+
+def test_tile_loop_reads_a_groups_stack_at_the_layers_index():
+    """The paged programs close over a group's ``(n, held, D, F)``
+    stacks and hand the layer's index: the same output as the layer's
+    own ``(held, D, F)`` slices."""
+    params = tfm.init_params(jax.random.PRNGKey(2), DROPLESS)
+    stacked = params["blocks"]
+    h = jax.random.normal(jax.random.PRNGKey(12), (3, 5, 32), jnp.float32)
+    for l in range(DROPLESS.n_layers):
+        layer = jax.tree.map(lambda a: a[l], stacked)
+        whole = {**layer, **{m: stacked[m]
+                             for m in ("w_gate", "w_up", "w_down")}}
+        y, load = tfm._moe_dropless(h, whole, DROPLESS, at=jnp.int32(l))
+        want, want_load = tfm._moe_dropless(h, layer, DROPLESS)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(load),
+                                      np.asarray(want_load))
+
+
+def test_step_reports_the_tiles_its_layers_visited_and_the_ledger_keeps_them():
+    """Through a tiny engine: a decode step returns, behind its load
+    counts, the tiles its expert layers' loops visited and the held
+    experts they hit, of the live lanes alone; the ledger's summary and
+    the ``serve.moe_load`` record say so."""
+    from ptype_tpu import trace
+    from ptype_tpu.metrics import MetricsRegistry
+    from ptype_tpu.serve_engine import PagedGeneratorActor
+
+    cfg = dataclasses.replace(DROPLESS, experts_held=(2, 4))
+    params = tfm.init_params(jax.random.PRNGKey(6), cfg)
+    assert params["blocks"]["w_gate"].shape[:2] == (2, 4)
+    eng = PagedGeneratorActor(cfg, params=params, n_slots=4, max_len=64,
+                              block_tokens=16, prefill_chunk=16,
+                              n_blocks=12,
+                              metrics_registry=MetricsRegistry())
+    rec = trace.enable("moe-tiles")
+    try:
+        prompt = np.arange(3, 23, dtype=np.int32)
+        eng.Generate(jnp.asarray(prompt)[None], 9)
+    finally:
+        trace.disable()
+        eng.close()
+    s = eng.ledger.summary()
+    recs = [sp.attrs for sp in rec.spans() if sp.name == "serve.moe_load"]
+    iters = s["moe_load"]["iterations"]
+    assert iters == len(recs) == 8
+    held = np.sum([[int(c) for c in r["held"].split(":")] for r in recs],
+                  axis=0)
+    assert held.tolist() == s["moe_load"]["held"]
+    # One live lane of four, two choices a layer, two expert layers.
+    assert held.sum() + s["moe_load"]["elsewhere"] == iters * 1 * 2 * 2
+    # A lone row's choices are distinct experts: a tile a held choice.
+    assert sum(r["tiles"] for r in recs) == held.sum() > 0
+    assert s["expert_tiles"] == pytest.approx(held.sum() / iters, abs=1e-3)
+    assert s["experts_hit"] == pytest.approx(held.sum() / iters / 2,
+                                             abs=1e-3)
+    assert s["expert_tile_fill"] == pytest.approx(
+        1 / tfm.EXPERT_TILE_STEP, abs=1e-4)
+
+
 def test_selection_is_by_score_plus_bias_and_the_gate_by_score_alone():
     layer = _layer(DROPLESS, key=7)
     layer["router_bias"] = jnp.zeros(8).at[5].set(10.0)
