@@ -306,7 +306,8 @@ LIVE_TILE_LANES = 8
 
 def live_block_list(tables, nalloc, active, block_tokens: int,
                     tile: int | None = None, first=None,
-                    row_blocks: int | None = None):
+                    row_blocks: int | None = None,
+                    own_tiles: bool = False):
     """The K,V blocks the live rows hold, as the decode step's
     attention reads them (:func:`_live_block_attention`). Host side,
     numpy: ``tables`` (n_slots, nb) block ids in position order,
@@ -324,22 +325,38 @@ def live_block_list(tables, nalloc, active, block_tokens: int,
     a row's window are given back) is listed from ``first`` (n_slots,),
     each row's first block still held; ``row_blocks`` is then the most
     a row holds, so the list covers ``n_slots * row_blocks`` blocks and
-    not every lane's reach."""
+    not every lane's reach.
+
+    ``own_tiles`` (a list from block 0, without ``first``): each row's
+    run starts on a tile (its last tile padded), so that a tile's blocks belong to ONE lane and the step
+    scores that lane's queries alone against it: the list of a cache
+    whose one K,V head many query heads share (a latent cache), where
+    a lane's queries are a matmul's rows by themselves."""
     ns, nb = tables.shape
-    cover = ns * min(nb, int(row_blocks or nb))
-    tile = min(int(tile or LIVE_TILE_BLOCKS), cover)
-    max_tiles = -(-cover // tile)
+    row = min(nb, int(row_blocks or nb))
+    tile = min(int(tile or LIVE_TILE_BLOCKS), ns * row)
+    if own_tiles:
+        tile = min(tile, row)
+    max_tiles = ns * -(-row // tile) if own_tiles else -(-ns * row // tile)
     held = (np.arange(nb)[None, :] < np.asarray(nalloc)[:, None]) \
         & np.asarray(active, bool)[:, None]
     if first is not None:
         held &= np.arange(nb)[None, :] >= np.asarray(first)[:, None]
     lane, col = np.nonzero(held)  # row-major: row after row
     n = lane.size
+    at = np.arange(n)
+    if own_tiles:
+        # Where each lane's run starts: on the tile after the one the
+        # lanes before it end in.
+        runs = -(-held.sum(axis=1) // tile) * tile
+        ends = np.cumsum(runs)
+        at = (ends - runs)[lane] + col
+        n = int(ends[-1])
     blocks = np.zeros((3, max_tiles * tile), np.int32)
     blocks[1] = ns
-    blocks[0, :n] = tables[lane, col]
-    blocks[1, :n] = lane
-    blocks[2, :n] = col * block_tokens
+    blocks[0, at] = tables[lane, col]
+    blocks[1, at] = lane
+    blocks[2, at] = col * block_tokens
     return (blocks.reshape(3, max_tiles, tile),
             np.int32(-(-n // tile)))
 
@@ -363,7 +380,9 @@ def live_lane_list(active, tile: int | None = None):
 
 
 def _live_block_attention(q, kf, vf, base, blocks, limits,
-                          window: int = 0, scope: str = "attn"):
+                          window: int = 0, scope: str = "attn",
+                          scale: float | None = None,
+                          v_dim: int | None = None):
     """Decode attention over the blocks live rows hold: work follows
     Σ live context, not lanes x reach. q: (B, 1, H, Dh); ``kf``/``vf``:
     the flat banks ``(L * n_blocks, block_tokens, Kh, Dh)``, ``base``
@@ -373,6 +392,18 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
     window layer, ``>= limits[b] - window`` (the list then holds the
     window pool's blocks). ``scope`` names the loop in a device trace
     (``attn_window`` / ``attn_full`` in a stack with attention kinds).
+    ``scale`` multiplies the scores (None: ``Dh ** -0.5``).
+
+    What a block holds: K and V per head (``kf``, ``vf``), or, with
+    ``vf`` None, one row a token that is key and value at once — a
+    latent cache's ``(rows, block_tokens, 1, cache_dim)`` view, the
+    queries in the absorbed form (``sparse_mla.absorb_query``): the
+    values are the row's leading ``v_dim`` lanes, one gather serves
+    both, and the result is ``(B, 1, H, v_dim)``. Such a list has
+    every tile owned by one lane (``live_block_list(own_tiles=True)``):
+    the tile is scored against its owner's queries alone (``G`` rows
+    a KV head, all heads of one lane) and folds into that lane's
+    running sums.
 
     One loop over the list's tiles in use (a ``while`` whose trip count
     is data, so ONE compiled program whatever the load): a tile's
@@ -390,6 +421,7 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
     bt, Kh = kf.shape[1], kf.shape[2]
     G = H // Kh
     S = lst.shape[2] * bt
+    Dv = Dh if vf is not None else v_dim
     f32 = jnp.float32
     with jax.named_scope(scope):
         qg = q.reshape(B, Kh, G, Dh)
@@ -398,19 +430,36 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
         offs = jnp.arange(bt, dtype=jnp.int32)
 
         def fold(t, carry):
-            m, l, acc = carry
             ids, owner, first = lst[0, t], lst[1, t], lst[2, t]
             with jax.named_scope("kv_gather"):
                 ks = kf[base + ids].reshape(S, Kh, Dh)
-                vs = vf[base + ids].reshape(S, Kh, Dh)
-            s = jnp.einsum("bkgd,skd->bkgs", qg, ks).astype(f32)
-            s = s / jnp.sqrt(f32(Dh))
+                vs = (ks[..., :Dv] if vf is None
+                      else vf[base + ids].reshape(S, Kh, Dh))
+            if vf is None:
+                # The tile's one lane: its row of the queries, the
+                # limits and the running sums.
+                mine = jnp.minimum(owner[0], B - 1)
+
+                def row(a):
+                    return lax.dynamic_index_in_dim(a, mine, 0)
+
+                def put(a, new):
+                    return lax.dynamic_update_index_in_dim(a, new, mine, 0)
+            else:
+                def row(a):
+                    return a
+
+                def put(a, new):
+                    return new
+            m, l, acc = (row(a) for a in carry)
+            s = jnp.einsum("bkgd,skd->bkgs", row(qg), ks).astype(f32)
+            s = s / jnp.sqrt(f32(Dh)) if scale is None else s * f32(scale)
             owner = jnp.repeat(owner, bt)
             at = (first[:, None] + offs[None, :]).reshape(S)
-            mask = ((owner[None, :] == lanes[:, None])
-                    & (at[None, :] < limits[:, None]))
+            mask = ((owner[None, :] == row(lanes)[:, None])
+                    & (at[None, :] < row(limits)[:, None]))
             if window:
-                mask &= at[None, :] >= limits[:, None] - window
+                mask &= at[None, :] >= row(limits)[:, None] - window
             mask = mask[:, None, None, :]
             s = jnp.where(mask, s, f32(-1e30))
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -421,15 +470,16 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
             l = l * alpha + jnp.sum(p, axis=-1)
             pv = jnp.einsum("bkgs,skd->bkgd", p.astype(q.dtype), vs,
                             preferred_element_type=f32)
-            return m_new, l, acc * alpha[..., None] + pv
+            return tuple(put(a, new) for a, new in zip(
+                carry, (m_new, l, acc * alpha[..., None] + pv)))
 
         m, l, acc = lax.fori_loop(
             0, n_tiles, fold,
             (jnp.full((B, Kh, G), -1e30, f32),
              jnp.zeros((B, Kh, G), f32),
-             jnp.zeros((B, Kh, G, Dh), f32)))
+             jnp.zeros((B, Kh, G, Dv), f32)))
         o = acc / jnp.where(l > 0, l, f32(1))[..., None]
-        return o.astype(q.dtype).reshape(B, 1, H, Dh)
+        return o.astype(q.dtype).reshape(B, 1, H, Dv)
 
 
 #: Blocks in one tile of a full-attention layer's table walk
@@ -439,8 +489,11 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
 TABLE_TILE_BLOCKS = 64
 
 
-def _table_attention(q, kf, vf, tables, limits, window: int, scope: str):
-    """Attention through block tables in a stack with attention kinds,
+def _table_attention(q, kf, vf, tables, limits, window: int, scope: str,
+                     scale: float | None = None, v_dim: int | None = None):
+    """Attention through block tables in a stack with attention kinds
+    or a latent cache no indexer selects from (``vf`` None, ``v_dim``
+    and ``scale`` as :func:`_live_block_attention` takes them),
     for queries that are many to a table (a prefill chunk) or rows
     with no block list: the tables are walked a tile of blocks at a
     time and folded into a float32 running softmax, the trip count
@@ -461,6 +514,7 @@ def _table_attention(q, kf, vf, tables, limits, window: int, scope: str):
     nb = tables.shape[1]
     bt, Kh = kf.shape[1], kf.shape[2]
     G = H // Kh
+    Dv = Dh if vf is not None else v_dim
     f32 = jnp.float32
     limits = jnp.asarray(limits, jnp.int32)
     hi = limits[:, None] if limits.ndim == 1 else limits  # (B, 1|Q)
@@ -487,9 +541,10 @@ def _table_attention(q, kf, vf, tables, limits, window: int, scope: str):
                 ids = jnp.take_along_axis(
                     tables, jnp.minimum(cols, nb - 1), axis=1)
                 ks = kf[ids].reshape(B, S, Kh, Dh)
-                vs = vf[ids].reshape(B, S, Kh, Dh)
+                vs = (ks[..., :Dv] if vf is None
+                      else vf[ids].reshape(B, S, Kh, Dh))
             s = jnp.einsum("bqkgd,bskd->bkgqs", qg, ks).astype(f32)
-            s = s / jnp.sqrt(f32(Dh))
+            s = s / jnp.sqrt(f32(Dh)) if scale is None else s * f32(scale)
             # Columns past the table sit past every limit.
             at = (col0[:, None] + t * tile) * bt + offs[None, :]  # (B, S)
             mask = at[:, None, :] < hi[:, :, None]             # (B, Q, S)
@@ -509,10 +564,10 @@ def _table_attention(q, kf, vf, tables, limits, window: int, scope: str):
             0, n_tiles, fold,
             (jnp.full((B, Kh, G, Q), -1e30, f32),
              jnp.zeros((B, Kh, G, Q), f32),
-             jnp.zeros((B, Kh, G, Q, Dh), f32)))
+             jnp.zeros((B, Kh, G, Q, Dv), f32)))
         o = acc / jnp.where(l > 0, l, f32(1))[..., None]
-        o = jnp.transpose(o, (0, 3, 1, 2, 4))  # (B, Q, Kh, G, Dh)
-        return o.astype(q.dtype).reshape(B, Q, H, Dh)
+        o = jnp.transpose(o, (0, 3, 1, 2, 4))  # (B, Q, Kh, G, Dv)
+        return o.astype(q.dtype).reshape(B, Q, H, Dv)
 
 
 def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
@@ -522,8 +577,8 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     step, prefill chunk, speculative verify and draft). ``banks`` is
     the cache as the model describes it (``tfm.cache_spec``: a dict of
     ``(L, n_blocks, block_tokens, ...)`` arrays — ``k``, ``v`` for GQA,
-    ``ckv``, ``ki`` for latent attention). The banks ride the scan's
-    CARRY, whole, and a layer reaches its own rows through the indices
+    ``ckv`` and, behind an indexer, ``ki`` for latent attention). The
+    banks ride the scan's CARRY, whole, and a layer reaches its own rows through the indices
     of its scatter and gather: in the free flat view ``(L * n_blocks,
     block_tokens, ...)`` layer ``l``'s block ``b`` is row ``l *
     n_blocks + b``. (Scanned, every layer sliced its bank out of the
@@ -550,10 +605,14 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     the live rows hold, in the form the cache kind reads, given to a
     decode step. GQA: the block list (:func:`live_block_list`); the
     step attends over it (:func:`_live_block_attention`) and not
-    through ``tables``, which then only route the writes. Latent: the
-    lane list (:func:`live_lane_list`); index, selection, gather and
-    attention run over its lanes alone. Returns ``(x (B, Q, D) before the
-    final norm, banks, load)``; ``load`` is a dropless router's counts
+    through ``tables``, which then only route the writes. Latent
+    behind an indexer: the lane list (:func:`live_lane_list`); index,
+    selection, gather and attention run over its lanes alone. Latent
+    with no indexer: the block list again, each lane's run on tiles of
+    its own (``own_tiles``), read in the absorbed form; its prefill
+    chunk walks the table (:func:`_table_attention`). Returns ``(x (B,
+    Q, D) before the final norm, banks, load)``; ``load`` is a
+    dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
     ``live`` (B, Q) marks, if given) and behind them two more int32,
     the tiles its layers' loops visited and the held experts they hit
@@ -614,17 +673,35 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     else:
         from ptype_tpu.models import sparse_mla
 
+        la = cfg.latent
+
         def attention(x, bf, layer, base, window=None):
             q_nope, q_rope, ckv, qi, ki, wi = sparse_mla.project(
                 x, layer, cfg, positions)
             with jax.named_scope("kv_write"):
-                cf = bf["ckv"].at[base + wr_b, wr_o].set(ckv)
-                kif = bf["ki"].at[base + wr_b, wr_o].set(ki)
-            o = sparse_mla.attend_paged(q_nope, q_rope, qi, wi, cf, kif,
-                                        base + tables, limits, layer,
-                                        cfg, whole_context=chunk,
-                                        lanes=live_list)
-            return o, {"ckv": cf, "ki": kif}
+                own = {"ckv": bf["ckv"].at[base + wr_b, wr_o].set(ckv)}
+                if la.indexer:
+                    own["ki"] = bf["ki"].at[base + wr_b, wr_o].set(ki)
+            if la.indexer:
+                o = sparse_mla.attend_paged(
+                    q_nope, q_rope, qi, wi, own["ckv"], own["ki"],
+                    base + tables, limits, layer, cfg,
+                    whole_context=chunk, lanes=live_list)
+                return o, own
+            # Nothing selects: every held position is read, and the
+            # bank is one K,V head whose row is key and value at once.
+            with jax.named_scope("attn"):
+                qa = sparse_mla.absorb_query(q_nope, q_rope, layer, cfg)
+            rows = own["ckv"][:, :, None]
+            how = dict(scale=sparse_mla.score_scale(cfg), v_dim=la.kv_rank)
+            if live_list is not None:
+                ol = _live_block_attention(qa, rows, None, base, live_list,
+                                           limits, **how)
+            else:
+                ol = _table_attention(qa, rows, None, base + tables,
+                                      limits, 0, "attn", **how)
+            with jax.named_scope("attn"):
+                return sparse_mla.expand_values(ol, layer, cfg), own
 
     def layers_of(window, experts):
         def body(carry, inputs):
@@ -694,7 +771,10 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     reads. GQA (:func:`live_block_list`'s pair): their K,V blocks; the
     step reads those and no others, tile by tile
     (:func:`_live_block_attention`), so its cost follows the tokens in
-    flight. Latent (:func:`live_lane_list`'s pair): their lanes; the
+    flight; a latent cache with no indexer is read the same way, its
+    one row a token key and value at once and each row's run of the
+    list on tiles of its own. Latent behind an indexer
+    (:func:`live_lane_list`'s pair): their lanes; the
     indexer, the selection, the latent gather and the attention run
     over those, a tile of lanes at a time
     (``sparse_mla.attend_paged``), so their cost follows the rows in

@@ -1,16 +1,25 @@
-"""Latent K,V attention with a learned sparse-attention indexer.
+"""Latent K,V attention, with or without a learned sparse-attention
+indexer.
 
-The attention of the DeepSeek-V2/V3 line (MLA) with DeepSeek-V3.2's
-indexer in front of it, as ``TransformerConfig.latent`` sizes it:
+The attention of the DeepSeek-V2/V3 line (MLA), alone or with
+DeepSeek-V3.2's indexer in front of it, as ``TransformerConfig.latent``
+sizes it:
 
 - **Latent cache.** A token holds, a layer, the RMS-normed latent
   ``cKV`` (``kv_rank``) with one rotary key ``kR`` (``rope_dim``)
   shared by all heads — bank ``ckv``, its row padded to whole lane
-  tiles (``LatentAttention.cache_dim``) — and the indexer's key ``kI``
-  (``index_dim``) — bank ``ki`` — instead of K and V per head.
-- **Indexer.** ``I[t, s] = Σ_j w[t, j] · ReLU(qI[t, j] · kI[s])`` over
-  ``index_heads``; a query attends the ``index_topk`` keys ``s ≤ t``
-  with the largest ``I`` (all of them while fewer are held).
+  tiles (``LatentAttention.cache_dim``) — instead of K and V per head,
+  and with an indexer its key ``kI`` (``index_dim``) — bank ``ki``.
+- **Indexer** (``LatentAttention.indexer``). ``I[t, s] = Σ_j w[t, j] ·
+  ReLU(qI[t, j] · kI[s])`` over ``index_heads``; a query attends the
+  ``index_topk`` keys ``s ≤ t`` with the largest ``I`` (all of them
+  while fewer are held). **Without one** a query attends every key
+  ``s ≤ t``: nothing is selected, and in the absorbed form the cache
+  row is one K,V head of ``cache_dim`` lanes shared by all query heads
+  whose values are its leading ``kv_rank`` lanes, so the paged
+  programs read it through the block-list and table walks GQA has
+  (``generate._live_block_attention``, ``generate._table_attention``;
+  :func:`absorb_query` and :func:`expand_values` around them).
 - **Two forms of one attention.** *Expanded* (:func:`attend_expanded`,
   the contiguous forward): per-head keys and values are made from the
   latent and every unselected key is masked. *Absorbed*
@@ -27,7 +36,9 @@ selection, gather and attention cost what is live, not ``n_slots``.
 
 Rotary positions rotate adjacent pairs (``rope_interleave``), on all of
 ``kR`` / ``q^rope`` and on the leading ``index_rope_dim`` dims of the
-indexer's q and k. Scopes: ``qkv`` (norms and projections), ``index``
+indexer's q and k; with ``cfg.rope_yarn`` the tables are YaRN's and the
+scores carry its factor (:func:`score_scale`). Scopes: ``qkv`` (norms
+and projections), ``index``
 (the indexer's projections and scores), ``select`` (top-k),
 ``kv_gather``, ``attn``.
 """
@@ -58,7 +69,7 @@ def init_attention(key, cfg: tfm.TransformerConfig, n: int) -> dict:
     def norm(k, shape, scale=0.02):
         return tfm.scaled_normal(k, shape, scale, pd)
 
-    return {
+    attn = {
         "attn_norm": jnp.ones((n, D), pd),
         "w_dq": norm(ks[0], (n, D, la.q_rank)),
         "q_norm": jnp.ones((n, la.q_rank), pd),
@@ -68,12 +79,15 @@ def init_attention(key, cfg: tfm.TransformerConfig, n: int) -> dict:
         "w_uk": norm(ks[3], (n, la.kv_rank, H, la.nope_dim)),
         "w_uv": norm(ks[4], (n, la.kv_rank, H, la.v_dim)),
         "wo": norm(ks[5], (n, H, la.v_dim, D), resid),
-        "w_iq": norm(ks[6], (n, la.q_rank, la.index_heads, la.index_dim)),
-        "w_ik": norm(ks[7], (n, D, la.index_dim)),
-        "ik_norm": jnp.ones((n, la.index_dim), pd),
-        "ik_norm_b": norm(ks[8], (n, la.index_dim)),
-        "w_iw": norm(ks[9], (n, D, la.index_heads)),
     }
+    if la.indexer:
+        attn.update(
+            w_iq=norm(ks[6], (n, la.q_rank, la.index_heads, la.index_dim)),
+            w_ik=norm(ks[7], (n, D, la.index_dim)),
+            ik_norm=jnp.ones((n, la.index_dim), pd),
+            ik_norm_b=norm(ks[8], (n, la.index_dim)),
+            w_iw=norm(ks[9], (n, D, la.index_heads)))
+    return attn
 
 
 def rope_interleaved(x, sin, cos):
@@ -110,7 +124,7 @@ def project(x, layer, cfg: tfm.TransformerConfig, positions):
     [normed latent ; rotated shared key ; zeros to whole lane tiles],
     and the indexer's ``qi``
     (B, Q, J, di), ``ki`` (B, Q, di) and head weights ``wi``
-    (B, Q, J) float32."""
+    (B, Q, J) float32, each None without an indexer."""
     la, dt, eps = cfg.latent, cfg.dtype, cfg.norm_eps
     positions = jnp.asarray(positions)
     if positions.ndim == 1:
@@ -131,6 +145,8 @@ def project(x, layer, cfg: tfm.TransformerConfig, positions):
             [tfm.rms_norm(kv[..., :la.kv_rank], layer["kv_norm"], eps),
              rope_interleaved(kv[..., la.kv_rank:], sin, cos),
              jnp.zeros(kv.shape[:-1] + (pad,), kv.dtype)], axis=-1)
+    if not la.indexer:
+        return q_nope, q_rope, ckv, None, None, None
     with jax.named_scope("index"):
         isin, icos = tfm.rope_tables(cfg, positions=positions,
                                      dim=la.index_rope_dim)
@@ -148,6 +164,38 @@ def project(x, layer, cfg: tfm.TransformerConfig, positions):
     return q_nope, q_rope, ckv, qi, ki, wi
 
 
+def score_scale(cfg: tfm.TransformerConfig) -> float:
+    """What a score is multiplied by: ``qk_dim ** -0.5`` and YaRN's
+    factor (``tfm.yarn_score_factor``)."""
+    return cfg.latent.qk_dim ** -0.5 * tfm.yarn_score_factor(cfg)
+
+
+def absorb_query(q_nope, q_rope, layer, cfg: tfm.TransformerConfig):
+    """Absorb W_UK into the query: (q^nope W_UK^T) · cKV = q^nope ·
+    (W_UK cKV); the rotary part rides beside it, so one product against
+    the cache row [cKV ; kR ; 0] gives the score. → (B, Q, H,
+    cache_dim)."""
+    la, dt = cfg.latent, cfg.dtype
+    B, Q, H, _ = q_nope.shape
+    return jnp.concatenate(
+        [jnp.einsum("bqhn,chn->bqhc", q_nope, layer["w_uk"].astype(dt)),
+         q_rope, jnp.zeros((B, Q, H, la.cache_dim - la.row_dim), dt)],
+        axis=-1)
+
+
+def expand_values(ol, layer, cfg: tfm.TransformerConfig):
+    """W_UV after the sum: the attention's output over the latent
+    (B, Q, H, kv_rank) → per-head values (B, Q, H, v)."""
+    return jnp.einsum("bqhc,chv->bqhv", ol,
+                      layer["w_uv"].astype(cfg.dtype))
+
+
+def _scaled(scores, cfg: tfm.TransformerConfig):
+    scores = scores / jnp.sqrt(jnp.float32(cfg.latent.qk_dim))
+    f = tfm.yarn_score_factor(cfg)
+    return scores if f == 1.0 else scores * jnp.float32(f)
+
+
 def index_scores(qi, wi, ki):
     """``I`` (B, Q, T) float32 of queries ``qi`` (B, Q, J, di), ``wi``
     (B, Q, J) on keys ``ki`` (B, T, di)."""
@@ -159,21 +207,25 @@ def index_scores(qi, wi, ki):
 def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
     """The expanded form over one contiguous sequence from position 0:
     x (B, S, D) → o (B, S, H, v). Per-head keys and values are made
-    from every token's latent; a query's unselected keys are masked."""
+    from every token's latent; a query's unselected keys are masked
+    (without an indexer: the keys after it)."""
     la, dt = cfg.latent, cfg.dtype
     B, S, _ = x.shape
     q_nope, q_rope, ckv, qi, ki, wi = project(x, layer, cfg,
                                               jnp.arange(S))
     causal = jnp.tril(jnp.ones((S, S), jnp.bool_))
-    with jax.named_scope("index"):
-        I = jnp.where(causal[None], index_scores(qi, wi, ki), _NEG)
-    with jax.named_scope("select"):
-        k = min(la.index_topk, S)
-        _, idx = lax.top_k(I, k)  # (B, S, k)
-        chosen = jnp.zeros((B, S, S), jnp.bool_).at[
-            jnp.arange(B)[:, None, None], jnp.arange(S)[None, :, None],
-            idx].set(True)
-        mask = chosen & causal[None]
+    if la.indexer:
+        with jax.named_scope("index"):
+            I = jnp.where(causal[None], index_scores(qi, wi, ki), _NEG)
+        with jax.named_scope("select"):
+            k = min(la.index_topk, S)
+            _, idx = lax.top_k(I, k)  # (B, S, k)
+            chosen = jnp.zeros((B, S, S), jnp.bool_).at[
+                jnp.arange(B)[:, None, None],
+                jnp.arange(S)[None, :, None], idx].set(True)
+            mask = chosen & causal[None]
+    else:
+        mask = causal[None]
     with jax.named_scope("attn"):
         c_kv = ckv[..., :la.kv_rank]
         k_r = ckv[..., la.kv_rank:la.row_dim]
@@ -184,7 +236,7 @@ def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
                              preferred_element_type=jnp.float32)
                   + jnp.einsum("bqhr,bsr->bhqs", q_rope, k_r,
                                preferred_element_type=jnp.float32))
-        scores = scores / jnp.sqrt(jnp.float32(la.qk_dim))
+        scores = _scaled(scores, cfg)
         scores = jnp.where(mask[:, None], scores, _NEG)
         probs = jax.nn.softmax(scores, axis=-1).astype(dt)
         return jnp.einsum("bhqs,bshv->bqhv", probs, v)
@@ -193,7 +245,9 @@ def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
 def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
                  limits, layer, cfg: tfm.TransformerConfig,
                  whole_context: bool = False, lanes=None):
-    """The absorbed form through a block table. Queries (B, Q, ...) as
+    """The absorbed form through a block table, behind the indexer
+    (without one the paged programs walk the block list or the table:
+    ``generate._paged_layers``). Queries (B, Q, ...) as
     :func:`project` gives them; ``ckv_bank`` (rows, bt, cache_dim)
     and ``ki_bank`` (rows, bt, di) the banks in the flat view;
     ``tables`` (B, nb) this layer's rows of them in position order;
@@ -234,14 +288,7 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
         with jax.named_scope("kv_gather"):
             ctx = ckv_bank[tables].reshape(B, T, la.cache_dim)
     with jax.named_scope("attn"):
-        # Absorb W_UK into the query: (q^nope W_UK^T) · cKV = q^nope ·
-        # (W_UK cKV); the rotary part rides beside it, so one product
-        # against the cache row [cKV ; kR ; 0] gives the score.
-        qa = jnp.concatenate(
-            [jnp.einsum("bqhn,chn->bqhc", q_nope,
-                        layer["w_uk"].astype(dt)), q_rope,
-             jnp.zeros((B, Q, H, la.cache_dim - la.row_dim), dt)],
-            axis=-1)
+        qa = absorb_query(q_nope, q_rope, layer, cfg)
 
     def block(qa, qi, wi, limits, tables=tables, ki=ki):
         n, Qb = qa.shape[:2]
@@ -265,13 +312,12 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
         with jax.named_scope("attn"):
             scores = jnp.einsum("bqhc,bqkc->bqhk", qa, sel,
                                 preferred_element_type=jnp.float32)
-            scores = scores / jnp.sqrt(jnp.float32(la.qk_dim))
+            scores = _scaled(scores, cfg)
             scores = jnp.where(ok[:, :, None], scores, _NEG)
             probs = jax.nn.softmax(scores, axis=-1).astype(dt)
             ol = jnp.einsum("bqhk,bqkc->bqhc", probs,
                             sel[..., :la.kv_rank])
-            return jnp.einsum("bqhc,chv->bqhv", ol,
-                              layer["w_uv"].astype(dt))
+            return expand_values(ol, layer, cfg)
 
     if lanes is not None:
         # The banks are closed over and only read: a bank in the loop's

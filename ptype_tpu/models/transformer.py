@@ -15,12 +15,14 @@ anything:
   parameters and the softmax/logit paths stay f32 for stability.
 - **RMSNorm + RoPE + SwiGLU + GQA** — one architecture covers the
   125M optimus preset and the Llama-3-8B FSDP baseline config. Beside
-  it, for serving: latent K,V attention behind a sparse-attention
-  indexer (models/sparse_mla.py), a stack of several layer groups
+  it, for serving: latent K,V attention, behind a sparse-attention
+  indexer or reading a row's whole context (models/sparse_mla.py),
+  YaRN's scaled positions, a stack of several layer groups
   (:func:`layer_groups`: one scan a run of identical layers), layers
   of a sliding window among full ones with a cache a kind
   (``attn_windows``, :func:`cache_layers`), a stated head width and a
-  per-head q/k norm, and a dropless router over a share of the experts
+  per-head q/k norm, and a dropless router over a share of the experts,
+  choosing inside groups where the model says so
   (:func:`_moe_dropless`).
 - **Sharding by annotation.** :func:`param_specs` returns a PartitionSpec
   pytree (fsdp/model axes); the train layer jits with those shardings and
@@ -45,6 +47,7 @@ anything:
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from functools import partial
 
@@ -58,19 +61,26 @@ from ptype_tpu.parallel.topology import DATA_AXIS
 
 @dataclass(frozen=True)
 class LatentAttention:
-    """Widths of latent K,V attention (DeepSeek-V2 MLA) and of the
-    sparse-attention indexer that picks its keys (DeepSeek-V3.2)."""
+    """Widths of latent K,V attention (DeepSeek-V2 MLA) and, where the
+    model has one, of the sparse-attention indexer that picks its keys
+    (DeepSeek-V3.2). Without the indexer's widths (``index_topk`` 0)
+    a query reads every latent row it can see."""
 
     q_rank: int
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    index_heads: int
-    index_dim: int
-    index_topk: int
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     #: Leading dims of the indexer's q and k that rotate.
     index_rope_dim: int = 64
+
+    @property
+    def indexer(self) -> bool:
+        """An indexer selects each query's keys."""
+        return self.index_topk > 0
 
     @property
     def qk_dim(self) -> int:
@@ -93,6 +103,24 @@ class LatentAttention:
 
 
 @dataclass(frozen=True)
+class YarnScaling:
+    """YaRN's scaling of the rotary positions, in DeepSeek-V3's form
+    (:func:`rope_tables`, :func:`yarn_score_factor`)."""
+
+    factor: float
+    #: The reach the model was trained at before the scaling.
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+@dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
     d_model: int = 768
@@ -104,6 +132,9 @@ class TransformerConfig:
     d_ff: int = 2048
     max_seq: int = 1024
     rope_theta: float = 10000.0
+    #: YaRN's scaling of the rotary frequencies, with its factor on
+    #: the attention scores (latent attention's); None → plain RoPE.
+    rope_yarn: "YarnScaling | None" = None
     #: Compute dtype for MXU matmuls; params stay in param_dtype.
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
@@ -143,8 +174,9 @@ class TransformerConfig:
     moe_aux_coef: float = 0.01
     #: RMSNorm epsilon.
     norm_eps: float = 1e-6
-    #: Latent (low-rank) K,V attention with a learned sparse-attention
-    #: indexer (models/sparse_mla.py) in place of GQA; None → GQA.
+    #: Latent (low-rank) K,V attention (models/sparse_mla.py) in place
+    #: of GQA, with a learned sparse-attention indexer if its widths
+    #: are stated; None → GQA.
     latent: "LatentAttention | None" = None
     #: Leading layers with a dense MLP before the expert layers (the
     #: layer stack is then two groups, each its own ``lax.scan``).
@@ -159,6 +191,11 @@ class TransformerConfig:
     #: dropped (:func:`_moe_dropless`).
     moe_router: str = "softmax"
     routed_scale: float = 1.0
+    #: (groups, kept): the dropless router chooses inside groups — the
+    #: ``n_experts`` scores form ``groups`` equal runs, a group's score
+    #: is the sum of its two largest, and only the ``kept`` best
+    #: groups' experts can be chosen. (1, 1): no limit.
+    expert_groups: tuple[int, int] = (1, 1)
     #: (first, count): the slice of the ``n_experts`` this program
     #: holds, as one member of an expert-parallel group; the router
     #: keeps ``n_experts`` outputs and what the absent experts would
@@ -181,6 +218,18 @@ class TransformerConfig:
     nope_full: bool = False
 
     def __post_init__(self):
+        if self.rope_yarn is not None and self.latent is None:
+            raise ValueError(
+                "rope_yarn scales the scores of latent attention "
+                "(yarn_score_factor); the GQA paths have no such factor")
+        groups, kept = self.expert_groups
+        if groups > 1 and (self.n_experts % groups or not
+                           0 < kept <= groups
+                           or self.n_experts // groups < 2):
+            raise ValueError(
+                f"expert_groups {self.expert_groups}: {self.n_experts} "
+                f"experts do not form {groups} equal groups of two or "
+                f"more with 1..{groups} of them kept")
         w = self.attn_windows
         if w is None:
             return
@@ -376,8 +425,10 @@ def cache_spec(cfg: TransformerConfig) -> dict[str, tuple[int, ...]]:
     token; how MANY tokens a layer holds is its kind's
     (:func:`cache_layers`)."""
     if cfg.latent is not None:
-        return {"ckv": (cfg.latent.cache_dim,),
-                "ki": (cfg.latent.index_dim,)}
+        spec = {"ckv": (cfg.latent.cache_dim,)}
+        if cfg.latent.indexer:
+            spec["ki"] = (cfg.latent.index_dim,)
+        return spec
     return {"k": (cfg.kv_heads, cfg.head_dim),
             "v": (cfg.kv_heads, cfg.head_dim)}
 
@@ -521,10 +572,43 @@ def rope_tables(cfg: TransformerConfig, seq_len: int | None = None,
     )
     if positions is None:
         positions = jnp.arange(seq_len)
+    y = cfg.rope_yarn
+    if y is not None:
+        # DeepSeek-V3's YaRN: a pair that turns more than ``beta_fast``
+        # times within the original reach keeps its frequency, one that
+        # turns fewer than ``beta_slow`` times has it divided by
+        # ``factor``, and a linear ramp over the pair index blends the
+        # two between those (``find_correction_range``). Written as one
+        # factor on ``inv_freq`` so that ``factor`` 1 is exactly 1.
+        def pair_of(turns):
+            return (2 * half * math.log(y.original_max
+                                        / (turns * 2 * math.pi))
+                    / (2 * math.log(cfg.rope_theta)))
+
+        low = max(math.floor(pair_of(y.beta_fast)), 0)
+        high = min(math.ceil(pair_of(y.beta_slow)), 2 * half - 1)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low)
+            / ((high if high != low else high + 0.001) - low), 0, 1)
+        inv_freq = inv_freq * (1.0 - ramp * (1.0 - 1.0 / y.factor))
     # Broadcast (not outer, which flattens): positions may be (S,) —
     # shared, the training path — or (B, S) for per-row ragged offsets.
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    if y is not None:
+        m = (yarn_mscale(y.factor, y.mscale)
+             / yarn_mscale(y.factor, y.mscale_all_dim))
+        return jnp.sin(angles) * m, jnp.cos(angles) * m
     return jnp.sin(angles), jnp.cos(angles)
+
+
+def yarn_score_factor(cfg: TransformerConfig) -> float:
+    """What YaRN multiplies the attention scores by, beside
+    ``qk_dim ** -0.5``: ``yarn_mscale(factor, mscale_all_dim) ** 2``
+    (DeepSeek-V3); 1.0 without YaRN or with ``mscale_all_dim`` 0."""
+    y = cfg.rope_yarn
+    if y is None or not y.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(y.factor, y.mscale_all_dim) ** 2
 
 
 def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
@@ -724,11 +808,13 @@ def _moe_dropless(h, layer, cfg: TransformerConfig, live=None, at=None):
 
     The router keeps its ``n_experts`` outputs: sigmoid scores ``s``,
     the ``expert_top_k`` largest ``s + bias`` chosen (``bias`` the
-    learned correction, selection only), gates ``routed_scale · s /
-    Σ_chosen s``. Of a token's choices those that fall on
-    ``cfg.held`` are computed, as a grouped product over tiles of the
-    routed rows: the assignments are ordered by held expert (token
-    order inside an expert), each expert's run padded to a multiple of
+    learned correction, selection only; with ``cfg.expert_groups``
+    among the experts of the best groups alone, DeepSeek-V3's
+    group-limited choice), gates ``routed_scale · s / Σ_chosen s``.
+    Of a token's choices those that fall on ``cfg.held`` are computed,
+    as a grouped product over tiles of the routed rows: the
+    assignments are ordered by held expert (token order inside an
+    expert), each expert's run padded to a multiple of
     :func:`expert_tile` rows so that a tile belongs to one expert, and
     ONE loop runs over the tiles in use, ``Σ_e ceil(n_e / tile)``, a
     trip count that is data. A trip gathers its tile's token rows,
@@ -770,7 +856,19 @@ def _moe_dropless(h, layer, cfg: TransformerConfig, live=None, at=None):
         s = jax.nn.sigmoid(jnp.einsum(
             "td,de->te", x.astype(jnp.float32),
             layer["router"].astype(jnp.float32), precision="highest"))
-        _, idx = lax.top_k(s + layer["router_bias"].astype(jnp.float32), k)
+        sel = s + layer["router_bias"].astype(jnp.float32)
+        groups, kept = cfg.expert_groups
+        if groups > 1:
+            # Choice inside groups: only the experts of the ``kept``
+            # groups with the largest sum of their two best can win.
+            by_group = sel.reshape(T, groups, -1)
+            standing = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+            _, best = lax.top_k(standing, kept)
+            stays = jnp.any(jax.nn.one_hot(best, groups, dtype=bool),
+                            axis=1)  # (T, groups)
+            sel = jnp.where(stays[:, :, None], by_group,
+                            -jnp.inf).reshape(sel.shape)
+        _, idx = lax.top_k(sel, k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         g = cfg.routed_scale * w / jnp.maximum(
             jnp.sum(w, axis=-1, keepdims=True), 1e-20)

@@ -4,7 +4,8 @@ Banks of fixed-size blocks back every live sequence on a serving
 actor: one ``(L, n_blocks, block_tokens, ...)`` array for each named
 per-token array the model says a layer's cache holds
 (``transformer.cache_spec``: ``k`` and ``v`` of ``(Kh, Dh)`` for GQA,
-the latent ``ckv`` and the indexer's key ``ki`` for latent attention).
+the latent ``ckv`` and, behind an indexer, its key ``ki`` for latent
+attention).
 The pool allocates from that description, the migrator packs by it and
 the engine's programs carry the banks as one dict. A model whose
 layers do not all keep the same tokens (``transformer.cache_layers``:

@@ -13,8 +13,10 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   admission, retire and block boundary) and the step loops over the
   list's tiles in use, so a step costs the weights plus Σ live
   context, whatever ``n_slots`` and the reach are — one compiled
-  program for every trip count. A latent step (an indexer selects
-  each row's keys) is handed the list of live LANES instead
+  program for every trip count. A latent cache with no indexer is
+  read the same way (each row's run of the list on tiles of its own);
+  a latent step whose indexer selects each row's keys is handed the
+  list of live LANES instead
   (``generate.live_lane_list``, rebuilt at the same moments): index,
   selection, gather and attention run over those lanes, a tile of
   them a trip, and cost what is live whatever ``n_slots`` is. Greedy
@@ -431,9 +433,13 @@ class PagedGeneratorActor(GeneratorActor):
         #: authoritative and must be re-uploaded (set dirty by
         #: admission, retire, and block-boundary allocation).
         self._dev: dict | None = None
+        #: An indexer selects each query's keys (latent attention
+        #: behind one): the step then reads by lane, not by block.
+        self._selects = cfg.latent is not None and cfg.latent.indexer
         #: The engine hands the step the list of what its live rows
-        #: hold, rebuilt with ``_dev``: a GQA engine the blocks
-        #: (gen.live_block_list), a latent one the lanes
+        #: hold, rebuilt with ``_dev``: the blocks
+        #: (gen.live_block_list; a latent cache's with each row's run
+        #: on tiles of its own), or where an indexer selects the lanes
         #: (gen.live_lane_list). The step's attention then costs what
         #: is in flight, not n_slots x reach. ``_kv``: the list's
         #: counts, as the dispatch span carries them (``kv_blocks``,
@@ -1488,10 +1494,11 @@ class PagedGeneratorActor(GeneratorActor):
             # sees a new signature and compiles again (chip run, PR 21).
             with annotate("serve.step/upload"):
                 tables = self._tables
-                if self.cfg.latent is None:
+                if not self._selects:
                     live_list = gen.live_block_list(
                         self._tables, self._nalloc, self._active,
-                        self.block_tokens)
+                        self.block_tokens,
+                        own_tiles=self.cfg.latent is not None)
                     self._kv = {
                         "kv_blocks": int(
                             self._nalloc[self._active].sum()),
@@ -1525,7 +1532,7 @@ class PagedGeneratorActor(GeneratorActor):
         n_live = int(self._active.sum())
         self._max_live = max(self._max_live, n_live)
         kv = self._kv
-        if self.cfg.latent is None:
+        if not self._selects:
             full_list = (d["live_list"] if self._wpool is None
                          else d["live_list"]["full"])
             self.ledger.kv_list(

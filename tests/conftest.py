@@ -27,11 +27,13 @@ _BENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "benchmark")
 if _BENCH_TESTS not in sys.path:
     sys.path.insert(0, _BENCH_TESTS)
+import axk1_tiny  # noqa: E402
 import exaone_tiny  # noqa: E402
 import glm_tiny  # noqa: E402
 
 glm_tiny.install()
 exaone_tiny.install()
+axk1_tiny.install()
 
 #: Modules auto-marked ``slow`` (excluded from `make test`, run by
 #: `make test-all`). Per-module, not per-test: the cost in these files

@@ -150,7 +150,8 @@ def test_decode_attends_over_the_live_rows_blocks(monkeypatch,
     build, seen, wrong = gen.live_block_list, [], []
     holder = {}
 
-    def checked(tables, nalloc, active, bt, tile=None):
+    def checked(tables, nalloc, active, bt, tile=None, own_tiles=False):
+        assert not own_tiles  # a GQA engine's rows lie end to end
         lst, n_tiles = build(tables, nalloc, active, bt, tile)
         eng = holder["actor"]
         want = [(bid, slot, j * bt)
