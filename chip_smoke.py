@@ -560,10 +560,63 @@ def _flash_case(name, B, S, H, K, Dh, **blocks) -> dict:
     return {k_: round(e, 5) for k_, e in errs.items()}
 
 
+def _latent_block_case(H, D, Dv, bt, lanes, row_blocks, tile) -> float:
+    """``ops.latent_block_attention`` over rows of unequal length (one
+    lane idle, one row's last tile mostly padding) against a float32
+    softmax over each row's own positions; on the chip, compiled and
+    not interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ptype_tpu.models import generate as gen
+    from ptype_tpu.ops.latent_block_attention import (
+        KERNEL_NAME, latent_block_attention)
+
+    dt = jnp.bfloat16
+    n_blocks = lanes * row_blocks + 1
+    bank = jax.random.normal(jax.random.PRNGKey(8), (n_blocks, bt, D), dt)
+    q = jax.random.normal(jax.random.PRNGKey(9), (lanes, H, D), dt)
+    rng = np.random.default_rng(2)
+    ctx = rng.integers(bt, row_blocks * bt, lanes)
+    ctx[0], ctx[-1] = 0, min(tile * bt + 1, row_blocks * bt)
+    tables = (rng.permutation(n_blocks - 1)[:lanes * row_blocks]
+              .reshape(lanes, row_blocks) + 1).astype(np.int32)
+    lst, n = gen.live_block_list(tables, -(-ctx // bt), ctx > 0, bt,
+                                 tile=tile, own_tiles=True)
+    kernel = jax.jit(lambda q, bank, lst, n, limits: latent_block_attention(
+        q, bank, 0, (lst, n), limits, scale=D ** -0.5, v_dim=Dv))
+    args = (q, bank, jnp.asarray(lst), jnp.asarray(n),
+            jnp.asarray(np.maximum(ctx, 1), jnp.int32))
+    if jax.default_backend() == "tpu":
+        seen = lowered_kernels(kernel.lower(*args).as_text())
+        check([k for k, _ in seen] == [KERNEL_NAME],
+              f"kernel {KERNEL_NAME} is not in the lowered module "
+              f"(interpreted or substituted); have {seen}")
+    got = np.asarray(kernel(*args), np.float32)
+    rows = np.asarray(bank, np.float32)[tables].reshape(lanes, -1, D)
+    s = np.einsum("bhd,bsd->bhs", np.asarray(q, np.float32), rows) \
+        * D ** -0.5
+    keys = np.arange(rows.shape[1])[None, None] < ctx[:, None, None]
+    s = np.where(keys, s, -1e30)
+    p = np.where(keys, np.exp(s - s.max(-1, keepdims=True)), 0.0)
+    want = np.einsum("bhs,bsd->bhd",
+                     p / np.maximum(p.sum(-1, keepdims=True), 1e-30),
+                     rows[..., :Dv])
+    check(bool((got[0] == 0).all()), "latent block kernel: an idle lane "
+                                     "must read zeros")
+    return _close(got, want, "latent block kernel output")
+
+
+#: ``ops.latent_block_attention`` at A.X-K1's widths (H, D, Dv, block
+#: tokens, lanes, blocks a row, list tile).
+LATENT_SHAPE = (64, 640, 512, 16, 8, 300, 256)
+
+
 def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
                   flash_blocks: dict | None = None,
-                  width_preset: str = PRESET, prefill_len: int = 200
-                  ) -> dict:
+                  width_preset: str = PRESET, prefill_len: int = 200,
+                  latent_shape=LATENT_SHAPE) -> dict:
     """Every Pallas kernel in ``ptype_tpu/ops`` against its float32
     reference, and the lowerings no TPU compiler had seen: the flash
     prefill (an unaligned prompt, so the pad path runs), the engine's
@@ -583,6 +636,8 @@ def phase_kernels(cluster, *, flash_shapes=FLASH_SHAPES,
     for name, B, S, H, K, Dh in flash_shapes:
         out["flash"][name] = _flash_case(name, B, S, H, K, Dh,
                                          **(flash_blocks or {}))
+
+    out["latent_block_err"] = round(_latent_block_case(*latent_shape), 5)
 
     # The model's full width at cut depth: prefill, kernel path against
     # the dense path, and the paged decode step, block list against the

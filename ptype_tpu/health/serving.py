@@ -379,6 +379,14 @@ class ServingLedger:
         #: and the number of expert layers a step.
         self._moe_tiles = [0, 0, 0]
         self._moe_layers = 1
+        #: Which decode attention the engine's step compiled, as the
+        #: engine states it: ``"list"`` (GQA: the live rows' block
+        #: list, ``generate._live_block_attention``), ``"lanes"``
+        #: (latent behind an indexer: the live lanes' list,
+        #: ``sparse_mla.attend_paged``), ``"latent_kernel"`` (latent
+        #: with no indexer: ``ops.latent_block_attention`` over the
+        #: block list). In ``summary()`` once set.
+        self.decode_attn: str | None = None
         #: The decode step's live-block list (kv_list): running totals
         #: of steps, listed blocks, tiles run, tokens attended and
         #: tokens the tiles covered; absent from the summary until a
@@ -688,6 +696,8 @@ class ServingLedger:
                 self._lane_list
             c_steps, c_full, c_window, c_uniform, c_freed = self._cache
         out = {}
+        if self.decode_attn:
+            out["decode_attn"] = self.decode_attn
         if c_steps:
             out["full_blocks"] = round(c_full / c_steps, 2)
             out["window_blocks"] = round(c_window / c_steps, 2)

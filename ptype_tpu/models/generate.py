@@ -295,7 +295,8 @@ def _paged_attention_gather(q, kc, vc, tables, pos_limit, cfg):
 
 #: Blocks in one tile of the live-block list (4,096 tokens at 16 a
 #: block: 8 MB of K, 8 MB of V and 17 MB of float32 scores at mistral's
-#: widths and 32 lanes). Chosen on the chip (PERF.md §6, PR 29).
+#: widths and 32 lanes). Chosen on the chip (PERF.md §6, PR 29); a
+#: latent list's kernel reads a tile in sub-tiles of its own choosing.
 LIVE_TILE_BLOCKS = 256
 #: Lanes in one tile of the live-lane list: one float32 sublane tile,
 #: so a tile's ``(8, reach)`` indexer scores sort in whole tiles.
@@ -328,10 +329,13 @@ def live_block_list(tables, nalloc, active, block_tokens: int,
     not every lane's reach.
 
     ``own_tiles`` (a list from block 0, without ``first``): each row's
-    run starts on a tile (its last tile padded), so that a tile's blocks belong to ONE lane and the step
-    scores that lane's queries alone against it: the list of a cache
-    whose one K,V head many query heads share (a latent cache), where
-    a lane's queries are a matmul's rows by themselves."""
+    run starts on a tile (its last tile padded), so that a tile's
+    blocks belong to ONE lane, in position order, and the step scores
+    that lane's queries alone against it: the list of a cache whose
+    one K,V head many query heads share (a latent cache), where a
+    lane's queries are a matmul's rows by themselves
+    (``ops.latent_block_attention`` reads it: a tile's owner and first
+    position from its first entry, its ids a tile at a time)."""
     ns, nb = tables.shape
     row = min(nb, int(row_blocks or nb))
     tile = min(int(tile or LIVE_TILE_BLOCKS), ns * row)
@@ -380,30 +384,18 @@ def live_lane_list(active, tile: int | None = None):
 
 
 def _live_block_attention(q, kf, vf, base, blocks, limits,
-                          window: int = 0, scope: str = "attn",
-                          scale: float | None = None,
-                          v_dim: int | None = None):
-    """Decode attention over the blocks live rows hold: work follows
-    Σ live context, not lanes x reach. q: (B, 1, H, Dh); ``kf``/``vf``:
-    the flat banks ``(L * n_blocks, block_tokens, Kh, Dh)``, ``base``
-    the layer's first row in them; ``blocks`` as
+                          window: int = 0, scope: str = "attn"):
+    """GQA decode attention over the blocks live rows hold: work
+    follows Σ live context, not lanes x reach. q: (B, 1, H, Dh);
+    ``kf``/``vf``: the flat banks ``(L * n_blocks, block_tokens, Kh,
+    Dh)``, ``base`` the layer's first row in them; ``blocks`` as
     :func:`live_block_list` gives it; ``limits`` (B,): lane ``b``
     attends positions ``< limits[b]`` of its own blocks and, in a
     window layer, ``>= limits[b] - window`` (the list then holds the
     window pool's blocks). ``scope`` names the loop in a device trace
     (``attn_window`` / ``attn_full`` in a stack with attention kinds).
-    ``scale`` multiplies the scores (None: ``Dh ** -0.5``).
-
-    What a block holds: K and V per head (``kf``, ``vf``), or, with
-    ``vf`` None, one row a token that is key and value at once — a
-    latent cache's ``(rows, block_tokens, 1, cache_dim)`` view, the
-    queries in the absorbed form (``sparse_mla.absorb_query``): the
-    values are the row's leading ``v_dim`` lanes, one gather serves
-    both, and the result is ``(B, 1, H, v_dim)``. Such a list has
-    every tile owned by one lane (``live_block_list(own_tiles=True)``):
-    the tile is scored against its owner's queries alone (``G`` rows
-    a KV head, all heads of one lane) and folds into that lane's
-    running sums.
+    (A latent cache with no indexer reads its list, each tile one
+    lane's, through a kernel: ``ops.latent_block_attention``.)
 
     One loop over the list's tiles in use (a ``while`` whose trip count
     is data, so ONE compiled program whatever the load): a tile's
@@ -421,7 +413,6 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
     bt, Kh = kf.shape[1], kf.shape[2]
     G = H // Kh
     S = lst.shape[2] * bt
-    Dv = Dh if vf is not None else v_dim
     f32 = jnp.float32
     with jax.named_scope(scope):
         qg = q.reshape(B, Kh, G, Dh)
@@ -433,33 +424,16 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
             ids, owner, first = lst[0, t], lst[1, t], lst[2, t]
             with jax.named_scope("kv_gather"):
                 ks = kf[base + ids].reshape(S, Kh, Dh)
-                vs = (ks[..., :Dv] if vf is None
-                      else vf[base + ids].reshape(S, Kh, Dh))
-            if vf is None:
-                # The tile's one lane: its row of the queries, the
-                # limits and the running sums.
-                mine = jnp.minimum(owner[0], B - 1)
-
-                def row(a):
-                    return lax.dynamic_index_in_dim(a, mine, 0)
-
-                def put(a, new):
-                    return lax.dynamic_update_index_in_dim(a, new, mine, 0)
-            else:
-                def row(a):
-                    return a
-
-                def put(a, new):
-                    return new
-            m, l, acc = (row(a) for a in carry)
-            s = jnp.einsum("bkgd,skd->bkgs", row(qg), ks).astype(f32)
-            s = s / jnp.sqrt(f32(Dh)) if scale is None else s * f32(scale)
+                vs = vf[base + ids].reshape(S, Kh, Dh)
+            m, l, acc = carry
+            s = jnp.einsum("bkgd,skd->bkgs", qg, ks).astype(f32)
+            s = s / jnp.sqrt(f32(Dh))
             owner = jnp.repeat(owner, bt)
             at = (first[:, None] + offs[None, :]).reshape(S)
-            mask = ((owner[None, :] == row(lanes)[:, None])
-                    & (at[None, :] < row(limits)[:, None]))
+            mask = ((owner[None, :] == lanes[:, None])
+                    & (at[None, :] < limits[:, None]))
             if window:
-                mask &= at[None, :] >= row(limits)[:, None] - window
+                mask &= at[None, :] >= limits[:, None] - window
             mask = mask[:, None, None, :]
             s = jnp.where(mask, s, f32(-1e30))
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -470,16 +444,15 @@ def _live_block_attention(q, kf, vf, base, blocks, limits,
             l = l * alpha + jnp.sum(p, axis=-1)
             pv = jnp.einsum("bkgs,skd->bkgd", p.astype(q.dtype), vs,
                             preferred_element_type=f32)
-            return tuple(put(a, new) for a, new in zip(
-                carry, (m_new, l, acc * alpha[..., None] + pv)))
+            return m_new, l, acc * alpha[..., None] + pv
 
         m, l, acc = lax.fori_loop(
             0, n_tiles, fold,
             (jnp.full((B, Kh, G), -1e30, f32),
              jnp.zeros((B, Kh, G), f32),
-             jnp.zeros((B, Kh, G, Dv), f32)))
+             jnp.zeros((B, Kh, G, Dh), f32)))
         o = acc / jnp.where(l > 0, l, f32(1))[..., None]
-        return o.astype(q.dtype).reshape(B, 1, H, Dv)
+        return o.astype(q.dtype).reshape(B, 1, H, Dh)
 
 
 #: Blocks in one tile of a full-attention layer's table walk
@@ -609,8 +582,10 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     behind an indexer: the lane list (:func:`live_lane_list`); index,
     selection, gather and attention run over its lanes alone. Latent
     with no indexer: the block list again, each lane's run on tiles of
-    its own (``own_tiles``), read in the absorbed form; its prefill
-    chunk walks the table (:func:`_table_attention`). Returns ``(x (B,
+    its own (``own_tiles``), read in the absorbed form by one kernel a
+    layer (``ops.latent_block_attention``: a row's blocks copied into
+    VMEM once, out of the bank as it lies); its prefill chunk walks
+    the table (:func:`_table_attention`). Returns ``(x (B,
     Q, D) before the final norm, banks, load)``; ``load`` is a
     dropless router's counts
     summed over its layers (``tfm._moe_dropless``; of the tokens
@@ -672,6 +647,8 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             return o, {**bf, kind: own}
     else:
         from ptype_tpu.models import sparse_mla
+        from ptype_tpu.ops.latent_block_attention import (
+            latent_block_attention)
 
         la = cfg.latent
 
@@ -692,14 +669,18 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
             # bank is one K,V head whose row is key and value at once.
             with jax.named_scope("attn"):
                 qa = sparse_mla.absorb_query(q_nope, q_rope, layer, cfg)
-            rows = own["ckv"][:, :, None]
             how = dict(scale=sparse_mla.score_scale(cfg), v_dim=la.kv_rank)
             if live_list is not None:
-                ol = _live_block_attention(qa, rows, None, base, live_list,
-                                           limits, **how)
+                # A decode step: one kernel reads each live row's
+                # blocks once, out of the bank where it lies.
+                with jax.named_scope("attn"):
+                    ol = latent_block_attention(
+                        qa[:, 0], own["ckv"], base, live_list, limits,
+                        **how)[:, None]
             else:
-                ol = _table_attention(qa, rows, None, base + tables,
-                                      limits, 0, "attn", **how)
+                ol = _table_attention(qa, own["ckv"][:, :, None], None,
+                                      base + tables, limits, 0, "attn",
+                                      **how)
             with jax.named_scope("attn"):
                 return sparse_mla.expand_values(ol, layer, cfg), own
 
@@ -771,9 +752,10 @@ def decode_step_banks(params: dict, token: jax.Array, pos: jax.Array,
     reads. GQA (:func:`live_block_list`'s pair): their K,V blocks; the
     step reads those and no others, tile by tile
     (:func:`_live_block_attention`), so its cost follows the tokens in
-    flight; a latent cache with no indexer is read the same way, its
-    one row a token key and value at once and each row's run of the
-    list on tiles of its own. Latent behind an indexer
+    flight; a latent cache with no indexer is read from the same
+    list, each row's run of it on tiles of its own, by one kernel a
+    layer (``ops.latent_block_attention``), its one row a token key
+    and value at once. Latent behind an indexer
     (:func:`live_lane_list`'s pair): their lanes; the
     indexer, the selection, the latent gather and the attention run
     over those, a tile of lanes at a time

@@ -16,13 +16,16 @@ counts instead of ``n_slots × reach``:
   request skips prefill for every already-resident full block), and
   per-slot RNG sampling on the continuous path.
 
-The decode attention path is XLA over what the live rows hold
+The decode attention reads what the live rows hold
 (``models/generate.decode_step_banks`` over the engine's live list: a
-GQA step reads ``generate.live_block_list``'s blocks, a latent step
-indexes, selects, gathers and attends over
-``generate.live_lane_list``'s lanes, a tile of them a trip; the
-prefill chunk and the speculative programs gather through the block
-table).
+GQA step reads ``generate.live_block_list``'s blocks in an XLA loop, a
+latent step behind an indexer indexes, selects, gathers and attends
+over ``generate.live_lane_list``'s lanes, a tile of them a trip, and a
+latent step with no indexer reads its block list through one Pallas
+kernel a layer, ``ops.latent_block_attention``; the prefill chunk and
+the speculative programs gather through the block table).
+``Info()``'s ``decode_attn`` says which: ``list``, ``lanes``,
+``latent_kernel``.
 """
 
 from ptype_tpu.serve_engine.blocks import (BlockPool, block_hashes,

@@ -14,9 +14,10 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   list's tiles in use, so a step costs the weights plus Σ live
   context, whatever ``n_slots`` and the reach are — one compiled
   program for every trip count. A latent cache with no indexer is
-  read the same way (each row's run of the list on tiles of its own);
-  a latent step whose indexer selects each row's keys is handed the
-  list of live LANES instead
+  read from the same list (each row's run of it on tiles of its own)
+  by one kernel a layer that copies a row's blocks into VMEM once
+  (``ops.latent_block_attention``); a latent step whose indexer
+  selects each row's keys is handed the list of live LANES instead
   (``generate.live_lane_list``, rebuilt at the same moments): index,
   selection, gather and attention run over those lanes, a tile of
   them a trip, and cost what is live whatever ``n_slots`` is. Greedy
@@ -436,10 +437,14 @@ class PagedGeneratorActor(GeneratorActor):
         #: An indexer selects each query's keys (latent attention
         #: behind one): the step then reads by lane, not by block.
         self._selects = cfg.latent is not None and cfg.latent.indexer
+        self.ledger.decode_attn = (
+            "lanes" if self._selects
+            else "list" if cfg.latent is None else "latent_kernel")
         #: The engine hands the step the list of what its live rows
         #: hold, rebuilt with ``_dev``: the blocks
         #: (gen.live_block_list; a latent cache's with each row's run
-        #: on tiles of its own), or where an indexer selects the lanes
+        #: on tiles of its own, read by ops.latent_block_attention),
+        #: or where an indexer selects the lanes
         #: (gen.live_lane_list). The step's attention then costs what
         #: is in flight, not n_slots x reach. ``_kv``: the list's
         #: counts, as the dispatch span carries them (``kv_blocks``,

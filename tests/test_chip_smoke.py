@@ -53,8 +53,10 @@ def test_kernels_phase(cluster):
         cluster, flash_shapes=(("mha", 2, 64, 2, 2, 16),
                                ("gqa", 1, 64, 4, 2, 16)),
         flash_blocks={"block_q": 32, "block_k": 32},
-        width_preset="tiny", prefill_len=40)
+        width_preset="tiny", prefill_len=40,
+        latent_shape=(4, 128, 64, 4, 4, 10, 4))
     assert set(out["flash"]) == {"mha", "gqa"}
+    assert out["latent_block_err"] < 2e-2
     assert out["paged_decode_blocks_err"] < 2e-2
 
 
