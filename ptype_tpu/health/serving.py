@@ -16,13 +16,17 @@ number at the gateway. This module is the serving analogue — a
   ``serve.tpot_ms``, ``serve.e2e_ms`` — the health
   :class:`~ptype_tpu.health.series.Sampler` stamps their ``.p99`` /
   ``.count`` series, which the ``ttft-p99`` alert rule reads).
-- **Engine-iteration composition**: one record per engine iteration —
-  active slots, decode-vs-prefill token split, per-iteration wall and
-  the co-batched stall — published as ``serve.step_ms`` /
+- **Engine-iteration composition**: one record per pass of the engine
+  loop that had work (:class:`_IterMeter`) — active slots,
+  decode-vs-prefill token split, the step's wall and the co-batched
+  stall, whose prefill chunks the pass carried and the inter-token gap
+  its rows saw — published as ``serve.step_ms`` /
   ``serve.active_slots`` / ``serve.stall_ms`` gauges and
   ``serve.steps`` / ``serve.decode_tokens`` / ``serve.prefill_tokens``
   counters (the ``serve-stall`` rule watches ``serve.steps`` progress
-  against ``serve.queue_depth``).
+  against ``serve.queue_depth``), kept in a ring that ``summary()``
+  reduces for ``Info()``, and sent through the one seam as a
+  ``serve.iteration`` record when something listens.
 - **KV-pool pressure**: :meth:`ServingLedger.kv_sample` turns
   :meth:`~ptype_tpu.serve_engine.blocks.BlockPool.stats` into the
   ``kv.free_blocks`` / ``kv.cached_blocks`` / ``kv.total_blocks`` /
@@ -46,10 +50,10 @@ number at the gateway. This module is the serving analogue — a
 
 Timer discipline: lint rule PT010 bars raw ``time.perf_counter()`` /
 ``time.time()`` calls inside ``serve_engine/`` — every stamp the
-engine needs comes from a seam on this ledger (``enqueued`` /
-``head_refused`` / ``admitted`` / ``chunk`` / ``first_token`` /
-``tokens_emitted`` / ``iteration`` / ``retired``), so latency math has
-exactly one home and a probe can cost it
+engine needs comes from a seam on this ledger (``ingress`` /
+``enqueued`` / ``head_refused`` / ``admitted`` / ``chunk`` /
+``first_token`` / ``tokens_emitted`` / ``iteration`` / ``retired``), so
+latency math has exactly one home and a probe can cost it
 (:func:`measure_seam_cost_us`, against the <1%-per-engine-iteration
 bar).
 """
@@ -87,13 +91,14 @@ class RequestRecord:
     """
 
     __slots__ = ("rid", "tp", "prompt_tokens", "max_new", "reused_blocks",
-                 "t_enqueue", "w_enqueue", "t_head", "t_admit",
+                 "t_call", "t_enqueue", "w_enqueue", "t_head", "t_admit",
                  "chunks", "t_first", "w_first", "tok_t",
                  "t_done", "reason", "closed", "t_mig0", "w_mig0",
                  "t_mig1", "migrate_blocks", "migrate_bytes")
 
     def __init__(self, prompt_tokens: int, max_new: int,
-                 tp: str | None, rid: int = 0):
+                 tp: str | None, rid: int = 0,
+                 t_call: float | None = None):
         #: The request's id on this ledger: in every region and span
         #: the request leaves, with tracing on or off.
         self.rid = rid
@@ -103,6 +108,11 @@ class RequestRecord:
         self.reused_blocks = 0
         self.t_enqueue = time.perf_counter()
         self.w_enqueue = time.time()
+        #: When the caller's handler entered the engine
+        #: (``ServingLedger.ingress``), before the prompt crossed to
+        #: the device and back; the enqueue stamp where no entry was
+        #: stamped.
+        self.t_call = self.t_enqueue if t_call is None else t_call
         self.t_head: float | None = None
         self.t_admit: float | None = None
         #: [(wall_start, dur_s, tokens), ...] — one per prefill chunk.
@@ -148,22 +158,21 @@ class RequestRecord:
     def first_token_split(self) -> dict:
         """The ``serve.first_token`` record: where this request's
         first-token time went, in ms. ``queue_ms + reserve_ms +
-        admitted_ms`` is its ``ttft_s``. ``prefill_host_ms`` (the sum
-        of its chunk meters) is NOT its prefill time: under async
-        dispatch a non-final chunk's meter closes at dispatch and the
-        final chunk's host sync pays for all of them."""
+        admitted_ms`` is its ``ttft_s``; with ``ingress_ms`` (the
+        caller's entry -> the enqueue stamp) before them, its
+        first-token time as its caller's handler saw it."""
         queue_s, reserve_s = self.queue_wait_s(), self.reserve_wait_s()
         return {
             "rid": self.rid,
             "prompt_tokens": self.prompt_tokens,
             "reused_blocks": self.reused_blocks,
             "chunks": len(self.chunks),
+            "ingress_ms": round(
+                (self.t_enqueue - self.t_call) * 1e3, 3),
             "queue_ms": round(queue_s * 1e3, 3),
             "reserve_ms": round(reserve_s * 1e3, 3),
             "admitted_ms": round(
                 (self.ttft_s() - queue_s - reserve_s) * 1e3, 3),
-            "prefill_host_ms": round(
-                sum(c[1] for c in self.chunks) * 1e3, 3),
         }
 
     def tpot_s(self) -> float | None:
@@ -234,15 +243,19 @@ def _region(rec: RequestRecord, name: str, **attrs):
 
 class _ChunkMeter:
     """Times one prefill chunk into its record + the ledger's
-    per-iteration prefill accumulator."""
+    per-iteration prefill accumulators."""
 
-    __slots__ = ("_led", "_rec", "tokens", "dur_s", "_t0", "_w0", "_sp")
+    __slots__ = ("_led", "_rec", "tokens", "ctx", "dur_s", "_t0", "_w0",
+                 "_sp")
 
     def __init__(self, led: "ServingLedger", rec: RequestRecord,
                  tokens: int):
         self._led = led
         self._rec = rec
         self.tokens = int(tokens)
+        #: The context, in tokens, the chunk ends at (what its cost
+        #: follows); the engine sets it before the scope opens.
+        self.ctx = 0
         self.dur_s = 0.0
 
     def __enter__(self) -> "_ChunkMeter":
@@ -260,16 +273,68 @@ class _ChunkMeter:
         with led._lock:
             led._iter_prefill_s += self.dur_s
             led._iter_prefill_tokens += self.tokens
+            led._iter_chunks.append((self._rec.rid, int(self.ctx)))
         self._sp.__exit__(*exc)
         return False
 
 
-class _IterMeter:
-    """Times one engine iteration (the batched decode step) and folds
-    the iteration record: active slots, decode/prefill token split,
-    the co-batched stall the engine charged to this step."""
+class _Ingress:
+    """A request's way in on its caller's thread, from the handler's
+    entry to its last row's enqueue stamp: the entry stamp its records
+    keep (``RequestRecord.t_call``) and, where something listens, a
+    ``serve.ingress`` span. ``close`` is the scope's end, and may come
+    before the ``with`` block's (the handler goes on to wait for its
+    rows inside it)."""
 
-    __slots__ = ("_led", "active", "stall_ms", "decode_tokens", "_t0")
+    __slots__ = ("t_call", "_sp")
+
+    def __init__(self, rows: int, prompt_tokens: int):
+        self._sp = trace.span("serve.ingress", rows=rows,
+                              prompt_tokens=prompt_tokens)
+
+    def __enter__(self) -> "_Ingress":
+        self._sp.__enter__()
+        self.t_call = time.perf_counter()
+        return self
+
+    def close(self, *exc) -> None:
+        sp, self._sp = self._sp, None
+        if sp is not None:
+            sp.__exit__(*(exc or (None, None, None)))
+
+    def __exit__(self, *exc) -> bool:
+        self.close(*exc)
+        return False
+
+
+class _IterMeter:
+    """One pass of the engine loop that had work: the cancel sweep, the
+    admission round with the prefill chunks it ran and, where rows are
+    live, the batched decode step (``step`` marks its start). Folds
+    the iteration record:
+
+    - ``seq``: the ledger's count of records, tracing on or off;
+    - ``step_ms`` (the step's wall; of a pass that ran none, the
+      pass's), ``active``, ``decode_tokens``, ``prefill_tokens``,
+      ``prefill_ms``, ``stall_ms`` (the co-batched stall the engine
+      charged to this step) and ``iter_ms`` (the whole pass);
+    - ``chunks``, ``chunk_rids``, ``chunk_ctx``: whose prefill the pass
+      carried, and the largest context a chunk of it ended at;
+    - ``gap_ms``, ``gap_rows``: the time since the previous
+      ``tokens_emitted`` stamp and the rows whose previous token bore
+      it, which is what each of them saw as its inter-token gap;
+      ``new_gaps_ms``: the gaps of the rows whose previous token was
+      their first (``first_token``'s stamp). A speculative window's
+      further tokens share the commit stamp: ``decode_tokens -
+      gap_rows - len(new_gaps_ms)`` gaps of zero (:func:`record_gaps`).
+
+    Kept in the ledger's ring, and one zero-length ``serve.iteration``
+    record through the seam where a capture or the recorder listens. A
+    pass that ran neither a chunk nor a step ticks the counters and
+    leaves no record."""
+
+    __slots__ = ("_led", "active", "stall_ms", "decode_tokens", "_t0",
+                 "_t_step")
 
     def __init__(self, led: "ServingLedger", active: int,
                  stall_ms: float):
@@ -282,35 +347,128 @@ class _IterMeter:
         #: scope closes, so ``serve.decode_tokens`` stays the real
         #: throughput counter either way.
         self.decode_tokens: int | None = None
+        self._t_step: float | None = None
 
     def __enter__(self) -> "_IterMeter":
         self._t0 = time.perf_counter()
         return self
 
+    def step(self, active: int, stall_ms: float) -> None:
+        """The pass reaches its decode step over ``active`` rows."""
+        self.active = int(active)
+        self.stall_ms = float(stall_ms)
+        self._t_step = time.perf_counter()
+
     def __exit__(self, *exc) -> bool:
         led = self._led
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
+        now = time.perf_counter()
+        iter_ms = round((now - self._t0) * 1e3, 3)
+        step_ms = (iter_ms if self._t_step is None
+                   else round((now - self._t_step) * 1e3, 3))
+        stall_ms = round(self.stall_ms, 3)
         dtoks = (self.active if self.decode_tokens is None
                  else int(self.decode_tokens))
+        rec = None
         with led._lock:
             prefill_s, led._iter_prefill_s = led._iter_prefill_s, 0.0
             ptoks, led._iter_prefill_tokens = \
                 led._iter_prefill_tokens, 0
-            rec = {"step_ms": round(dur_ms, 3),
-                   "active": self.active,
-                   "decode_tokens": dtoks,
-                   "prefill_tokens": ptoks,
-                   "prefill_ms": round(prefill_s * 1e3, 3),
-                   "stall_ms": round(self.stall_ms, 3)}
-            led._iters.append(rec)
+            chunks, led._iter_chunks = led._iter_chunks, []
+            (gap_s, gap_rows), led._iter_gap = led._iter_gap, (0.0, 0)
+            new_gaps, led._iter_new_gaps = led._iter_new_gaps, []
+            if chunks or self.active:
+                led._seq += 1
+                rec = {"seq": led._seq,
+                       "step_ms": step_ms,
+                       "active": self.active,
+                       "decode_tokens": dtoks,
+                       "prefill_tokens": ptoks,
+                       "prefill_ms": round(prefill_s * 1e3, 3),
+                       "stall_ms": stall_ms,
+                       "iter_ms": iter_ms,
+                       "chunks": len(chunks),
+                       "chunk_rids": tuple([r for r, _ in chunks]),
+                       "chunk_ctx": max([c for _, c in chunks],
+                                        default=0),
+                       "gap_ms": round(gap_s * 1e3, 3),
+                       "gap_rows": gap_rows,
+                       "new_gaps_ms": tuple([round(g * 1e3, 3)
+                                             for g in new_gaps])}
+                led._iters.append(rec)
         led.c_steps.add(1)
         led.c_decode_tokens.add(dtoks)
         if ptoks:
             led.c_prefill_tokens.add(ptoks)
-        led.g_step_ms.set(rec["step_ms"])
+        led.g_step_ms.set(step_ms)
         led.g_active.set(self.active)
-        led.g_stall.set(rec["stall_ms"])
+        led.g_stall.set(stall_ms)
+        if rec is not None and (trace.capturing() or trace.enabled()):
+            # ":" inside a list: a profiler annotation's metadata is
+            # itself a comma-separated list.
+            with trace.span("serve.iteration", **{
+                    **rec,
+                    "chunk_rids": ":".join(map(str, rec["chunk_rids"])),
+                    "new_gaps_ms": ":".join(
+                        map(str, rec["new_gaps_ms"]))}):
+                pass
         return False
+
+
+def record_gaps(rec: dict) -> list[tuple[float, int]]:
+    """The inter-token gaps one iteration record stands for, as
+    ``(gap_ms, rows that saw it)``."""
+    new = rec["new_gaps_ms"]
+    out = [(g, 1) for g in new]
+    if rec["gap_rows"]:
+        out.append((rec["gap_ms"], rec["gap_rows"]))
+    burst = rec["decode_tokens"] - rec["gap_rows"] - len(new)
+    if burst > 0:
+        out.append((0.0, burst))
+    return out
+
+
+def _ring_summary(iters: list[dict]) -> dict:
+    """What the ring of iteration records says, for ``summary()``:
+    whether the tail of the inter-token gaps is a prefill's or a
+    step's (``chunk_gap_share`` of the gaps came from a pass that
+    carried a chunk, median ``gap_p50_chunk_ms``; the rest reach
+    ``gap_p95_decode_only_ms``), and how many rows a step serves."""
+    n = len(iters)
+    dtoks = sum(r["decode_tokens"] for r in iters)
+    ptoks = sum(r["prefill_tokens"] for r in iters)
+    live = [r["active"] for r in iters if r["active"]]
+    out = {
+        "iterations": n,
+        "step_ms_mean": round(sum(r["step_ms"] for r in iters) / n, 3),
+        "stall_ms_max": round(max(r["stall_ms"] for r in iters), 3),
+        "prefill_token_share": round(
+            ptoks / (ptoks + dtoks), 4) if ptoks + dtoks else 0.0,
+    }
+    if live:
+        out["rows_live_mean"] = round(sum(live) / len(live), 2)
+    chunked = [g for r in iters if r["chunks"] for g in record_gaps(r)]
+    plain = [g for r in iters if not r["chunks"] for g in record_gaps(r)]
+    if chunked or plain:
+        out["chunk_gap_share"] = round(
+            sum(w for _, w in chunked)
+            / sum(w for _, w in chunked + plain), 4)
+    if plain:
+        out["gap_p95_decode_only_ms"] = _wquantile(plain, 0.95)
+    if chunked:
+        out["gap_p50_chunk_ms"] = _wquantile(chunked, 0.5)
+    return out
+
+
+def _wquantile(pairs: list[tuple[float, int]], q: float) -> float:
+    """The value under which a share ``q`` of the weight lies."""
+    pairs = sorted(pairs)
+    need = q * sum(w for _, w in pairs)
+    acc = 0
+    for v, w in pairs:
+        acc += w
+        if acc >= need:
+            break
+    return v
 
 
 class ServingLedger:
@@ -355,8 +513,19 @@ class ServingLedger:
         self._ttft_seq = 0
         self._ttft_recent: collections.deque = collections.deque(
             maxlen=TTFT_RECENT)
+        #: What the open pass has gathered, folded and cleared when its
+        #: iteration scope closes (engine-thread owned): its chunks'
+        #: seconds, tokens and ``(rid, ctx)``, the gap its rows saw
+        #: ``(seconds, rows)`` and the new rows' own gaps.
         self._iter_prefill_s = 0.0
         self._iter_prefill_tokens = 0
+        self._iter_chunks: list[tuple[int, int]] = []
+        self._iter_gap = (0.0, 0)
+        self._iter_new_gaps: list[float] = []
+        #: Iteration records so far, and the last ``tokens_emitted``
+        #: stamp.
+        self._seq = 0
+        self._emit_t: float | None = None
         self._evictions_last = 0.0
         # Speculative decoding (ISSUE 12): cumulative window totals
         # behind the summary's spec_accept_rate / spec_tokens; the
@@ -403,14 +572,23 @@ class ServingLedger:
 
     # --------------------------------------------------- request seams
 
+    def ingress(self, shape) -> _Ingress:
+        """Open a request's way in (``with``; ``close`` at its last
+        row's ``enqueued``); ``shape`` is the prompt's as it arrived,
+        ``(tokens,)`` or ``(rows, tokens)``."""
+        rows = int(shape[0]) if len(shape) > 1 else 1
+        return _Ingress(rows, rows * int(shape[-1]))
+
     def enqueued(self, prompt_tokens: int, max_new: int,
-                 tp: str | None = None) -> RequestRecord:
+                 tp: str | None = None,
+                 t_call: float | None = None) -> RequestRecord:
         """A row entered the waiting room; ``tp`` is the caller's
         traceparent (captured inside the actor handler span) the
-        synthesized span tree will parent under."""
+        synthesized span tree will parent under, ``t_call`` its
+        handler's entry stamp (``ingress``)."""
         self.registry.counter("serve.requests").add(1)
         return RequestRecord(prompt_tokens, max_new, tp,
-                             rid=next(self._rids))
+                             rid=next(self._rids), t_call=t_call)
 
     def head_refused(self, rec: RequestRecord) -> float:
         """The head-of-line reservation was refused; returns seconds
@@ -465,14 +643,25 @@ class ServingLedger:
         shared stamp (the step boundary), appended per row.
         ``counts`` (speculative windows): per-rec emitted-token counts
         — the window's tokens share the commit stamp, so TPOT stays
-        the mean inter-token time of what the caller actually saw."""
+        the mean inter-token time of what the caller actually saw.
+        Once a pass: what the rows saw as their gap goes to the open
+        iteration's record (the rows whose previous token bore the
+        previous stamp share one gap; a row fresh from ``first_token``
+        has its own)."""
         now = time.perf_counter()
-        if counts is None:
-            for rec in recs:
-                rec.tok_t.append(now)
-            return
-        for rec, n in zip(recs, counts):
-            rec.tok_t.extend([now] * int(n))
+        last, self._emit_t = self._emit_t, now
+        rows, new = 0, self._iter_new_gaps
+        for i, rec in enumerate(recs):
+            tok_t = rec.tok_t
+            if tok_t[-1] == last:
+                rows += 1
+            else:
+                new.append(now - tok_t[-1])
+            if counts is None:
+                tok_t.append(now)
+            else:
+                tok_t.extend([now] * int(counts[i]))
+        self._iter_gap = (now - last if rows else 0.0, rows)
 
     def moe_load(self, counts, tiles: int = 0, hit: int = 0,
                  tile: int = 0, layers: int = 1) -> None:
@@ -598,8 +787,10 @@ class ServingLedger:
 
     # ------------------------------------------------- iteration seams
 
-    def iteration(self, active: int, stall_ms: float = 0.0) -> _IterMeter:
-        """Meter one engine iteration (wrap the batched decode step)."""
+    def iteration(self, active: int = 0,
+                  stall_ms: float = 0.0) -> _IterMeter:
+        """Meter one pass of the engine loop (wrap all of it; the
+        meter's ``step`` marks where its decode step starts)."""
         return _IterMeter(self, active, stall_ms)
 
     def spec_window(self, proposed: int, accepted: int, emitted: int,
@@ -695,7 +886,10 @@ class ServingLedger:
             lane_steps, lane_tiles, lanes_live, lanes_covered = \
                 self._lane_list
             c_steps, c_full, c_window, c_uniform, c_freed = self._cache
+            iters = list(self._iters)
         out = {}
+        if iters:
+            out.update(_ring_summary(iters))
         if self.decode_attn:
             out["decode_attn"] = self.decode_attn
         if c_steps:
@@ -745,27 +939,6 @@ class ServingLedger:
             "e2e_p99_ms": round(self.h_e2e.percentile(99), 3),
             "queue_wait_p99_ms": round(
                 self.h_queue_wait.percentile(99), 3),
-        }
-
-    def iteration_summary(self) -> dict:
-        with self._lock:
-            iters = list(self._iters)
-        if not iters:
-            return {"iterations": 0, "step_ms_mean": 0.0,
-                    "active_mean": 0.0, "prefill_token_share": 0.0}
-        n = len(iters)
-        dtoks = sum(r["decode_tokens"] for r in iters)
-        ptoks = sum(r["prefill_tokens"] for r in iters)
-        return {
-            "iterations": n,
-            "step_ms_mean": round(
-                sum(r["step_ms"] for r in iters) / n, 3),
-            "active_mean": round(
-                sum(r["active"] for r in iters) / n, 2),
-            "stall_ms_max": round(
-                max(r["stall_ms"] for r in iters), 3),
-            "prefill_token_share": round(
-                ptoks / (ptoks + dtoks), 4) if ptoks + dtoks else 0.0,
         }
 
     # ----------------------------------------------------- span trees
@@ -861,8 +1034,6 @@ def measure_seam_cost_us(iters: int = 5000) -> dict:
     t0 = time.perf_counter()
     for _ in range(iters):
         with led.iteration(active=1, stall_ms=0.0):
-            pass
-        led.tokens_emitted((rec,))
-        rec.tok_t.clear()
+            led.tokens_emitted((rec,))
     cost_s = (time.perf_counter() - t0) / iters
     return {"seam_cost_us": round(cost_s * 1e6, 3), "iters": iters}

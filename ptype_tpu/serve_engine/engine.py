@@ -108,6 +108,9 @@ SERVE_CLASSES = ("unified", "prefill", "decode")
 #: Numeric codes for the ``serve.class`` gauge (obs serve renders
 #: the names back; same pattern as ``serve.lifecycle``).
 SERVE_CLASS_CODES = {"unified": 0, "prefill": 1, "decode": 2}
+#: How often an idle engine looks up from its wait for work to see
+#: whether a capture or the recorder has started or stopped listening.
+IDLE_LOOK_S = 0.25
 
 
 @dataclass
@@ -386,7 +389,6 @@ class PagedGeneratorActor(GeneratorActor):
         self._prefill_chunks = 0
         self._prefill_tokens = 0
         self._max_stall_ms = 0.0
-        self._last_stall_ms = 0.0
 
         def engine_step(sampled, params, banks, tok, pos, tables,
                         active, keys, eidx, temps, topk, topp,
@@ -469,6 +471,24 @@ class PagedGeneratorActor(GeneratorActor):
                  top_k: int = 0, top_p: float = 1.0,
                  stop_token: int = -1, pad_token: int = 0,
                  repetition_penalty: float = 1.0):
+        # One traceparent per call: the actor handler span (when the
+        # request arrived over a traced RPC) — the synthesized
+        # admit/prefill/decode span tree parents under it, which is
+        # what stitches gateway.request → ... → serve.decode. Read
+        # before the ingress span becomes the current one.
+        tp = trace.traceparent()
+        # The request's clock starts here, not at the enqueue stamp:
+        # _norm_prompt sends the prompt to the device and the rows'
+        # np.asarray brings it back, behind whatever program the
+        # engine has in flight.
+        with self.ledger.ingress(np.shape(prompt)) as ing:
+            return self._generate(
+                ing, tp, prompt, max_new_tokens, temperature, seed,
+                top_k, top_p, stop_token, pad_token, repetition_penalty)
+
+    def _generate(self, ing, tp, prompt, max_new_tokens, temperature,
+                  seed, top_k, top_p, stop_token, pad_token,
+                  repetition_penalty):
         prompt = _norm_prompt(prompt)
         if (float(repetition_penalty) != 1.0
                 or (float(temperature) != 0.0 and prompt.shape[0] > 1)):
@@ -477,6 +497,7 @@ class PagedGeneratorActor(GeneratorActor):
             # batch-shaped RNG stream — both keep the solo fallback.
             # Single-row sampled requests ride the engine with exact
             # solo RNG parity (sample_token_rows).
+            ing.close()
             return super().Generate(prompt, max_new_tokens, temperature,
                                     seed, top_k, top_p, stop_token,
                                     pad_token, repetition_penalty)
@@ -527,15 +548,10 @@ class PagedGeneratorActor(GeneratorActor):
                               int(stop_token), float(temperature),
                               int(top_k), float(top_p), key)
                     for i in range(prompt.shape[0])]
-            # One traceparent per call: the actor handler span (when
-            # the request arrived over a traced RPC) — the
-            # synthesized admit/prefill/decode span tree parents
-            # under it, which is what stitches gateway.request → ...
-            # → serve.decode.
-            tp = trace.traceparent()
             for r in rows:
                 r.rec = self.ledger.enqueued(len(r.prompt), max_new,
-                                             tp=tp)
+                                             tp=tp, t_call=ing.t_call)
+            ing.close()
             with self._lock:
                 self._calls += 1
             with self._cond:
@@ -999,57 +1015,69 @@ class PagedGeneratorActor(GeneratorActor):
                 self.ledger.retired(r.rec, "error")
                 r.done.set()
 
+    def _no_work_locked(self) -> bool:
+        """(under _cond) No queue, nothing admitting, no live row."""
+        return (not self._queue and self._admitting is None
+                and not self._active.any() and not self._closed)
+
     def _engine_loop(self) -> None:
         pending_stall = 0.0
         while True:
             with self._cond:
-                while (not self._queue and self._admitting is None
-                       and not self._active.any() and not self._closed):
-                    self._cond.wait()
+                while self._no_work_locked():
+                    # Idle for want of load: the device's idle time
+                    # inside this span is the traffic's, outside it the
+                    # host's. The wait looks up each IDLE_LOOK_S and
+                    # opens the span anew when who listens has changed:
+                    # a capture started on an idle replica shows it
+                    # idle, not a gap no span covers.
+                    heard = trace.capturing() or trace.enabled()
+                    with metrics_mod.annotate("serve.idle"):
+                        while (self._no_work_locked() and heard == (
+                                trace.capturing() or trace.enabled())):
+                            self._cond.wait(IDLE_LOOK_S)
                     pending_stall = 0.0  # idle time is not stall
                 if self._closed:
                     return
-            # Cancelled rows (their caller already got a sibling's
-            # error) retire before admission: their blocks are exactly
-            # the headroom the queue head is waiting on.
-            with metrics_mod.annotate("serve.admit"):
-                for slot in list(self._slot_state):
-                    if (self._active[slot]
-                            and self._slot_state[slot].cancelled):
-                        self._retire(slot, "cancelled")
-            # Admission round, bounded by the TOKEN budget: several
-            # short prompts (or one chunk of a long one) may prefill,
-            # but never more than prefill_chunk prompt tokens — that
-            # budget IS the stall bound a co-batched decode step sees.
-            # Charge it as stall only when a decode was LIVE to wait
-            # on it: the chunk that activates the first row of an
-            # idle engine stalls nobody (that row's own first decode
-            # is not a co-batched waiter).
-            if self._active.any():
-                pending_stall += self._admission_round()
-            else:
-                # Prefill-only iteration (no decode co-batched): still
-                # an engine iteration — metered, so `serve.steps`
-                # advances (a burst of max_new=1 requests completing
-                # entirely inside prefill must not read as a stalled
-                # engine with a non-empty queue) and this round's
-                # chunk accounting lands on its own record instead of
-                # being charged to the next unrelated decode step.
-                with self.ledger.iteration(active=0, stall_ms=0.0):
+            # One iteration record a pass (the batch-composition seam):
+            # whose chunks it carried, the step's wall, active slots
+            # and co-batched stall, the gap its rows saw, the whole
+            # pass (a speculative window sets its ragged emitted total
+            # on the meter before the scope closes).
+            with self.ledger.iteration() as it:
+                # Cancelled rows (their caller already got a sibling's
+                # error) retire before admission: their blocks are
+                # exactly the headroom the queue head is waiting on.
+                with metrics_mod.annotate("serve.admit"):
+                    for slot in list(self._slot_state):
+                        if (self._active[slot]
+                                and self._slot_state[slot].cancelled):
+                            self._retire(slot, "cancelled")
+                # Admission round, bounded by the TOKEN budget: several
+                # short prompts (or one chunk of a long one) may
+                # prefill, but never more than prefill_chunk prompt
+                # tokens — that budget IS the stall bound a co-batched
+                # decode step sees. Charge it as stall only when a
+                # decode was LIVE to wait on it: the chunk that
+                # activates the first row of an idle engine stalls
+                # nobody (that row's own first decode is not a
+                # co-batched waiter).
+                if self._active.any():
+                    pending_stall += self._admission_round()
+                else:
                     self._admission_round()
-                pending_stall = 0.0
-            if not self._active.any():
-                continue
-            stall_ms, pending_stall = pending_stall * 1e3, 0.0
-            self._record_stall(stall_ms)
-            with metrics_mod.annotate("serve.step"):
-                # The iteration meter is the batch-composition seam:
-                # step wall, active slots, this round's prefill split,
-                # and the co-batched stall — one record per iteration
-                # (a speculative window sets its ragged emitted total
-                # on the meter before the scope closes).
-                with self.ledger.iteration(int(self._active.sum()),
-                                           stall_ms) as it:
+                    pending_stall = 0.0
+                if not self._active.any():
+                    # Prefill-only pass (no decode co-batched): still
+                    # an engine iteration — metered, so `serve.steps`
+                    # advances (a burst of max_new=1 requests
+                    # completing entirely inside prefill must not read
+                    # as a stalled engine with a non-empty queue).
+                    continue
+                stall_ms, pending_stall = pending_stall * 1e3, 0.0
+                self._record_stall(stall_ms)
+                it.step(int(self._active.sum()), stall_ms)
+                with metrics_mod.annotate("serve.step"):
                     self._step(it)
 
     def _banks(self):
@@ -1185,6 +1213,7 @@ class PagedGeneratorActor(GeneratorActor):
         # the meter early would under-report the stall charge (and the
         # chunk span) by the final chunk's compute.
         cm = self.ledger.chunk(row.rec, n)
+        cm.ctx = start + n
         with cm:
             # The dispatch lock orders this bank-donating call against
             # ExportBlocks' pack reads on RPC threads (ISSUE 16): a
@@ -1977,7 +2006,6 @@ class PagedGeneratorActor(GeneratorActor):
     # -------------------------------------------------------- telemetry
 
     def _record_stall(self, stall_ms: float) -> None:
-        self._last_stall_ms = stall_ms
         if stall_ms > self._max_stall_ms:
             self._max_stall_ms = stall_ms
 
@@ -2071,7 +2099,6 @@ class PagedGeneratorActor(GeneratorActor):
         info["prefill_chunks"] = self._prefill_chunks
         info["prefill_tokens"] = self._prefill_tokens
         info["prefill_stall_ms"] = round(self._max_stall_ms, 3)
-        info["prefill_stall_last_ms"] = round(self._last_stall_ms, 3)
         # Serving-ledger surface (ISSUE 10): TTFT/TPOT/e2e tails the
         # gateway's probes and `obs serve` read, plus the recent
         # per-request TTFT samples the pool drains into the fleet SLO

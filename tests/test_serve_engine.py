@@ -506,7 +506,7 @@ def test_chunked_prefill_bounds_co_batched_decode_stall():
             # Warm every chunk-bucket compile OFF the measured pass.
             actor.Generate(warm_p, 2)
             actor.Generate(short, 2)
-            actor._max_stall_ms = actor._last_stall_ms = 0.0
+            actor._max_stall_ms = 0.0
             stalls.clear()
             done = threading.Event()
             t = threading.Thread(target=lambda: (

@@ -107,8 +107,8 @@ def test_iteration_meter_folds_batch_composition():
     assert reg.counter("serve.decode_tokens").value == 6
     assert reg.counter("serve.prefill_tokens").value == 32
     assert reg.gauge("serve.active_slots").value == 3
-    s = led.iteration_summary()
-    assert s["iterations"] == 2 and s["active_mean"] == 3.0
+    s = led.summary()
+    assert s["iterations"] == 2 and s["rows_live_mean"] == 3.0
     assert s["stall_ms_max"] == 1.5
     assert s["prefill_token_share"] == pytest.approx(32 / 38,
                                                      abs=1e-4)

@@ -131,7 +131,7 @@ def test_spec_windows_beat_per_token_iterations(params):
         # windows of up to 5 — a hard structural bound, not a timing.
         assert info["engine_steps"] <= 12, info
         assert info["spec_tokens"] >= 30, info
-        iters = actor.ledger.iteration_summary()
+        iters = actor.ledger.summary()
         recs = actor.ledger.records()
         assert recs[-1]["tokens_out"] == 40
         assert iters["iterations"] < 20

@@ -373,7 +373,7 @@ class _RawTimerCheck(ast.NodeVisitor):
             node, "PT010",
             f"raw time.{verb} in serve_engine/ — engine latency "
             f"stamps must ride the serving ledger's seams "
-            f"(health/serving.py: enqueued/head_refused/admitted/"
+            f"(health/serving.py: ingress/enqueued/head_refused/admitted/"
             f"chunk/first_token/tokens_emitted/iteration/retired), "
             f"the one timing home the histograms, span tree, and "
             f"seam-cost probe all derive from"))
