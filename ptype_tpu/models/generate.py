@@ -463,15 +463,16 @@ TABLE_TILE_BLOCKS = 64
 
 
 def _table_attention(q, kf, vf, tables, limits, window: int, scope: str,
-                     scale: float | None = None, v_dim: int | None = None):
+                     scale: float | None = None, v_dim: int | None = None,
+                     keep=None):
     """Attention through block tables in a stack with attention kinds
-    or a latent cache no indexer selects from (``vf`` None, ``v_dim``
-    and ``scale`` as :func:`_live_block_attention` takes them),
-    for queries that are many to a table (a prefill chunk) or rows
-    with no block list: the tables are walked a tile of blocks at a
-    time and folded into a float32 running softmax, the trip count
-    from the data, so ONE compiled program serves every context up to
-    the reach and its temporaries do not grow with it. q: (B, Q, H,
+    or over a latent cache (``vf`` None, ``v_dim`` and ``scale`` as
+    :func:`_live_block_attention` takes them), for queries that are
+    many to a table (a prefill chunk) or rows with no block list: the
+    tables are walked a tile of blocks at a time and folded into a
+    float32 running softmax, the trip count from the data, so ONE
+    compiled program serves every context up to the reach and its
+    temporaries do not grow with it. q: (B, Q, H,
     Dh); ``kf``/``vf`` the flat banks, ``tables`` (B, nb) their rows
     in position order (the layer's base added); ``limits`` (B,) or
     (B, Q): a query attends positions ``< limit`` and, with ``window``,
@@ -482,7 +483,14 @@ def _table_attention(q, kf, vf, tables, limits, window: int, scope: str,
     reads ONE tile, the ``window + Q`` positions its queries can see
     between them, starting at the first block any of them sees:
     what lies behind it was given back (the table names the trash
-    block there) and is masked."""
+    block there) and is masked.
+
+    Behind an indexer (a full layer's walk, ``sparse_mla.attend_paged``)
+    ``keep(first, at)`` gives, for the tile whose first position is
+    ``first`` and whose positions are ``at`` (B, S), the (B, Q, S) keys
+    each query's selection holds, and the scores stay float32; without
+    it they are rounded to ``q``'s type as its product gives them
+    (A.X-K1's chunk keeps that rounding: PERF.md §6, PR 36)."""
     B, Q, H, Dh = q.shape
     nb = tables.shape[1]
     bt, Kh = kf.shape[1], kf.shape[2]
@@ -516,13 +524,17 @@ def _table_attention(q, kf, vf, tables, limits, window: int, scope: str,
                 ks = kf[ids].reshape(B, S, Kh, Dh)
                 vs = (ks[..., :Dv] if vf is None
                       else vf[ids].reshape(B, S, Kh, Dh))
-            s = jnp.einsum("bqkgd,bskd->bkgqs", qg, ks).astype(f32)
+            s = jnp.einsum("bqkgd,bskd->bkgqs", qg, ks,
+                           preferred_element_type=None if keep is None
+                           else f32).astype(f32)
             s = s / jnp.sqrt(f32(Dh)) if scale is None else s * f32(scale)
             # Columns past the table sit past every limit.
             at = (col0[:, None] + t * tile) * bt + offs[None, :]  # (B, S)
             mask = at[:, None, :] < hi[:, :, None]             # (B, Q, S)
             if window:
                 mask &= at[:, None, :] >= hi[:, :, None] - window
+            if keep is not None:
+                mask &= keep(t * S, at)
             mask = mask[:, None, None, :, :]
             s = jnp.where(mask, s, f32(-1e30))
             m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -573,8 +585,9 @@ def _paged_layers(params, tokens, positions, cfg, banks, tables, wr_b,
     cache row goes to ``(wr_b, wr_o)`` (inactive lanes and pads name
     the trash block 0); ``tables`` (B, nb), ``limits`` ((B,) or (B, Q))
     as :func:`_paged_attention_gather` takes them. ``chunk``: the
-    queries are a prefill chunk's, many to a table (latent attention
-    gathers by it, ``sparse_mla.attend_paged``). ``live_list``: what
+    queries are a prefill chunk's, many to a table (behind an indexer
+    each block of them walks the table with its selection as a mask,
+    ``sparse_mla.attend_paged``). ``live_list``: what
     the live rows hold, in the form the cache kind reads, given to a
     decode step. GQA: the block list (:func:`live_block_list`); the
     step attends over it (:func:`_live_block_attention`) and not

@@ -25,9 +25,11 @@ sizes it:
   latent and every unselected key is masked. *Absorbed*
   (:func:`attend_paged`, the paged decode step and prefill chunk):
   ``W_UK`` is folded into the query and ``W_UV`` applied after the
-  sum, so the selected rows of ``ckv`` are gathered through the block
-  table and read as they lie. tests/benchmark/test_bench_glm.py holds
-  the two to each other and to the plain reference.
+  sum, so the rows of ``ckv`` are read as they lie: a decode step
+  gathers its selected rows through the block table, a prefill chunk
+  walks the table with the selection as a mask.
+  tests/benchmark/test_bench_glm.py holds the two to each other and to
+  the plain reference.
 
 The decode step (one query a lane) runs the absorbed form over the
 lanes that hold a request, a tile of them at a time, in one loop
@@ -51,9 +53,12 @@ from jax import lax
 
 from ptype_tpu.models import transformer as tfm
 
-#: Queries scored and gathered at a time by the paged path: a prefill
-#: chunk of 512 queries against 20k keys would hold 1.3 GB of indexer
-#: scores and 1.2 GB of gathered latents at once.
+#: Queries scored, selected and attended at a time by the paged path:
+#: a block of a prefill chunk walks the table alone, its float32 scores
+#: 34 MB a tile at GLM-5's 64 heads. All 512 queries of a chunk walking
+#: together (134 MB a tile, and every query's indexer scores held
+#: through the walk) ran a chunk at 16.4k of context in 134.2 ms
+#: against 113.7 (chip run, PR 38).
 QUERY_BLOCK = 128
 #: The indexer's LayerNorm epsilon (DeepSeek-V3.2's inference code).
 INDEX_NORM_EPS = 1e-6
@@ -242,6 +247,26 @@ def attend_expanded(x, layer, cfg: tfm.TransformerConfig):
         return jnp.einsum("bhqs,bshv->bqhv", probs, v)
 
 
+def kept_by_top_k(I, thr, last, span: int):
+    """What ``lax.top_k`` kept of the scores ``I`` (B, Q, T) float32, as
+    ``generate._table_attention``'s ``keep`` over tiles of ``span``
+    positions: ``thr`` (B, Q) is each query's k-th score and ``last``
+    (B, Q) the position of that k-th entry. ``top_k`` puts the lower
+    position first among equal scores, so it kept exactly the positions
+    that score over ``thr`` and those that score ``thr`` at or before
+    ``last``: the same set, ties included, with no scatter."""
+    pad = -I.shape[-1] % span
+    if pad:
+        I = jnp.pad(I, ((0, 0), (0, 0), (0, pad)), constant_values=_NEG)
+    thr, last = thr[..., None], last[..., None]
+
+    def keep(first, at):
+        tile = lax.dynamic_slice_in_dim(I, first, span, axis=2)
+        return (tile > thr) | ((tile == thr) & (at[:, None, :] <= last))
+
+    return keep
+
+
 def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
                  limits, layer, cfg: tfm.TransformerConfig,
                  whole_context: bool = False, lanes=None):
@@ -252,17 +277,22 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
     and ``ki_bank`` (rows, bt, di) the banks in the flat view;
     ``tables`` (B, nb) this layer's rows of them in position order;
     ``limits`` (B,) or (B, Q): a query attends positions ``< limit``.
-    The indexer scores every position of the table, the top
-    ``index_topk`` are selected, and their rows of ``ckv`` alone are
-    gathered and read. ``whole_context`` (a prefill chunk says so:
-    many queries a table): a row's whole latent context is gathered
-    once, by blocks, and each query picks its rows from that.
-    ``lanes`` (``generate.live_lane_list``'s pair; a decode step's,
-    one query a lane): the lanes that hold a request. With it the same
-    arithmetic runs on those lanes alone, a tile of them a trip of one
-    loop whose trip count is data (ONE compiled program whatever the
-    load), and every other lane reads zeros; without it, on all ``B``.
-    → o (B, Q, H, v)."""
+    The indexer scores every position of the table and the top
+    ``index_topk`` are selected, :data:`QUERY_BLOCK` queries at a time.
+    A decode step's rows (one query a row, a table each) gather the
+    selected rows of ``ckv`` alone and read them. ``whole_context`` (a
+    prefill chunk says so: many queries a table): each block of queries
+    walks the table ONCE, a tile of blocks at a time
+    (``generate._table_attention``), every query's float32 scores
+    against the tile, with the selection as the mask
+    (:func:`kept_by_top_k`), into a float32 running softmax; the trip
+    count follows the chunk's context, and no query's selected rows
+    are copied out. ``lanes`` (``generate.live_lane_list``'s
+    pair; a decode step's, one query a lane): the lanes that hold a
+    request. With it the same arithmetic runs on those lanes alone, a
+    tile of them a trip of one loop whose trip count is data (ONE
+    compiled program whatever the load), and every other lane reads
+    zeros; without it, on all ``B``. → o (B, Q, H, v)."""
     la, dt = cfg.latent, cfg.dtype
     B, Q, H, _ = q_nope.shape
     nb, bt = tables.shape[1], ckv_bank.shape[1]
@@ -276,19 +306,51 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
         with jax.named_scope("index"):
             return ki_bank[tables].reshape(-1, T, la.index_dim)
 
+    def split(a):
+        return jnp.moveaxis(a.reshape(
+            B, Q // QUERY_BLOCK, QUERY_BLOCK, *a.shape[2:]), 1, 0)
+
+    def merge(a):
+        return jnp.moveaxis(a, 0, 1).reshape(B, Q, *a.shape[3:])
+
+    def blocks(f, *xs):
+        """``f`` over the queries a block of QUERY_BLOCK at a time."""
+        if Q <= QUERY_BLOCK:
+            return f(*xs)
+        if Q % QUERY_BLOCK:
+            raise ValueError(f"{Q} queries do not divide into blocks of "
+                             f"{QUERY_BLOCK}")
+        return merge(lax.map(lambda xs: f(*xs), tuple(map(split, xs))))
+
     ki = keys(tables) if lanes is None else None
-    ctx = None
-    if whole_context:
-        # The whole latent context in position order is one gather of
-        # blocks (26 MB at 20k keys), and every query then picks its
-        # rows by position. (Through the table a row at a time, the
-        # 262,144 block ids of 128 queries cost more than the sort:
-        # chip run, PR 28.) Decode rows, each with a table of its own,
-        # read only what they select.
-        with jax.named_scope("kv_gather"):
-            ctx = ckv_bank[tables].reshape(B, T, la.cache_dim)
     with jax.named_scope("attn"):
         qa = absorb_query(q_nope, q_rope, layer, cfg)
+
+    if whole_context:
+        from ptype_tpu.models import generate as gen
+
+        def pick(qi, wi, limits):
+            n, Qb = qi.shape[:2]
+            with jax.named_scope("index"):
+                I = index_scores(qi, wi, ki)
+                # -0.0 as 0.0: top_k orders the two, a mask's == does not.
+                I = jnp.where(jnp.arange(T)[None, None] < limits[..., None],
+                              jnp.where(I == 0, 0.0, I), _NEG)
+            with jax.named_scope("select"):
+                vals, idx = lax.top_k(I.reshape(n * Qb, T), k)
+            return I, vals[:, -1].reshape(n, Qb), idx[:, -1].reshape(n, Qb)
+
+        span = min(nb, gen.TABLE_TILE_BLOCKS) * bt
+
+        def walk(qa, qi, wi, limits):
+            return gen._table_attention(
+                qa, ckv_bank[:, :, None], None, tables, limits, 0, "attn",
+                scale=score_scale(cfg), v_dim=la.kv_rank,
+                keep=kept_by_top_k(*pick(qi, wi, limits), span))
+
+        with jax.named_scope("attn"):
+            return expand_values(blocks(walk, qa, qi, wi, limits), layer,
+                                 cfg)
 
     def block(qa, qi, wi, limits, tables=tables, ki=ki):
         n, Qb = qa.shape[:2]
@@ -303,12 +365,9 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
             idx = idx.reshape(n, Qb, k)  # positions
             ok = idx < limits[..., None]
         with jax.named_scope("kv_gather"):
-            if ctx is not None:
-                sel = jax.vmap(lambda c, i: c[i])(ctx, idx)
-            else:
-                blk = jnp.take_along_axis(tables[:, None, :], idx // bt,
-                                          axis=-1)
-                sel = ckv_bank[blk, idx % bt]  # (B, Qb, k, cache_dim)
+            blk = jnp.take_along_axis(tables[:, None, :], idx // bt,
+                                      axis=-1)
+            sel = ckv_bank[blk, idx % bt]  # (B, Qb, k, cache_dim)
         with jax.named_scope("attn"):
             scores = jnp.einsum("bqhc,bqkc->bqhk", qa, sel,
                                 preferred_element_type=jnp.float32)
@@ -336,16 +395,4 @@ def attend_paged(q_nope, q_rope, qi, wi, ckv_bank, ki_bank, tables,
         with jax.named_scope("attn"):
             return lax.fori_loop(0, n_tiles, tile,
                                  jnp.zeros((B, Q, H, la.v_dim), dt))
-    if Q <= QUERY_BLOCK:
-        return block(qa, qi, wi, limits)
-    if Q % QUERY_BLOCK:
-        raise ValueError(f"{Q} queries do not divide into blocks of "
-                         f"{QUERY_BLOCK}")
-
-    def split(a):
-        return jnp.moveaxis(a.reshape(
-            B, Q // QUERY_BLOCK, QUERY_BLOCK, *a.shape[2:]), 1, 0)
-
-    o = lax.map(lambda xs: block(*xs),
-                (split(qa), split(qi), split(wi), split(limits)))
-    return jnp.moveaxis(o, 0, 1).reshape(B, Q, H, la.v_dim)
+    return blocks(block, qa, qi, wi, limits)

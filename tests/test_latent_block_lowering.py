@@ -3,7 +3,9 @@ tiling contract as data (the rule tests/test_flash_lowering.py applies
 to the flash kernels), its lowering for TPU inside the decode step, a
 compile by the TPU's compiler for a described v5e where one can be
 described here, and a tiny A.X-K1 engine that serves the reference's
-tokens through it."""
+tokens through it; and GLM-5's prefill chunk compiled for that chip,
+its temporaries audited (here because one test file alone describes
+the chip)."""
 
 import dataclasses
 import os
@@ -125,6 +127,64 @@ def test_tpu_compiler_takes_the_kernel_at_the_cells_shapes(one_chip,
     assert mem.temp_size_in_bytes < bank_bytes // 100
     used, = scoped_vmem_bytes(compiled.as_text()) or [0]
     assert used <= vmem_bytes(LANES, HEADS, ROW, VALUES, BT, 256)
+
+
+def test_glm5_chunk_holds_no_gathered_rows_and_no_reach_sized_temporary(
+        one_chip):
+    """``progaudit``'s bound on GLM-5's prefill chunk at the docqa
+    cell's widths (one dense layer; 512 queries, 64 heads, a 640-lane
+    latent row, top 2,048), compiled for a described v5e at two
+    reaches: the chunk walks its table with the indexer's selection as
+    a mask, so no temporary holds a block's gathered rows (128 x 2,048
+    x 640 bf16 = 335 MB; the gather form held 524-540 MB of
+    temporaries here, PR 38) and none grows with the reach."""
+    import json
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from ptype_tpu import progaudit
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "glm-5.json")) as f:
+        glm = {**json.load(f), "num_hidden_layers": 1, "vocab_size": 128}
+    fam = family.of(glm)
+    la = fam.program_config(glm, 128, "bfloat16").latent
+    gathered = 128 * la.index_topk * la.cache_dim * 2
+    bt, C, i32 = 16, 512, jnp.int32
+
+    def sds(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    def temporaries(reach):
+        cfg = fam.program_config(glm, reach, "bfloat16")
+        nb = reach // bt
+        params = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+        banks = {n: sds((1, nb + 1, bt) + w, cfg.dtype)
+                 for n, w in tfm.cache_spec(cfg).items()}
+
+        def chunk(params, banks, tokens, start, length, table):
+            return gen.prefill_chunk_banks(params, tokens, start, length,
+                                           cfg, banks, table)[:2]
+
+        rep = progaudit.audit(
+            chunk, (params, banks, sds((1, C), i32), sds((), i32),
+                    sds((), i32), sds((nb,), i32)),
+            name="serve.latent_prefill_chunk", donate_argnums=(1,),
+            expect_collectives=0, max_temp_bytes=gathered // 4)
+        rep.raise_if_failed()
+        return rep.temp_bytes
+
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        half, whole = temporaries(10240), temporaries(20480)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+    assert 0 < whole <= 1.05 * half
 
 
 # ------------------------------------------------- through a tiny engine
