@@ -315,16 +315,24 @@ class _IterMeter:
 
     - ``seq``: the ledger's count of records, tracing on or off;
     - ``step_ms`` (the step's wall; of a pass that ran none, the
-      pass's), ``active``, ``decode_tokens``, ``prefill_tokens``,
-      ``prefill_ms``, ``stall_ms`` (the co-batched stall the engine
-      charged to this step) and ``iter_ms`` (the whole pass);
+      pass's), ``active`` (the rows the pass emitted tokens for: the
+      step it fetched, or the window it ran), ``decode_tokens``,
+      ``prefill_tokens``, ``prefill_ms``, ``stall_ms`` (the co-batched
+      stall the engine charged to this step) and ``iter_ms`` (the
+      whole pass);
+    - ``ahead``: 1 where the pass dispatched its decode step while the
+      previous step's tokens were still on the device, else 0 (behind
+      ``summary()``'s ``steps_ahead_share`` of the passes that
+      dispatched one);
     - ``chunks``, ``chunk_rids``, ``chunk_ctx``: whose prefill the pass
       carried, and the largest context a chunk of it ended at;
     - ``gap_ms``, ``gap_rows``: the time since the previous
       ``tokens_emitted`` stamp and the rows whose previous token bore
       it, which is what each of them saw as its inter-token gap;
       ``new_gaps_ms``: the gaps of the rows whose previous token was
-      their first (``first_token``'s stamp). A speculative window's
+      their first (``first_token``'s stamp), and those of an earlier
+      emit in the same pass (a step drained before a speculative
+      window). A speculative window's
       further tokens share the commit stamp: ``decode_tokens -
       gap_rows - len(new_gaps_ms)`` gaps of zero (:func:`record_gaps`).
 
@@ -333,8 +341,8 @@ class _IterMeter:
     pass that ran neither a chunk nor a step ticks the counters and
     leaves no record."""
 
-    __slots__ = ("_led", "active", "stall_ms", "decode_tokens", "_t0",
-                 "_t_step")
+    __slots__ = ("_led", "active", "stall_ms", "decode_tokens", "ahead",
+                 "_t0", "_t_step")
 
     def __init__(self, led: "ServingLedger", active: int,
                  stall_ms: float):
@@ -347,6 +355,9 @@ class _IterMeter:
         #: scope closes, so ``serve.decode_tokens`` stays the real
         #: throughput counter either way.
         self.decode_tokens: int | None = None
+        #: Set by the engine where the pass dispatched a decode step:
+        #: 1 with the previous step in flight, 0 without one.
+        self.ahead: int | None = None
         self._t_step: float | None = None
 
     def __enter__(self) -> "_IterMeter":
@@ -376,6 +387,9 @@ class _IterMeter:
             chunks, led._iter_chunks = led._iter_chunks, []
             (gap_s, gap_rows), led._iter_gap = led._iter_gap, (0.0, 0)
             new_gaps, led._iter_new_gaps = led._iter_new_gaps, []
+            if self.ahead is not None:
+                led._dispatched[0] += 1
+                led._dispatched[1] += int(self.ahead)
             if chunks or self.active:
                 led._seq += 1
                 rec = {"seq": led._seq,
@@ -393,7 +407,8 @@ class _IterMeter:
                        "gap_ms": round(gap_s * 1e3, 3),
                        "gap_rows": gap_rows,
                        "new_gaps_ms": tuple([round(g * 1e3, 3)
-                                             for g in new_gaps])}
+                                             for g in new_gaps]),
+                       "ahead": int(bool(self.ahead))}
                 led._iters.append(rec)
         led.c_steps.add(1)
         led.c_decode_tokens.add(dtoks)
@@ -522,6 +537,9 @@ class ServingLedger:
         self._iter_chunks: list[tuple[int, int]] = []
         self._iter_gap = (0.0, 0)
         self._iter_new_gaps: list[float] = []
+        #: Passes that dispatched a decode step, and of them those that
+        #: did so with the previous step in flight.
+        self._dispatched = [0, 0]
         #: Iteration records so far, and the last ``tokens_emitted``
         #: stamp.
         self._seq = 0
@@ -647,10 +665,13 @@ class ServingLedger:
         Once a pass: what the rows saw as their gap goes to the open
         iteration's record (the rows whose previous token bore the
         previous stamp share one gap; a row fresh from ``first_token``
-        has its own)."""
+        has its own; a second emit in one pass turns the first one's
+        shared gap into gaps of its rows' own)."""
         now = time.perf_counter()
         last, self._emit_t = self._emit_t, now
         rows, new = 0, self._iter_new_gaps
+        gap, shared = self._iter_gap
+        new.extend([gap] * shared)
         for i, rec in enumerate(recs):
             tok_t = rec.tok_t
             if tok_t[-1] == last:
@@ -886,10 +907,13 @@ class ServingLedger:
             lane_steps, lane_tiles, lanes_live, lanes_covered = \
                 self._lane_list
             c_steps, c_full, c_window, c_uniform, c_freed = self._cache
+            dispatched, ahead = self._dispatched
             iters = list(self._iters)
         out = {}
         if iters:
             out.update(_ring_summary(iters))
+        if dispatched:
+            out["steps_ahead_share"] = round(ahead / dispatched, 4)
         if self.decode_attn:
             out["decode_attn"] = self.decode_attn
         if c_steps:

@@ -34,6 +34,11 @@ the :class:`~ptype_tpu.serve_engine.blocks.BlockPool`:
   step, so a 32k-token row holds 32k tokens in the full layers alone.
   A prefix hit is the longest chained prefix whose full-layer blocks
   AND whose last window's window-layer blocks are still resident.
+- **One step in flight**: a pass of the engine loop dispatches decode
+  step n+1 before it fetches step n's tokens, so the host's
+  bookkeeping, emit, retire and uploads run while the device computes
+  and the device has the next step queued when one ends. The host's
+  books advance at the dispatch; the tokens feed back on the device.
 - **Chunked prefill**: admission writes the prompt in bounded
   ``prefill_chunk``-token chunks INTERLEAVED with decode steps — a 4k
   prompt can no longer freeze co-batched decodes for its whole
@@ -89,8 +94,7 @@ from ptype_tpu.errors import ShedError
 from ptype_tpu.health.serving import ServingLedger
 from ptype_tpu.models import generate as gen
 from ptype_tpu.models import transformer as tfm
-from ptype_tpu.serve import (LIFECYCLE_CODES, GeneratorActor, _norm_prompt,
-                             _pow2)
+from ptype_tpu.serve import LIFECYCLE_CODES, GeneratorActor, _pow2
 from ptype_tpu.serve_engine.blocks import BlockPool, block_hashes
 from ptype_tpu.serve_engine.migrate import (WIRE_MODES, KVMigrator,
                                             migrate_refusal)
@@ -111,6 +115,15 @@ SERVE_CLASS_CODES = {"unified": 0, "prefill": 1, "decode": 2}
 #: How often an idle engine looks up from its wait for work to see
 #: whether a capture or the recorder has started or stopped listening.
 IDLE_LOOK_S = 0.25
+
+
+def _host_prompt(prompt) -> np.ndarray:
+    """Tokens from the wire -> (B, S) int32 on the host, where the
+    engine's rows read them. Not through the device: with a decode step
+    always queued there, a round trip on the caller's thread would wait
+    a whole step."""
+    prompt = np.array(prompt, np.int32)
+    return prompt[None] if prompt.ndim == 1 else prompt
 
 
 @dataclass
@@ -436,6 +449,27 @@ class PagedGeneratorActor(GeneratorActor):
         #: authoritative and must be re-uploaded (set dirty by
         #: admission, retire, and block-boundary allocation).
         self._dev: dict | None = None
+        #: The decode step in flight: dispatched, its tokens not yet
+        #: fetched — ``(tokens, what the fetch reads, [(slot, row)])``.
+        #: The host books (``_pos``, ``_eidx``, tables) already count
+        #: it; ``_tok`` does not, so a re-upload takes a continuing
+        #: row's token from the step's output and a host token only
+        #: where ``_fresh`` marks a row activated since that dispatch.
+        self._flight: tuple | None = None
+        self._fresh = np.zeros(ns, bool)
+        #: A non-final prefill chunk dispatched this pass: the step's
+        #: tokens are emitted once it is done too, so that the gap it
+        #: stalls is the gap of the pass that carried it.
+        self._chunk_out = None
+        self._merge_tok = jax.jit(
+            lambda fresh, host, dev: jnp.where(fresh, host, dev))
+        # Compiled now, not at the first admission beside a step in
+        # flight: committed where the step's outputs are, under the
+        # engine thread's default device (a part of the program's key).
+        with jax.default_device(self.device):
+            z = jax.device_put(np.zeros(ns, np.int32), self.device)
+            self._merge_tok(
+                jax.device_put(np.zeros(ns, bool), self.device), z, z)
         #: An indexer selects each query's keys (latent attention
         #: behind one): the step then reads by lane, not by block.
         self._selects = cfg.latent is not None and cfg.latent.indexer
@@ -478,9 +512,9 @@ class PagedGeneratorActor(GeneratorActor):
         # before the ingress span becomes the current one.
         tp = trace.traceparent()
         # The request's clock starts here, not at the enqueue stamp:
-        # _norm_prompt sends the prompt to the device and the rows'
-        # np.asarray brings it back, behind whatever program the
-        # engine has in flight.
+        # what runs before it (a sampled row's key is an eager
+        # program, behind whatever the engine has queued) is the
+        # request's wait too.
         with self.ledger.ingress(np.shape(prompt)) as ing:
             return self._generate(
                 ing, tp, prompt, max_new_tokens, temperature, seed,
@@ -489,7 +523,7 @@ class PagedGeneratorActor(GeneratorActor):
     def _generate(self, ing, tp, prompt, max_new_tokens, temperature,
                   seed, top_k, top_p, stop_token, pad_token,
                   repetition_penalty):
-        prompt = _norm_prompt(prompt)
+        prompt = _host_prompt(prompt)
         if (float(repetition_penalty) != 1.0
                 or (float(temperature) != 0.0 and prompt.shape[0] > 1)):
             # Repetition penalty needs per-request seen-set state, and
@@ -639,7 +673,7 @@ class PagedGeneratorActor(GeneratorActor):
         ``max_new_tokens`` is advisory here (the decode side reserves
         for it) — this replica only ever computes token one."""
         self._one_cache("disaggregated prefill")
-        prompt = _norm_prompt(prompt)
+        prompt = _host_prompt(prompt)
         if prompt.shape[0] != 1:
             raise ValueError("Prefill is single-row (the gateway "
                              "migrates one request at a time)")
@@ -772,7 +806,7 @@ class PagedGeneratorActor(GeneratorActor):
         cover the worst case sheds typed, same contract as
         admission."""
         self._one_cache("a migration")
-        prompt = _norm_prompt(prompt)
+        prompt = _host_prompt(prompt)
         if prompt.shape[0] != 1:
             raise ValueError("MigratePlan is single-row")
         toks = np.asarray(prompt[0])
@@ -1009,6 +1043,9 @@ class PagedGeneratorActor(GeneratorActor):
                 self._admitting = None
         for slot in list(self._slot_state):
             stragglers.append(self._slot_state.pop(slot))
+        if self._flight is not None:
+            stragglers += [r for _, r in self._flight[2]]
+            self._flight = None
         for r in stragglers:
             if not r.done.is_set():
                 r.err = err or RuntimeError("generator actor closed")
@@ -1016,9 +1053,11 @@ class PagedGeneratorActor(GeneratorActor):
                 r.done.set()
 
     def _no_work_locked(self) -> bool:
-        """(under _cond) No queue, nothing admitting, no live row."""
+        """(under _cond) No queue, nothing admitting, no live row, no
+        step in flight."""
         return (not self._queue and self._admitting is None
-                and not self._active.any() and not self._closed)
+                and not self._active.any() and self._flight is None
+                and not self._closed)
 
     def _engine_loop(self) -> None:
         pending_stall = 0.0
@@ -1039,11 +1078,17 @@ class PagedGeneratorActor(GeneratorActor):
                     pending_stall = 0.0  # idle time is not stall
                 if self._closed:
                     return
+            # A pass: cancel sweep -> admission round -> block
+            # crossings and any upload for step n+1 -> dispatch n+1 ->
+            # fetch n -> emit n. One step stays in flight, so the
+            # host's work between steps runs while the device computes,
+            # and a final chunk's first-token sync waits for step n and
+            # the chunk, never for n+1.
             # One iteration record a pass (the batch-composition seam):
-            # whose chunks it carried, the step's wall, active slots
-            # and co-batched stall, the gap its rows saw, the whole
-            # pass (a speculative window sets its ragged emitted total
-            # on the meter before the scope closes).
+            # whose chunks it carried, the step's wall, the rows it
+            # emitted for and the co-batched stall, the gap they saw,
+            # the whole pass (a speculative window sets its ragged
+            # emitted total on the meter before the scope closes).
             with self.ledger.iteration() as it:
                 # Cancelled rows (their caller already got a sibling's
                 # error) retire before admission: their blocks are
@@ -1061,13 +1106,14 @@ class PagedGeneratorActor(GeneratorActor):
                 # decode was LIVE to wait on it: the chunk that
                 # activates the first row of an idle engine stalls
                 # nobody (that row's own first decode is not a
-                # co-batched waiter).
-                if self._active.any():
+                # co-batched waiter). The rows of a step in flight
+                # wait on it: their tokens are emitted once it is done.
+                if self._active.any() or self._flight is not None:
                     pending_stall += self._admission_round()
                 else:
                     self._admission_round()
                     pending_stall = 0.0
-                if not self._active.any():
+                if not self._active.any() and self._flight is None:
                     # Prefill-only pass (no decode co-batched): still
                     # an engine iteration — metered, so `serve.steps`
                     # advances (a burst of max_new=1 requests
@@ -1076,7 +1122,10 @@ class PagedGeneratorActor(GeneratorActor):
                     continue
                 stall_ms, pending_stall = pending_stall * 1e3, 0.0
                 self._record_stall(stall_ms)
-                it.step(int(self._active.sum()), stall_ms)
+                # The rows this pass emits for: the step in flight's.
+                it.step(sum(not r.done.is_set()
+                            for _, r in self._flight[2])
+                        if self._flight is not None else 0, stall_ms)
                 with metrics_mod.annotate("serve.step"):
                     self._step(it)
 
@@ -1226,6 +1275,7 @@ class PagedGeneratorActor(GeneratorActor):
                     jnp.asarray(padded), jnp.int32(start), jnp.int32(n),
                     jax.tree.map(jnp.asarray, table_arr))
                 self._put_banks(banks)
+            self._chunk_out = logits
             row.prefill_pos += n
             done = row.prefill_pos >= L
             if done:
@@ -1400,6 +1450,7 @@ class PagedGeneratorActor(GeneratorActor):
             self._wtables[slot, :len(row.wtable)] = row.wtable
             self._wfirst[slot] = row.wfirst
         self._tok[slot] = first
+        self._fresh[slot] = True
         self._pos[slot] = L
         self._active[slot] = True
         self._keys[slot] = row.key
@@ -1468,8 +1519,10 @@ class PagedGeneratorActor(GeneratorActor):
 
     def _step(self, meter=None) -> None:
         """One engine iteration over the live slots: a speculative
-        window when speculation is armed and earns its depth, else the
-        plain one-token batched decode step."""
+        window when speculation is armed and earns its depth (the step
+        in flight drained first: a window commits ragged advances
+        from host tokens), else the plain one-token batched decode
+        step, dispatched ahead of the previous one's fetch."""
         if self._spec is not None:
             k_eff = self._spec_k_eff()
             if k_eff >= 1:
@@ -1483,14 +1536,37 @@ class PagedGeneratorActor(GeneratorActor):
                     f.sleep()
                     f = None
                 if f is None:
-                    self._spec_step(k_eff, meter)
+                    prev, self._flight = self._flight, None
+                    drained = self._emit(prev) if prev is not None else 0
+                    if self._active.any():
+                        self._spec_step(k_eff, meter)
+                        if meter is not None:
+                            meter.ahead = 0
+                            meter.decode_tokens += drained
                     return
+        if meter is not None and self._active.any():
+            meter.ahead = int(self._flight is not None)
         self._plain_step()
 
     def _plain_step(self) -> None:
+        """Dispatch the next decode step over the live slots, then
+        fetch and emit the one dispatched the pass before: the host's
+        work for step n+1 runs while the device computes step n, and
+        the device has step n+1 queued when step n ends. A pass with
+        no live slot only fetches and emits."""
         # The iteration's phases are regions of their own inside
         # serve.step, so a device profile says what the host was doing
         # in each of the device's gaps (PERF.md §3).
+        prev, self._flight = self._flight, None
+        if self._active.any():
+            self._dispatch(prev)
+        if prev is not None:
+            self._emit(prev)
+
+    def _dispatch(self, flight) -> None:
+        """Dispatch a decode step over the live slots, ``flight`` the
+        previous step if it is still on the device: the step's block
+        crossings, its upload where the slot state changed, the call."""
         annotate = metrics_mod.annotate
         with annotate("serve.step/blocks"):
             # Boundary crossings first: a slot whose next write lands
@@ -1554,13 +1630,32 @@ class PagedGeneratorActor(GeneratorActor):
                     self._kv = {
                         "live_lanes": int(self._active.sum()),
                         "lane_tiles": int(live_list[1])}
-                self._dev = jax.device_put({
-                    "tok": self._tok, "pos": self._pos,
-                    "tables": tables, "active": self._active,
-                    "keys": self._keys, "eidx": self._eidx,
-                    "temps": self._temps, "topk": self._topk,
-                    "topp": self._topp, "live_list": live_list,
-                }, self.device)
+                up = {"pos": self._pos, "tables": tables,
+                      "active": self._active, "keys": self._keys,
+                      "eidx": self._eidx, "temps": self._temps,
+                      "topk": self._topk, "topp": self._topp}
+                # With a step in flight the host's tokens are one step
+                # stale: a continuing row's token is that step's
+                # output, already on the device; only the rows
+                # activated since its dispatch send theirs.
+                fresh = flight is not None and self._fresh.any()
+                if flight is None or fresh:
+                    up["tok"] = self._tok
+                if fresh:
+                    up["fresh"] = self._fresh
+                # Copies: the books change in place right after the
+                # dispatch, while the step (and the transfer; on a
+                # CPU none, the device array IS the host's) may not
+                # have run yet.
+                up = jax.tree.map(np.array, up)
+                up["live_list"] = live_list
+                d = jax.device_put(up, self.device)
+                if fresh:
+                    d["tok"] = self._merge_tok(d.pop("fresh"), d["tok"],
+                                               flight[0])
+                elif flight is not None:
+                    d["tok"] = flight[0]
+                self._dev = d
         d = self._dev
         self._steps += 1
         n_live = int(self._active.sum())
@@ -1594,38 +1689,69 @@ class PagedGeneratorActor(GeneratorActor):
                     d["topp"], d["live_list"])
                 self._put_banks(banks)
         d["tok"] = nxt
+        # The host books advance at dispatch: the positions, emission
+        # indices and block crossings of the next step are known
+        # before its tokens are. A row whose last token this step
+        # computes leaves the batch now; it finishes when the token
+        # is emitted.
+        live = [(int(s), self._slot_state[int(s)])
+                for s in np.flatnonzero(self._active)]
+        self._pos[self._active] += 1
+        self._eidx[self._active] += 1
+        self._fresh[:] = False
+        self._flight = (nxt, fetch[0] if fetch else nxt, live)
+        for slot, row in live:
+            if self._eidx[slot] >= row.max_new:
+                self._release(slot)
+
+    def _emit(self, flight) -> int:
+        """Fetch a dispatched step's tokens and hand them to their
+        rows; → the rows that emitted. A row retired since the
+        dispatch (cancelled, or stopped one step before) discards its
+        lane."""
+        nxt, out, rows = flight
+        annotate = metrics_mod.annotate
         with annotate("serve.step/fetch"):
-            # The host waits for the device here.
-            nxt_host = np.array(fetch[0] if fetch else nxt)  # host
-            #   mirror for retire bookkeeping
-        if fetch:
-            *counts, tiles, hit = nxt_host[self.n_slots:]
+            # The host waits for the device here: for the step, and
+            # for a chunk this pass queued behind it, so that a chunk's
+            # time is in the gap of the pass that carried it (and two
+            # chunks never share one gap).
+            host = np.array(out)  # host mirror for retire bookkeeping
+            if self._chunk_out is not None:
+                self._chunk_out.block_until_ready()
+                self._chunk_out = None
+        if out is not nxt:
+            *counts, tiles, hit = host[self.n_slots:]
             self.ledger.moe_load(
                 counts, tiles, hit, tfm.expert_tile(self.n_slots),
                 self.cfg.n_layers - self.cfg.n_dense_layers)
-            nxt_host = nxt_host[:self.n_slots]
         with annotate("serve.step/emit"):
-            self._pos[self._active] += 1
-            self._eidx[self._active] += 1
-            self._tok = nxt_host
-            live = [(slot, self._slot_state[slot])
-                    for slot in list(self._slot_state)
-                    if self._active[slot]]
-            # One shared stamp for every row that just emitted — the
-            # per-token decode-delta trail behind the TPOT histogram.
-            self.ledger.tokens_emitted([row.rec for _, row in live])
+            live = [(slot, row) for slot, row in rows
+                    if not row.done.is_set()]
+            if live:
+                # One shared stamp for every row that just emitted —
+                # the per-token decode-delta trail behind the TPOT
+                # histogram.
+                self.ledger.tokens_emitted([row.rec for _, row in live])
             for slot, row in live:
-                t = int(nxt_host[slot])
+                t = int(host[slot])
                 row.emitted.append(t)
-                if row.stop_token >= 0 and t == row.stop_token:
-                    self._retire(slot, "stop")
-                elif len(row.emitted) >= row.max_new:
-                    self._retire(slot, "complete")
+                held = self._slot_state.get(slot) is row
+                if held:
+                    self._tok[slot] = t
+                stop = row.stop_token >= 0 and t == row.stop_token
+                if stop or len(row.emitted) >= row.max_new:
+                    reason = "stop" if stop else "complete"
+                    if held:
+                        self._retire(slot, reason)
+                    else:
+                        self._finish_row(row, reason)
             if self._steps % 32 == 0:
                 self._export_gauges()  # sampler cadence is ~50 ms+;
                 #                        the retire/admission exports
                 #                        keep the block gauges fresh
                 #                        between these.
+        return len(live)
 
     # ------------------------------------------------------ speculation
 
@@ -1711,11 +1837,12 @@ class PagedGeneratorActor(GeneratorActor):
             # Fresh evidence decides: park the EWMA at the floor so
             # the probe window's own accept rate dominates via alpha.
             self._spec_ewma = self._spec.accept_floor
-        live = [self._slot_state[s]
-                for s in np.flatnonzero(self._active)]
-        if not live:
+        live = np.flatnonzero(self._active)
+        if not len(live):
             return 0
-        max_r = max(r.max_new - len(r.emitted) for r in live)
+        # Emission indices count a step in flight, its tokens not.
+        max_r = max(self._slot_state[int(s)].max_new - int(self._eidx[s])
+                    for s in live)
         return max(0, min(self._k_cur, max_r - 1))
 
     def _spec_adapt(self) -> None:
@@ -1909,6 +2036,7 @@ class PagedGeneratorActor(GeneratorActor):
         self.ledger.spec_window(k_eff * len(live), total_acc,
                                 total_emit, self._spec_ewma)
         if meter is not None:
+            meter.active = len(live)
             meter.decode_tokens = total_emit
         chaos.note_ok("serve.spec")
         for slot, reason in retires:
@@ -1972,17 +2100,40 @@ class PagedGeneratorActor(GeneratorActor):
         return bad
 
     def _retire(self, slot: int, reason: str = "complete") -> None:
+        self._finish_row(self._release(slot), reason)
+
+    def _release(self, slot: int) -> _PagedRow:
+        """Take a decoding row out of its slot and give back its
+        blocks; → the row, which finishes once its last token is on
+        the host (at once, from ``_retire``)."""
         self._active[slot] = False
         self._temps[slot] = 0.0
         self._dev = None  # slot state changed: re-upload next step
         self._sdev = None
-        self._finish_row(self._slot_state.pop(slot), reason)
+        row = self._slot_state.pop(slot)
+        # A step in flight may still write the row's blocks (the one
+        # that computes its last token, or a lane-step past a stop or a
+        # cancel the host sees only now). They go back to the pool's
+        # books here all the same: whatever writes them next is a
+        # program dispatched later to the same device, which runs
+        # after that step. The lane's own write lands at the row's
+        # next position, past the prompt's sealed blocks.
+        self._free_row(row)
         self._export_gauges()
+        return row
 
     def _finish_row(self, row: _PagedRow,
                     reason: str = "complete") -> None:
+        self._free_row(row)
+        self.ledger.retired(row.rec, reason)
+        row.done.set()
+
+    def _free_row(self, row: _PagedRow) -> None:
+        """Give back every block and reserved unit ``row`` holds, in
+        each pool (a second call finds none)."""
         for bid in row.table:
             self.pool.deref(bid)
+        row.table = []
         if row.reserve_left > 0:
             self.pool.unreserve(row.reserve_left)
         row.reserve_left = 0
@@ -2000,8 +2151,6 @@ class PagedGeneratorActor(GeneratorActor):
             if row.draft_reserve_left > 0:
                 self._dpool.unreserve(row.draft_reserve_left)
             row.draft_reserve_left = 0
-        self.ledger.retired(row.rec, reason)
-        row.done.set()
 
     # -------------------------------------------------------- telemetry
 
@@ -2036,7 +2185,7 @@ class PagedGeneratorActor(GeneratorActor):
                 # a planned-but-undecoded ticket on the decode side)
                 # — exiting now would strand it mid-transfer.
                 return False
-        return not self._active.any()
+        return not self._active.any() and self._flight is None
 
     def _export_gauges(self) -> None:
         reg = self._reg
